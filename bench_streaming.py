@@ -33,8 +33,6 @@ import sys
 import tempfile
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 FAST = {
     "spark.rapids.tpu.memory.retry.backoffBaseMs": 0.1,
     "spark.rapids.tpu.memory.retry.backoffMaxMs": 2.0,

@@ -20,16 +20,12 @@ from __future__ import annotations
 
 import os
 
-# int64/float64 columns require x64 mode. The env var only works if jax
-# is not yet initialized; the config update covers the (common) case where
-# the environment preimports jax before this package loads.
+# int64/float64 columns require x64 mode
 os.environ.setdefault("JAX_ENABLE_X64", "1")
-try:
-    import jax as _jax
 
-    _jax.config.update("jax_enable_x64", True)
-except Exception:  # noqa: BLE001 - jax optional at import time
-    pass
+import jax as _jax  # noqa: E402
+
+_jax.config.update("jax_enable_x64", True)
 
 __version__ = "0.1.0"
 

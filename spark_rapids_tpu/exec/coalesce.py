@@ -37,8 +37,8 @@ def concat_device_batches(batches: List[DeviceBatch],
     if len(batches) == 1:
         return batches[0]
     schema = batches[0].schema
-    # one batched readback — per-batch int(num_rows) is a device RTT
-    # each, ruinous over a remote-TPU link
+    # one batched readback — per-batch int(num_rows) is a device sync
+    # each
     counts = [int(n) for n in
               jax.device_get([b.num_rows for b in batches])]
     total = sum(counts)

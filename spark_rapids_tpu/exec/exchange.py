@@ -17,8 +17,8 @@ Range mirrors the reference's split of work (GpuRangePartitioner.scala:
 33-104 — driver-side sampled bounds, device-side bound compare): key
 samples are taken on device during the shuffle write, the quantile
 bounds are picked on host from the tiny sample, and row placement is a
-compiled lexicographic bound-compare over order-preserving uint64 key
-passes.  String keys are coarsened to a fixed byte prefix for
+compiled lexicographic bound-compare over order-preserving uint32 key
+words.  String keys are coarsened to a fixed byte prefix for
 placement only — prefix compare is a monotone coarsening of the true
 order, so per-partition sort + in-order concat still yields a total
 order (balance, never correctness, depends on the prefix).
@@ -45,7 +45,7 @@ from ..utils.tracing import trace_range
 from .base import DevicePartitionedData, TpuExec
 
 #: string keys are truncated to this byte prefix for range PLACEMENT
-#: (not for the sort itself) — 4 uint64 passes per string key
+#: (not for the sort itself) — 9 uint32 words per string key
 RANGE_PREFIX_BYTES = 32
 
 #: per-batch device key samples taken for the range bounds
@@ -53,7 +53,7 @@ RANGE_SAMPLES_PER_BATCH = 128
 
 
 def range_key_passes(batch: DeviceBatch, bound_keys):
-    """Stacked order-preserving uint64 passes [n_passes, padded] of the
+    """Stacked order-preserving uint32 words [n_passes, padded] of the
     range sort keys, with string keys truncated to RANGE_PREFIX_BYTES
     (monotone coarsening — see module docstring).
 
@@ -114,7 +114,7 @@ def range_pids_from_bounds(passes, bounds):
 
 
 def pick_bounds_host(samples: np.ndarray, n_out: int) -> np.ndarray:
-    """Quantile bounds from the gathered uint64 sample passes
+    """Quantile bounds from the gathered uint32 sample words
     [n_passes, n_samples] (host side, like the reference's driver-side
     bounds — GpuRangePartitioner.scala:68-104)."""
     order = np.lexsort(samples[::-1])  # passes[0] dominates
@@ -356,8 +356,7 @@ class TpuShuffleExchangeExec(TpuExec):
             def flush():
                 # ONE batched readback of the chunk's tiny per-block
                 # vectors — a per-batch int(num_rows) is a full device
-                # RTT each, which dominates shuffle writes on a
-                # remote-TPU link
+                # sync each
                 nonlocal rr
                 if not chunk:
                     return
@@ -825,8 +824,17 @@ def register(register_exec):
         return list(getattr(part, "_bound", None)
                     or getattr(part, "keys", []) or [])
 
+    def tag(meta):
+        if isinstance(meta.plan.partitioning, HashPartitioning):
+            for e in exprs_of(meta.plan):
+                gap = hashing.device_hash_gap(e.dtype)
+                if gap is not None:
+                    meta.will_not_work_on_tpu(
+                        f"hash partitioning on {e.sql()}: {gap}")
+
     register_exec(
         P.ShuffleExchangeExec,
         convert=lambda meta, ch: TpuShuffleExchangeExec(ch[0], meta.plan),
         desc="device hash/single/round-robin/range exchange",
+        tag=tag,
         exprs_of=exprs_of)

@@ -132,8 +132,8 @@ class TpuHashJoinExec(TpuExec):
             h = hashing.hash_device_batch(keys, seed=seed)
             pids = hashing.pmod(h, m).astype(jnp.int32)
             # ONE readback of all m bucket counts (a per-bucket
-            # int(sub.num_rows) is a device RTT each — m<=64 of them
-            # per batch dominated grace joins on a remote-TPU link)
+            # int(sub.num_rows) is a device sync each — m<=64 of them
+            # per batch)
             seg = jnp.where(b.row_mask(), pids, m)
             counts = np.asarray(jax.ops.segment_sum(
                 jnp.ones_like(seg, dtype=jnp.int32), seg,
@@ -502,6 +502,16 @@ def register(register_exec):
             meta.will_not_work_on_tpu(
                 f"join condition on {plan.how} join is not supported "
                 f"on TPU (inner only)")
+        # an oversized partition pair is re-bucketed by the device hash
+        # of the join keys (_join_grace)
+        from ..utils import hashing
+
+        l_keys, r_keys = _common_key_exprs(plan.left_keys,
+                                           plan.right_keys)
+        for k in l_keys + r_keys:
+            gap = hashing.device_hash_gap(k.dtype)
+            if gap is not None:
+                meta.will_not_work_on_tpu(f"join key {k.sql()}: {gap}")
 
     def exprs_of(plan: P.HashJoinExec):
         out = list(plan.left_keys) + list(plan.right_keys)

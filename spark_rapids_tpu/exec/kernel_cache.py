@@ -84,28 +84,20 @@ class _CachedKernel:
             kwargs["donate_argnums"] = tuple(donate_argnums)
         self._jfn = jax.jit(fn, **kwargs)
 
-    def _shape_cache_size(self) -> Optional[int]:
-        try:
-            return self._jfn._cache_size()
-        except Exception:  # noqa: BLE001 - private jax API moved
-            return None
-
     def __call__(self, *args, metrics=None):
         # the disabled-profiler cost is this ONE attribute read — no
         # allocation, no lock (the profiler-guard analysis rule pins
         # both)
         prof = PROFILER if PROFILER.enabled else None
-        before = self._shape_cache_size()
+        # the jit wrapper's per-shape executable count (jax 0.9 offers
+        # no public reading of it): a dispatch that grew it compiled
+        before = self._jfn._cache_size()
         t0 = time.perf_counter_ns()
         out = self._jfn(*args)
         if prof is not None:
             prof.record_dispatch(self.fingerprint,
                                  time.perf_counter_ns() - t0, args, out)
-        if before is None:
-            self._cache._count(dispatches=1)
-            return out
-        after = self._shape_cache_size()
-        if after is not None and after > before:
+        if self._jfn._cache_size() > before:
             dt = time.perf_counter_ns() - t0
             self._cache._count(dispatches=1, misses=1, compileTimeNs=dt)
             if metrics is not None:
@@ -170,14 +162,9 @@ class KernelCache:
     def donation_active(self) -> bool:
         """Donation applies only where the backend honors it — the CPU
         backend silently ignores donated buffers (and warns)."""
-        if not self.donation_enabled:
-            return False
-        try:
-            import jax
+        import jax
 
-            return jax.default_backend() != "cpu"
-        except Exception:  # noqa: BLE001 - backend not initializable
-            return False
+        return self.donation_enabled and jax.default_backend() != "cpu"
 
     # ---------------- counters ----------------------------------------
     def _count(self, **kv) -> None:

@@ -8,9 +8,9 @@ target is sorted out-of-core — each input batch becomes a sorted run cut
 into spill-registered tiles, then a k-way tile merge streams the globally
 sorted output (SURVEY §5's multi-tile sort demand).
 
-The in-core sort is the device lexsort (order-preserving uint64 key
-passes + stable argsort — XLA's sort lowers onto the TPU's sorting
-network), followed by a gather.
+The in-core sort is the device lexsort (order-preserving uint32 key
+words + a chain of stable single-key sorts — XLA's sort lowers onto the
+TPU's sorting network), followed by a gather.
 
 Global sorts get a range exchange below them from the planner, exactly as
 Spark's EnsureRequirements provides for the reference.

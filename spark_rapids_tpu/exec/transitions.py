@@ -387,10 +387,9 @@ class DeviceToHostExec(TpuExec):
                 from ..data.column import device_to_host_many
 
                 # chunked drain: one batched download per K batches —
-                # a per-batch device_to_host pays 2 device RTTs each,
-                # the dominant wall of a small-batch result stream over
-                # a remote link.  K bounds how many device batches the
-                # chunk pins at once.
+                # a per-batch device_to_host pays 2 device syncs each.
+                # K bounds how many device batches the chunk pins at
+                # once.
                 chunk = []
 
                 def drain():

@@ -25,7 +25,9 @@ from .semaphore import DeviceSemaphore
 
 log = logging.getLogger(__name__)
 
-_DEFAULT_HBM_BYTES = 16 * 1024 ** 3  # v5e chip, used when query fails
+#: arena basis on the CPU backend (tests), which reports no memory
+#: limit; an accelerator that reports none is an error
+_CPU_BACKEND_ARENA_BYTES = 16 * 1024 ** 3
 
 
 class DeviceManager:
@@ -43,11 +45,9 @@ class DeviceManager:
         self.devices = jax.devices()
         self.device = self.devices[0]
         self.platform = self.device.platform
-        if self.platform != "cpu":
-            # CPU AOT cache entries are machine-feature sensitive
-            # (XLA warns about SIGILL on mismatch), and the CPU warm
-            # path is already covered by the session's plan cache
-            self._enable_persistent_compile_cache(jax)
+        from ..utils import compile_cache
+
+        compile_cache.enable()
         total = self._query_memory()
         self.arena_bytes = int(total * conf.get(DEVICE_MEMORY_FRACTION))
         self.debug = conf.get(DEVICE_MEMORY_DEBUG)
@@ -69,28 +69,6 @@ class DeviceManager:
             log.info("DeviceManager: %s, arena=%d bytes",
                      self.device, self.arena_bytes)
 
-    @staticmethod
-    def _enable_persistent_compile_cache(jax) -> None:
-        """Cross-process XLA compile cache (reference intent: cuDF JNI
-        ships precompiled kernels; here compiles are runtime, so cache
-        them on disk — first collect in a fresh process reuses prior
-        compiles of the same program+shape)."""
-        import os
-        import tempfile
-
-        try:
-            if jax.config.jax_compilation_cache_dir:
-                return
-            cache = os.environ.get(
-                "SRT_XLA_CACHE_DIR",
-                os.path.join(tempfile.gettempdir(), "srt_xla_cache"))
-            os.makedirs(cache, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.3)
-        except Exception:  # noqa: BLE001 — cache is best-effort
-            pass
-
     @classmethod
     def get_or_create(cls, conf: TpuConf) -> "DeviceManager":
         with cls._lock:
@@ -104,13 +82,15 @@ class DeviceManager:
             cls._instance = None
 
     def _query_memory(self) -> int:
-        try:
-            stats = self.device.memory_stats()
-            if stats and "bytes_limit" in stats:
-                return int(stats["bytes_limit"])
-        except Exception:  # noqa: BLE001
-            pass
-        return _DEFAULT_HBM_BYTES
+        if self.platform == "cpu":
+            return _CPU_BACKEND_ARENA_BYTES
+        stats = self.device.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            raise RuntimeError(
+                f"device {self.device.device_kind!r} ({self.platform}) "
+                f"reports no bytes_limit in memory_stats() ({stats!r}): "
+                "the arena cannot be sized")
+        return int(stats["bytes_limit"])
 
     # ----- logical arena accounting (RMM-pool analogue) -------------------
     def track_alloc(self, nbytes: int) -> None:

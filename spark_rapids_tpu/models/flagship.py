@@ -113,6 +113,9 @@ def build_q1_pipeline(n_rows: int = 1 << 16, seed: int = 0
     def fn(batch: DeviceBatch) -> DeviceBatch:
         for k in chain:
             batch = k(batch)
+            if isinstance(batch, tuple):
+                # a fused segment returns one batch per output stream
+                (batch,) = batch
         return batch
 
     example = host_to_device(hb)

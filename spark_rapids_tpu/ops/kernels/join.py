@@ -9,8 +9,9 @@ replacement is reversed).  Three stages, all static shapes:
   1. group ids: concat both sides' key columns, one lexsort, segment
      ids at key-change boundaries → per-row int32 ids where equal keys
      (with Spark null/NaN/-0.0 semantics) share an id across sides.
-  2. probe: sort right ids once; per left row, searchsorted gives the
-     contiguous run [lo, lo+cnt) of its matches.  Match counts are
+  2. probe: the same sort, split by side, is the right rows in id
+     order; per left row, searchsorted gives the contiguous run
+     [lo, lo+cnt) of its matches.  Match counts are
      exact before any expansion — the same "size before materialize"
      contract cudf's join APIs give the reference.
   3. expand: with an output capacity chosen from the exact count, a
@@ -27,6 +28,7 @@ from typing import List, NamedTuple
 
 from ...data.column import DeviceColumn
 from . import segment as seg
+from .gather import partition_order, prefix_sum
 
 
 def _concat_key_cols(lc: DeviceColumn, rc: DeviceColumn) -> DeviceColumn:
@@ -50,31 +52,9 @@ def _concat_key_cols(lc: DeviceColumn, rc: DeviceColumn) -> DeviceColumn:
     return DeviceColumn(lc.dtype, data, validity, lengths)
 
 
-def group_ids(l_keys: List[DeviceColumn], r_keys: List[DeviceColumn],
-              l_ok, r_ok):
-    """Per-row join-key group ids: rows (on either side) with equal,
-    fully-non-null keys share an id.  Left rows with null keys/padding
-    get -1, right ones -2 — sentinels that never match anything."""
-    import jax.numpy as jnp
-
-    nl, nr = l_ok.shape[0], r_ok.shape[0]
-    combined = [_concat_key_cols(a, b) for a, b in zip(l_keys, r_keys)]
-    ok = jnp.concatenate([l_ok, r_ok])
-    # null keys never join: fold key validity into row eligibility
-    for c in combined:
-        ok = ok & c.validity
-    order = seg.lexsort_device(combined, pad_valid=ok)
-    sorted_cols = [DeviceColumn(c.dtype, c.data[order],
-                                c.validity[order] & ok[order],
-                                c.lengths[order]
-                                if c.lengths is not None else None)
-                   for c in combined]
-    ids_sorted = seg.segment_ids_device(sorted_cols, pad_valid=ok[order])
-    n = nl + nr
-    ids = jnp.zeros((n,), dtype=jnp.int32).at[order].set(ids_sorted)
-    gl = jnp.where(ok[:nl], ids[:nl], -1)
-    gr = jnp.where(ok[nl:], ids[nl:], -2)
-    return gl, gr
+#: id of a row that never joins (null key / padding) in the id-sorted
+#: views — above every real id, so the views stay ascending
+_NEVER = 2 ** 31 - 1
 
 
 class Probe(NamedTuple):
@@ -86,20 +66,51 @@ class Probe(NamedTuple):
     has_r: object    # bool[Nr] right row has a left match
 
 
-def probe(l_keys, r_keys, l_ok, r_ok) -> Probe:
+def probe(l_keys: List[DeviceColumn], r_keys: List[DeviceColumn],
+          l_ok, r_ok) -> Probe:
+    """Group ids and match runs from ONE sort of both sides' keys.
+
+    Rows (on either side) with equal, fully-non-null keys share a group
+    id; left rows with null keys/padding get -1, right ones -2 —
+    sentinels that never match anything.  The combined sort already
+    holds each side in id order, so the right rows sorted by id (and
+    the sorted ids of each side, for the run searches) are a stable
+    split of it by side — a prefix sum and a scatter — not two more
+    sorts (each sort in a program costs the TPU compiler ~30 s)."""
     import jax.numpy as jnp
 
-    gl, gr = group_ids(l_keys, r_keys, l_ok, r_ok)
-    order_r = jnp.argsort(gr, stable=True).astype(jnp.int32)
-    sorted_gr = gr[order_r]
+    nl, nr = l_ok.shape[0], r_ok.shape[0]
+    combined = [_concat_key_cols(a, b) for a, b in zip(l_keys, r_keys)]
+    ok = jnp.concatenate([l_ok, r_ok])
+    # null keys never join: fold key validity into row eligibility
+    for c in combined:
+        ok = ok & c.validity
+    order = seg.lexsort_device(combined, pad_valid=ok)
+    ok_s = ok[order]
+    sorted_cols = [DeviceColumn(c.dtype, c.data[order],
+                                c.validity[order] & ok_s,
+                                c.lengths[order]
+                                if c.lengths is not None else None)
+                   for c in combined]
+    ids_s = seg.segment_ids_device(sorted_cols, pad_valid=ok_s)
+    ids = jnp.zeros((nl + nr,), dtype=jnp.int32).at[order].set(ids_s)
+    gl = jnp.where(ok[:nl], ids[:nl], -1)
+    gr = jnp.where(ok[nl:], ids[nl:], -2)
+
+    # sorted positions of the right rows, then of the left rows, each
+    # still in id order (never-joining rows last)
+    by_side = partition_order(order >= nl)
+    pos_r, pos_l = by_side[:nr], by_side[nr:]
+    ids_s = jnp.where(ok_s, ids_s, _NEVER)
+    order_r = order[pos_r] - nl
+    sorted_gr, sorted_gl = ids_s[pos_r], ids_s[pos_l]
+
     lo = jnp.searchsorted(sorted_gr, gl, side="left").astype(jnp.int32)
     hi = jnp.searchsorted(sorted_gr, gl, side="right").astype(jnp.int32)
-    cnt = hi - lo
-    sorted_gl = jnp.sort(gl)
     rlo = jnp.searchsorted(sorted_gl, gr, side="left")
     rhi = jnp.searchsorted(sorted_gl, gr, side="right")
     has_r = (rhi > rlo) & (gr >= 0)
-    return Probe(gl, gr, order_r, lo, cnt, has_r)
+    return Probe(gl, gr, order_r, lo, hi - lo, has_r)
 
 
 def emit_counts(p: Probe, how: str, l_rm, r_rm):
@@ -130,7 +141,7 @@ def expand_pairs(p: Probe, emit, r_extra, c_out: int):
 
     nl = emit.shape[0]
     nr = p.gr.shape[0]
-    offs = jnp.cumsum(emit)                      # inclusive prefix sum
+    offs = prefix_sum(emit)                      # inclusive
     m_left = offs[-1]
     t = jnp.arange(c_out, dtype=jnp.int64)
     li = jnp.searchsorted(offs, t, side="right").astype(jnp.int32)
@@ -146,7 +157,7 @@ def expand_pairs(p: Probe, emit, r_extra, c_out: int):
 
     # unmatched right rows fill slots [m_left, m_left + n_extra)
     n_extra = r_extra.sum(dtype=jnp.int64)
-    unmatched_order = jnp.argsort(~r_extra, stable=True).astype(jnp.int32)
+    unmatched_order = partition_order(r_extra)
     s = jnp.clip(t - m_left, 0, nr - 1)
     ridx = jnp.where(~in_left, unmatched_order[s], ridx)
     slot_valid = t < (m_left + n_extra)

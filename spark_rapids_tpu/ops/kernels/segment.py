@@ -19,6 +19,7 @@ import numpy as np
 
 from ... import types as T
 from ...data.column import DeviceColumn, HostColumn
+from .gather import prefix_sum
 
 # ---------------------------------------------------------------------------
 # Host (numpy) engine
@@ -213,85 +214,158 @@ def segment_reduce_np(values: np.ndarray, valid: np.ndarray,
 # ---------------------------------------------------------------------------
 # Device (jnp) engine
 # ---------------------------------------------------------------------------
-def _sort_key_device(col: DeviceColumn, desc: bool, nulls_first: bool):
-    """Build orderable uint64 key(s) for one device column.
+def _ordered_u32(x):
+    """Order-preserving uint32 image of an int32 array (sign flip)."""
+    import jax.numpy as jnp
 
-    Numerics map order-preservingly into uint64; nulls get the extreme
-    value for their placement; strings contribute one key per byte chunk
-    (handled by caller via multiple passes)."""
+    return (x ^ jnp.int32(-2 ** 31)).view(jnp.uint32)
+
+
+def _float32_image(d):
+    """Order-preserving uint32 image of float32 values (sign-magnitude
+    bit flip; -0.0 and 0.0 share one image)."""
+    import jax.numpy as jnp
+
+    d = jnp.where(d == 0.0, jnp.zeros_like(d), d)
+    bits = d.view(jnp.int32)
+    return jnp.where(bits < 0, ~bits,
+                     bits ^ jnp.int32(-2 ** 31)).view(jnp.uint32)
+
+
+def float64_words_ieee(d):
+    """(hi, lo) uint32 words of the order-preserving image of IEEE
+    doubles — the device twin of ``_uint64_key_np``, for backends that
+    hold a float64 as its 64 IEEE bits."""
+    import jax.numpy as jnp
+
+    bits = d.view(jnp.int64)
+    flipped = jnp.where(bits < 0, ~bits, bits ^ jnp.int64(-2 ** 63))
+    return ((flipped >> 32).astype(jnp.uint32),
+            flipped.astype(jnp.uint32))
+
+
+def float64_words_pair(d):
+    """(hi, lo) uint32 words ordering float64 values on a backend that
+    holds them as an unevaluated sum of two float32 (the TPU: its
+    compiler refuses every bitcast OUT of f64).  ``hi`` is the value
+    rounded to float32 — monotone in the value — and ``lo`` the exact
+    remainder, so comparing (hi, lo) lexicographically compares the
+    values."""
+    import jax.numpy as jnp
+
+    hi = d.astype(jnp.float32)
+    lo = jnp.where(jnp.isfinite(hi), d - hi.astype(jnp.float64),
+                   0.0).astype(jnp.float32)
+    return _float32_image(hi), _float32_image(lo)
+
+
+def _float64_words(d):
+    import jax
+
+    if jax.default_backend() == "tpu":
+        return float64_words_pair(d)
+    return float64_words_ieee(d)
+
+
+def _sort_key_device(col: DeviceColumn, desc: bool):
+    """Orderable key fields of one non-string device column:
+    ``[(uint32 array, nbits)]``, most significant first — comparing
+    the fields lexicographically as unsigned integers compares the
+    values in Spark's order (NaN above every double, -0.0 == 0.0).
+    ``desc`` inverts every field; null rows get zeros (their placement
+    is the dominating null-rank field ``key_passes_device`` adds)."""
     import jax.numpy as jnp
 
     tid = col.dtype.id
-    if col.dtype.is_string:
-        raise AssertionError("string keys handled via chunked passes")
     data = col.data
     if tid is T.TypeId.BOOL:
-        u = data.astype(jnp.uint64)
-    elif col.dtype.is_floating:
-        d = data.astype(jnp.float64) if tid is T.TypeId.FLOAT64 \
-            else data.astype(jnp.float32)
+        fields = [(data.astype(jnp.uint32), 1)]
+    elif tid is T.TypeId.FLOAT64:
+        d = data.astype(jnp.float64)
         d = jnp.where(d == 0.0, jnp.zeros_like(d), d)
-        if tid is T.TypeId.FLOAT64:
-            bits = d.view(jnp.int64)
-            sign = bits < 0
-            flipped = jnp.where(sign, ~bits, bits ^ jnp.int64(-2 ** 63))
-            u = flipped.view(jnp.uint64)
-        else:
-            bits = d.view(jnp.int32)
-            sign = bits < 0
-            flipped = jnp.where(sign, ~bits, bits ^ jnp.int32(-2 ** 31))
-            u = flipped.view(jnp.uint32).astype(jnp.uint64)
+        hi, lo = _float64_words(d)
         # NaN sorts last among valids (Spark: NaN > all doubles)
         nan = jnp.isnan(d)
-        u = jnp.where(nan, jnp.uint64(0xFFFFFFFFFFFFFFFE), u)
+        fields = [(jnp.where(nan, jnp.uint32(0xFFFFFFFF), hi), 32),
+                  (jnp.where(nan, jnp.uint32(0xFFFFFFFE), lo), 32)]
+    elif col.dtype.is_floating:
+        d = data.astype(jnp.float32)
+        fields = [(jnp.where(jnp.isnan(d), jnp.uint32(0xFFFFFFFE),
+                             _float32_image(d)), 32)]
+    elif data.dtype.itemsize == 8:
+        x = data.astype(jnp.int64)
+        fields = [(_ordered_u32((x >> 32).astype(jnp.int32)), 32),
+                  (x.astype(jnp.uint32), 32)]
     else:
-        u = (data.astype(jnp.int64) ^ jnp.int64(-2 ** 63)).view(jnp.uint64)
-    if desc:
-        u = ~u
-    # nulls are placed by a separate dominating pass in lexsort_device;
-    # here they just need a deterministic value
-    u = jnp.where(col.validity, u, jnp.uint64(0))
-    return u
+        nbits = 8 * data.dtype.itemsize
+        x = _ordered_u32(data.astype(jnp.int32))
+        if nbits < 32:  # int8/int16: the image fits the narrow field
+            x = x - jnp.uint32(2 ** 31 - 2 ** (nbits - 1))
+        fields = [(x, nbits)]
+    out = []
+    for f, nbits in fields:
+        if desc:
+            f = ~f if nbits == 32 else f ^ jnp.uint32(2 ** nbits - 1)
+        out.append((jnp.where(col.validity, f, jnp.uint32(0)), nbits))
+    return out
+
+
+def _pack_fields(fields):
+    """Greedily pack ``[(uint32 array, nbits)]`` key fields, most
+    significant first, into as few uint32 words as hold them — the
+    order over the words equals the order over the fields, and every
+    word less is a sort pass less."""
+    import jax.numpy as jnp
+
+    words = []
+    cur, used = None, 0
+    for f, nbits in fields:
+        if cur is not None and used + nbits <= 32:
+            cur = (cur << jnp.uint32(nbits)) | f
+            used += nbits
+        else:
+            if cur is not None:
+                words.append(cur)
+            cur, used = f, nbits
+    if cur is not None:
+        words.append(cur)
+    return words
 
 
 def key_passes_device(key_cols: List[DeviceColumn],
                       descending: List[bool] = None,
-                      nulls_first: List[bool] = None):
-    """Order-preserving uint64 pass encoding of multi-column sort keys:
-    comparing rows lexicographically over the passes (passes[0]
+                      nulls_first: List[bool] = None,
+                      pad_valid=None):
+    """Order-preserving uint32 word encoding of multi-column sort keys:
+    comparing rows lexicographically over the words (words[0]
     dominates) == comparing them under the sort order, with desc /
-    null-placement baked into the encoding.  Shared by the lexsort and
-    the device range partitioner (sampled bounds compare)."""
+    null-placement baked into the encoding and rows where ``pad_valid``
+    is False after every other row.  Shared by the lexsort and the
+    device range partitioner (sampled bounds compare)."""
     import jax.numpy as jnp
 
-    n = key_cols[0].data.shape[0]
     if descending is None:
         descending = [False] * len(key_cols)
     if nulls_first is None:
         nulls_first = [True] * len(key_cols)
-    passes = []  # uint64 key passes; passes[0] dominates
+    fields = []  # (uint32 array, nbits); fields[0] dominates
+    if pad_valid is not None:
+        fields.append((jnp.where(pad_valid, jnp.uint32(0),
+                                 jnp.uint32(1)), 1))
     for col, desc, nf in zip(key_cols, descending, nulls_first):
-        # null-placement pass dominates this column's value passes
-        null_rank = jnp.uint64(0) if nf else jnp.uint64(1)
-        valid_rank = jnp.uint64(1) - null_rank
-        passes.append(jnp.where(col.validity, valid_rank, null_rank))
+        # null placement dominates this column's value fields
+        null_rank = jnp.uint32(0 if nf else 1)
+        fields.append((jnp.where(col.validity, jnp.uint32(1) - null_rank,
+                                 null_rank), 1))
         if col.dtype.is_string:
-            w = col.data.shape[1]
-            # chunk 8 bytes per uint64 pass (MSB-first ordering)
-            for start in range(0, w, 8):
-                chunk = col.data[:, start:start + 8]
-                cw = chunk.shape[1]
-                k = jnp.zeros((n,), dtype=jnp.uint64)
-                for b in range(cw):
-                    k = (k << jnp.uint64(8)) | chunk[:, b].astype(jnp.uint64)
-                k = k << jnp.uint64(8 * (8 - cw))
-                if desc:
-                    k = ~k
-                k = jnp.where(col.validity, k, jnp.uint64(0))
-                passes.append(k)
+            flip = jnp.uint32(0xFF if desc else 0)
+            for b in range(col.data.shape[1]):  # one byte per field
+                k = col.data[:, b].astype(jnp.uint32) ^ flip
+                fields.append((jnp.where(col.validity, k,
+                                         jnp.uint32(0)), 8))
         else:
-            passes.append(_sort_key_device(col, desc, nf))
-    return passes
+            fields.extend(_sort_key_device(col, desc))
+    return _pack_fields(fields)
 
 
 def lexsort_device(key_cols: List[DeviceColumn],
@@ -300,36 +374,43 @@ def lexsort_device(key_cols: List[DeviceColumn],
                    pad_valid=None):
     """Stable multi-key argsort on device.  Padding rows (pad_valid False)
     always sort last.  Returns int32 permutation."""
-    import jax.numpy as jnp
-
     n = key_cols[0].data.shape[0] if key_cols else pad_valid.shape[0]
-    passes = key_passes_device(key_cols, descending, nulls_first)
-    if pad_valid is not None:
-        passes.insert(0, jnp.where(pad_valid, jnp.uint64(0),
-                                   jnp.uint64(2 ** 64 - 1)))
-    return sort_permutation(passes, n)
+    return sort_permutation(
+        key_passes_device(key_cols, descending, nulls_first, pad_valid), n)
 
 
-def sort_permutation(passes, n: int):
-    """int32 permutation ordering rows lexicographically by the uint64
-    ``passes`` (passes[0] dominates), stable.
+def sort_permutation(words, n: int):
+    """int32 permutation ordering rows lexicographically by the uint32
+    ``words`` (words[0] dominates), stable.
 
-    One VARIADIC ``lax.sort`` call (num_keys = all passes) instead of a
-    per-pass argsort+gather chain: XLA sorts all key operands
-    lexicographically in a single kernel — one sorting-network launch
-    on TPU, one comparator sort on CPU, vs k of each before.  Payload
-    columns deliberately ride OUTSIDE the sort (gather by the returned
-    permutation): payload operands inside the comparator are ~3x
-    slower than sort+gather (measured on XLA CPU)."""
+    A least-significant-word-first chain of stable single-key sorts,
+    written as a loop over the stacked words so the program holds the
+    same one-key sort however many words the key has.  The TPU
+    compiler's time for a sort grows steeply with the comparator's key
+    count (2^20 rows for v5e: one 32-bit key 21 s, two 47 s, the six
+    that three 64-bit passes come to 243 s; this loop over six words
+    30 s), and a cold query holds dozens of sorts."""
     import jax.numpy as jnp
     from jax import lax
 
-    if not passes:
-        return jnp.arange(n, dtype=jnp.int32)
     iota = jnp.arange(n, dtype=jnp.int32)
-    res = lax.sort(tuple(passes) + (iota,), dimension=0,
-                   is_stable=True, num_keys=len(passes))
-    return res[-1]
+    if not words:
+        return iota
+
+    def sort_by(key, perm):
+        return lax.sort((key, perm), dimension=0, is_stable=True,
+                        num_keys=1)[1]
+
+    # the least significant word sorts outside the loop: under
+    # shard_map the carry must start as shard-varying as it ends
+    order = sort_by(words[-1], iota)
+    if len(words) == 1:
+        return order
+    stacked = jnp.stack(words[:-1])
+    last = len(words) - 2
+    return lax.fori_loop(
+        0, len(words) - 1,
+        lambda i, perm: sort_by(stacked[last - i][perm], perm), order)
 
 
 def segment_ids_device(sorted_keys: List[DeviceColumn], pad_valid=None):
@@ -372,7 +453,7 @@ def segment_ids_device(sorted_keys: List[DeviceColumn], pad_valid=None):
     if pad_valid is not None:
         # every padding row becomes its own segment so it never merges
         change = change | ~pad_valid
-    return (jnp.cumsum(change.astype(jnp.int32)) - 1).astype(jnp.int32)
+    return prefix_sum(change.astype(jnp.int32)) - 1
 
 
 def segment_pick_device(eligible, seg_ids, n_segments: int, op: str):

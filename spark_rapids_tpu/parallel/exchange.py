@@ -25,6 +25,7 @@ from __future__ import annotations
 from typing import List, Tuple
 
 from ..data.column import DeviceBatch, DeviceColumn
+from ..ops.kernels.gather import partition_order
 from ..utils import hashing
 
 
@@ -105,8 +106,7 @@ def _compact(batch_cols: List[DeviceColumn], present, schema) -> DeviceBatch:
     DeviceBatch (logical rows first, padding after)."""
     import jax.numpy as jnp
 
-    n = present.shape[0]
-    order = jnp.argsort(~present, stable=True).astype(jnp.int32)
+    order = partition_order(present)
     num_rows = present.sum().astype(jnp.int32)
     out = []
     for c in batch_cols:
@@ -182,21 +182,19 @@ def exchange_step(mesh, fn):
     a dead peer or a tripped ``fault.peer.collectiveTimeoutMs`` aborts
     with ``TpuPeerLost`` instead of hanging — and its wall clock
     accrues to ``shuffle.collectiveTime``."""
+    import jax
     from jax.sharding import PartitionSpec as P
 
     from ..shuffle.device_shuffle import collective_timer
-    from ._compat import get_shard_map
     from .elastic import guarded_call
-
-    shard_map = get_shard_map()
 
     axis = mesh.axis_names[0]
 
     def per_shard(stacked: DeviceBatch) -> DeviceBatch:
         return unsqueeze_leading(fn(squeeze_leading(stacked)))
 
-    step = shard_map(per_shard, mesh=mesh, in_specs=P(axis),
-                     out_specs=P(axis))
+    step = jax.shard_map(per_shard, mesh=mesh, in_specs=P(axis),
+                         out_specs=P(axis))
 
     def dispatch(stacked: DeviceBatch) -> DeviceBatch:
         def timed(stacked=stacked):

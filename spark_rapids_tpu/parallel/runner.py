@@ -69,7 +69,7 @@ def _max_dest_count(pids, num_parts: int):
     import jax.numpy as jnp
 
     counts = jax.ops.segment_sum(
-        jnp.ones_like(pids, dtype=jnp.int64), pids,
+        jnp.ones_like(pids, dtype=jnp.int32), pids,
         num_segments=num_parts + 1)
     return counts[:num_parts].max()
 
@@ -138,6 +138,10 @@ class DistributedRunner:
         #: pluggable exchange data path (reference: makeTransport
         #: reflection on spark.rapids.shuffle.transport.class)
         self.transport = transport or IciCollectiveTransport(self.axis)
+        #: ids of the devices that hold a shard of a placed leaf — a
+        #: mesh run that never left device 0 shows here, as
+        #: ``distributed.numShardDevices`` in the session's metrics
+        self.shard_device_ids: set = set()
 
     # ---------------- fault tolerance ---------------------------------
     @staticmethod
@@ -412,7 +416,10 @@ class DistributedRunner:
                       else _empty_batch(node.schema)
                       for bs in shard_lists]
         shards = self._verify_host_roundtrip(shards, ctx)
-        return self._place(self._stack_host(shards))
+        placed = self._place(self._stack_host(shards))
+        self.shard_device_ids.update(
+            s.device.id for s in placed.num_rows.addressable_shards)
+        return placed
 
     def _place(self, stacked: DeviceBatch) -> DeviceBatch:
         """Put a host-stacked [n, ...] batch onto the mesh (overridden
@@ -469,7 +476,6 @@ class DistributedRunner:
         the drop sentinel."""
         import jax.numpy as jnp
 
-        from ..ops.expression import as_device_column, bind_references
         from ..shuffle.partitioning import (HashPartitioning,
                                             RangePartitioning,
                                             RoundRobinPartitioning,
@@ -482,11 +488,7 @@ class DistributedRunner:
         elif isinstance(part, RoundRobinPartitioning):
             pids = (jnp.arange(batch.padded_rows, dtype=jnp.int32) % n)
         elif isinstance(part, HashPartitioning):
-            bound = [bind_references(k, exch.schema) for k in part.keys]
-            cols = [as_device_column(k.eval_tpu(batch), batch.padded_rows)
-                    for k in bound]
-            pids = hashing.pmod(hashing.hash_device_batch(cols),
-                                n).astype(jnp.int32)
+            return self._hash_pids_by_exprs(batch, part.keys, exch.schema)
         elif isinstance(part, RangePartitioning):
             # sampled device bounds (reference:
             # GpuRangePartitioner.scala:33-104) — the same traced
@@ -520,11 +522,22 @@ class DistributedRunner:
         return self.transport.exchange(batch, pids, self.n)
 
     def _hash_pids_by_exprs(self, batch: DeviceBatch, exprs, schema):
+        """Hash partition ids on expression keys — the one place the
+        runner hashes, for the planned exchanges and for the ones it
+        adds itself (join-colocation repair, complete-mode aggregates,
+        window partition-by), whose keys no plan rule looked at: a key
+        the device cannot hash the way Spark does ends the lowering
+        with its reason before anything is traced for it."""
         import jax.numpy as jnp
 
         from ..ops.expression import as_device_column, bind_references
 
         bound = [bind_references(k, schema) for k in exprs]
+        for k in bound:
+            gap = hashing.device_hash_gap(k.dtype)
+            if gap is not None:
+                raise DistributedUnsupported(
+                    f"mesh hash exchange on {k.sql()}: {gap}")
         cols = [as_device_column(k.eval_tpu(batch), batch.padded_rows)
                 for k in bound]
         pids = hashing.pmod(hashing.hash_device_batch(cols),
@@ -542,7 +555,7 @@ class DistributedRunner:
     def _range_pids(self, batch: DeviceBatch, sort_keys):
         """Traced device range partitioning (reference:
         GpuRangePartitioner.scala:33-104 — sample, bounds, device bound
-        compare).  Per shard: strided sample of the sort-key uint64
+        compare).  Per shard: strided sample of the sort-key uint32
         passes; `all_gather` so every shard sees every sample; global
         quantile bounds; pid = #bounds the row exceeds
         lexicographically.
@@ -579,8 +592,7 @@ class DistributedRunner:
         n_samp = g.shape[1]
 
         # sort samples (invalid last) exactly like the lexsort
-        sample_passes = [jnp.where(gv, jnp.uint64(0),
-                                   jnp.uint64(2 ** 64 - 1))] + \
+        sample_passes = [jnp.where(gv, jnp.uint32(0), jnp.uint32(1))] + \
             [g[i] for i in range(g.shape[0])]
         order = seg.sort_permutation(sample_passes, n_samp)
 
@@ -940,19 +952,23 @@ class DistributedRunner:
                 out.append((node[0], node[2]))
 
     def _run_program(self, root, env_stacked: Dict, caps: Dict,
-                     post=None) -> DeviceBatch:
+                     what: str, post=None) -> DeviceBatch:
         """jit + shard_map the lowering of ``root``; retries with grown
         capacities on collective overflow.  ``post`` (traced hook) runs
         on the per-shard output before unstacking — the broadcast
-        precompute passes the replicate here."""
+        precompute passes the replicate here.
+
+        Every attempt is its own program (capacities are shapes), so it
+        compiles again: each is logged under ``what`` as it is
+        dispatched and as it answers, with its seconds and the demands
+        that overflowed — a run that is cut short still says which
+        program it was in, on which attempt."""
         import jax
+        import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
         from ..shuffle.device_shuffle import collective_timer
-        from ._compat import get_shard_map
         from .elastic import guarded_call
-
-        shard_map = get_shard_map()
 
         # fault checkpoint at the stage boundary (host side, inside the
         # watchdog-timed region): delay injections become stragglers
@@ -969,8 +985,13 @@ class DistributedRunner:
         self._collect_aux_keys(root, aux_keys, cut_broadcast=True)
         aux_keys = sorted(aux_keys)
 
-        for _attempt in range(_MAX_JOIN_RETRIES):
+        for attempt in range(_MAX_JOIN_RETRIES):
             used_caps: Dict = {}
+            t0 = time.perf_counter()
+            log.info("%s attempt %d: dispatching (%d inputs, up to %d "
+                     "rows a shard)", what, attempt, len(ins),
+                     max((b.columns[0].data.shape[1] for b in ins),
+                         default=0))
 
             def per_shard(*stacked):
                 env = {k: X.squeeze_leading(b)
@@ -982,13 +1003,19 @@ class DistributedRunner:
                 # aux (capacity demands) replicated via pmax so EVERY
                 # controller process reads the same overflow verdict and
                 # takes the same retry path (multi-process SPMD needs
-                # identical host control flow on all controllers)
+                # identical host control flow on all controllers).
+                # As int32, saturated: the TPU compiler lowers no 64-bit
+                # all-reduce but a sum ("Supported lowering only of Sum
+                # all reduce"), and a row count past 2^31 must still
+                # read as an overflow
                 return (X.unsqueeze_leading(out),
-                        tuple(jax.lax.pmax(aux[k].reshape(()), self.axis)
-                              for k in aux_keys))
+                        tuple(jax.lax.pmax(
+                            jnp.minimum(aux[k].reshape(()), 2 ** 31 - 1)
+                            .astype(jnp.int32), self.axis)
+                            for k in aux_keys))
 
             spec = P(self.axis)
-            spmd = jax.jit(shard_map(
+            spmd = jax.jit(jax.shard_map(
                 per_shard, mesh=self.mesh,
                 in_specs=(spec,) * len(ins),
                 out_specs=(spec, (P(),) * len(aux_keys))))
@@ -1008,15 +1035,22 @@ class DistributedRunner:
                 out, aux_vals = guarded_call(
                     lambda spmd=spmd, ins=tuple(ins): spmd(*ins),
                     site="stage.dispatch")
-            overflow = False
+            overflow = {}
             for k, v in zip(aux_keys, aux_vals):
                 total = int(np.asarray(v))
                 if total > used_caps.get(k, 0):
                     caps[k] = bucket_rows(total, self.min_bucket)
-                    overflow = True
+                    overflow[k.rstrip("0123456789")] = (
+                        used_caps.get(k, 0), total)
+            log.info("%s attempt %d: answered in %.1f s (trace, compile "
+                     "and run)%s", what, attempt,
+                     time.perf_counter() - t0,
+                     f"; (capacity, demand) overflowed: {overflow}"
+                     if overflow else "")
             if not overflow:
                 return out
-        raise RuntimeError("collective capacity retries exhausted")
+        raise RuntimeError(
+            f"{what}: collective capacity retries exhausted")
 
     @staticmethod
     def _has_collective(node) -> bool:
@@ -1046,21 +1080,26 @@ class DistributedRunner:
         GpuBroadcastExchangeExec.scala:215-247)."""
         ops: List = []
         self._collect_broadcasts(stage.root, ops)
-        for op, build_kid in ops:
+        for i, (op, build_kid) in enumerate(ops):
             key = f"bcast{id(op)}"
             if key in env_stacked:
                 continue
-            env_stacked[key] = self._run_program(
+            # replicated rows are front-packed like any stage output
+            # (every shard holds all of them, num_rows alike), so the
+            # same trim applies: the consuming stage is traced at the
+            # build side's row count, not at n_shards x its bucket
+            env_stacked[key] = self._retile(self._run_program(
                 build_kid, env_stacked, caps,
-                post=self.transport.replicate)
+                f"stage[{stage.sid}].broadcast[{i}]",
+                post=self.transport.replicate))
 
     def _run_stage(self, stage: _Stage, env_stacked: Dict,
                    caps: Dict) -> DeviceBatch:
         """jit + shard_map one stage; returns the stacked output batch.
         Retries with doubled join capacity on overflow."""
         self._prepare_broadcasts(stage, env_stacked, caps)
-        return self._retile(
-            self._run_program(stage.root, env_stacked, caps))
+        return self._retile(self._run_program(
+            stage.root, env_stacked, caps, f"stage[{stage.sid}]"))
 
     def _retile(self, stacked: DeviceBatch) -> DeviceBatch:
         """Host-side bucket trim between stages: shapes grow through
@@ -1073,7 +1112,11 @@ class DistributedRunner:
         self._last_stage_rows = nrows
         need = bucket_rows(int(nrows.max()) if nrows.size else 1,
                            self.min_bucket)
-        if need >= stacked.padded_rows:
+        # a stacked batch is [n_shards, padded, ...]: the row bucket is
+        # axis 1, not ``padded_rows`` (axis 0, the shard count, which no
+        # bucket is below: compared with that, nothing is ever trimmed)
+        if not stacked.columns or \
+                need >= stacked.columns[0].data.shape[1]:
             return stacked
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1328,10 +1371,10 @@ def run_distributed(session, df, mesh=None, n_devices: int = 8,
     axis = mesh.axis_names[0] if mesh.axis_names else _AX
     prev_deadline = elastic.install_collective_deadline(
         session.conf.get(FAULT_PEER_COLLECTIVE_TIMEOUT_MS))
+    runner = DistributedRunner(
+        mesh, transport=make_transport(session.conf, axis))
     try:
-        return DistributedRunner(
-            mesh,
-            transport=make_transport(session.conf, axis)).run(phys, ctx)
+        return runner.run(phys, ctx)
     finally:
         elastic.install_collective_deadline(prev_deadline)
         # the fault counters must be visible even on a direct
@@ -1339,6 +1382,8 @@ def run_distributed(session, df, mesh=None, n_devices: int = 8,
         session.last_metrics = dict(
             getattr(session, "last_metrics", None) or {})
         session.last_metrics.update(_fault_stats.snapshot())
+        session.last_metrics["distributed.numShardDevices"] = \
+            len(runner.shard_device_ids)
         from ..shuffle.device_shuffle import GLOBAL as _shuffle_stats
 
         session.last_metrics.update(_shuffle_stats.metrics_since(

@@ -239,12 +239,39 @@ def hash_bytes_jnp(byte_mat, lengths, seed):
     return fmix(h1, lengths.astype(jnp.uint32))
 
 
+def device_hash_gap(dtype):
+    """Why the device cannot hash ``dtype`` the way Spark does, or None
+    when it can.  The plan rules of every operator that hashes keys on
+    the device (hash exchange, hash join) tag themselves with this, so
+    the gap shows in ``explain()`` and never at a dispatch.
+
+    The one gap: FLOAT64 on the TPU backend.  Spark hashes the 64 IEEE
+    bits of a double; the TPU holds a float64 as an unevaluated sum of
+    two float32 (about 48 mantissa bits, float32's exponent range), so
+    those bits do not exist on the device — its compiler answers every
+    bitcast out of f64 with "UNIMPLEMENTED: While rewriting computation
+    to not contain X64 element types, XLA encountered an HLO for which
+    this rewriting is not implemented: ... bitcast-convert"."""
+    import jax
+
+    from ..types import TypeId
+
+    if dtype.id is TypeId.FLOAT64 and jax.default_backend() == "tpu":
+        return ("Spark's hash of a FLOAT64 key needs its 64 IEEE bits, "
+                "which the TPU does not hold (f64 is a pair of f32 there "
+                "and XLA cannot bitcast it)")
+    return None
+
+
 def hash_device_column(col, seed):
     """Fold one DeviceColumn into a running per-row uint32 hash (traced)."""
     import jax.numpy as jnp
 
     from ..types import TypeId
 
+    gap = device_hash_gap(col.dtype)
+    if gap is not None:
+        raise TypeError(gap)
     tid = col.dtype.id
     if tid in (TypeId.INT8, TypeId.INT16, TypeId.INT32, TypeId.DATE32,
                TypeId.BOOL):

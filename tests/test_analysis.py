@@ -584,7 +584,7 @@ def test_bench_refuses_artifacts_on_new_findings(tmp_path, monkeypatch):
         import bench
     finally:
         sys.path.remove(REPO_ROOT)
-    p = tmp_path / "BENCH_TPU_LAST.json"
+    p = tmp_path / "BENCH_LAST.json"
     monkeypatch.setattr(bench, "_ANALYSIS_GATE", False)
     bench._persist_tpu_artifact({"suite": "x"}, path=str(p))
     assert not p.exists(), "artifact written despite failed gate"

@@ -179,6 +179,76 @@ def test_distributed_tpch_query(qnum):
     _assert_rows_equal(got, exp)
 
 
+def test_retile_trims_to_the_row_bucket_not_the_shard_count():
+    """A stacked stage output is [n_shards, padded, ...].  The trim
+    between stages compares the rows' bucket with axis 1; read from
+    axis 0 (the shard count, below every bucket) it never trimmed, and
+    each stage handed its capacity growth on to the next — PR 21 saw
+    q3 at 2^25 rows a shard for 10 rows of answer."""
+    from spark_rapids_tpu.parallel import exchange as X
+    from spark_rapids_tpu.parallel.runner import DistributedRunner
+
+    schema = T.Schema([T.Field("k", T.INT64), T.Field("s", T.STRING)])
+    counts = [3, 1, 0, 200]
+    shards = [HostBatch.from_pydict(
+        {"k": np.arange(n), "s": [f"r{i}" for i in range(n)]}, schema)
+        for n in counts]
+    mesh = _mesh(4)
+    wide = DistributedRunner(mesh, min_bucket_rows=4096)
+    stacked = wide._place(wide._stack_host(shards))
+    assert stacked.columns[0].data.shape[:2] == (4, 4096)
+    runner = DistributedRunner(mesh)
+
+    out = runner._retile(stacked)
+    for c in out.columns:
+        assert c.data.shape[:2] == (4, 256) and \
+            c.validity.shape == (4, 256), c.data.shape
+    assert out.columns[1].lengths.shape == (4, 256)
+    assert len({s.device.id
+                for s in out.columns[0].data.addressable_shards}) == 4
+    for n, part in zip(counts, X.unstack_partitions(out)):
+        hb = device_to_host(part)
+        assert hb.column("k").to_pylist() == list(range(n))
+        assert hb.column("s").to_pylist() == [f"r{i}" for i in range(n)]
+    # already at its bucket: handed back as it is
+    assert runner._retile(out) is out
+
+
+def test_distributed_stages_run_at_the_trimmed_width(caplog):
+    """End to end: after a join and an aggregate have shrunk the rows,
+    the later stage programs — and a broadcast build side — are
+    dispatched at the bucket of what is left, not at the capacities the
+    earlier stages grew to."""
+    import logging
+    import re
+
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.benchmarks import tpch, tpch_datagen
+    from spark_rapids_tpu.parallel.runner import run_distributed
+
+    sess = Session()
+    tables = tpch_datagen.dataframes(sess, sf=0.002, seed=7)
+    with caplog.at_level(logging.INFO,
+                         logger="spark_rapids_tpu.parallel.runner"):
+        got = run_distributed(sess, tpch.QUERIES[5](tables),
+                              mesh=_mesh(4)).to_rows()
+    assert got
+    widths = {m.group(1): int(m.group(2)) for m in (
+        re.match(r"(stage\[\d+\](?:\.broadcast\[\d+\])?) attempt 0: "
+                 r"dispatching \(\d+ inputs, up to (\d+) rows", r.message)
+        for r in caplog.records) if m}
+    last = max(int(k[6:-1]) for k in widths if k.endswith("]")
+               and ".broadcast" not in k)
+    # q5 ends in five groups: the stages after the aggregate's exchange
+    assert widths[f"stage[{last}]"] == 128, widths
+    assert widths[f"stage[{last - 1}]"] == 128, widths
+    # every program answered on its first attempt and said so
+    answered = [r.message for r in caplog.records
+                if "answered in" in r.message]
+    assert len(answered) == len(widths) and \
+        not any("overflowed" in a for a in answered), answered
+
+
 def test_distributed_broadcast_build_reused_across_retries():
     """One all_gather of the broadcast build side per query: the
     replicated batch is precomputed outside the stage retry loop, so a

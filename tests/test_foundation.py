@@ -155,41 +155,37 @@ def test_conf_registry_and_docs():
     assert "spark.rapids.tpu.sql.enabled" in md
 
 
-def test_packed_upload_roundtrip():
-    """host_to_device packs every array into ONE transfer; the unpack
-    (slice + bitcast) must be byte-exact for every dtype family."""
+def test_upload_roundtrip_every_dtype_family():
+    """host_to_device sends a batch's arrays up in one batched put; the
+    round trip through the device must be exact for every dtype family
+    (validity, string byte matrices and lengths included)."""
     import numpy as np
 
     from spark_rapids_tpu import types as T
-    from spark_rapids_tpu.data.column import (HostBatch, device_to_host,
-                                              host_to_device,
-                                              packed_upload)
-    import jax
+    from spark_rapids_tpu.data.column import (HostBatch, HostColumn,
+                                              device_to_host,
+                                              host_to_device)
 
-    probe = [np.asarray([-1, 0, 2**62], dtype=np.int64),
-             np.asarray([1.5, -0.0, float("nan")], dtype=np.float64),
-             np.asarray([True, False]),
-             np.arange(9, dtype=np.uint8).reshape(3, 3),
-             np.asarray([7, -7], dtype=np.int32),
-             np.asarray([-1, -128, 127], dtype=np.int8)]
-    got = jax.device_get(packed_upload(probe))
-    for a, o in zip(probe, got):
-        np.testing.assert_array_equal(a, np.asarray(o))
+    cols = {
+        "i64": np.asarray([-1, 0, 2**62], dtype=np.int64),
+        "f64": np.asarray([1.5, -0.0, float("nan")], dtype=np.float64),
+        "i32": np.asarray([7, -7, 0], dtype=np.int32),
+        "i8": np.asarray([-1, -128, 127], dtype=np.int8),
+        "b": np.asarray([True, False, True]),
+    }
+    hb = HostBatch(T.Schema([T.Field(n, T.from_numpy(a.dtype))
+                             for n, a in cols.items()]),
+                   [HostColumn.from_numpy(a) for a in cols.values()])
+    rt = device_to_host(host_to_device(hb))
+    for a, c in zip(cols.values(), rt.columns):
+        np.testing.assert_array_equal(a, c.data)
+        assert c.data.dtype == a.dtype
 
     hb = HostBatch.from_pydict({
         "i": [1, None, 3], "f": [0.5, 2.5, None],
         "s": ["ab", None, "xyz"], "b": [True, False, None],
     })
-    # force the packed path (auto mode disables it on the CPU backend)
-    from spark_rapids_tpu.data import column as dcol
-
-    old = dict(dcol._PACK_STATE)
-    dcol._PACK_STATE.update({"mode": "1", "enabled": True,
-                             "verified": False})
-    try:
-        rt = device_to_host(host_to_device(hb))
-    finally:
-        dcol._PACK_STATE.update(old)
+    rt = device_to_host(host_to_device(hb))
     assert rt.to_rows() == hb.to_rows()
 
 

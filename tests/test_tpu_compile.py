@@ -1,0 +1,363 @@
+"""The chip's compiler, asked from the CPU sandbox.
+
+The TPU's compiler is installed wherever jax[tpu] is, and compiles for a
+chip that is described and not attached (``on-chip-measurement`` §2.3).
+These tests hand it a few programs of the served path — at 2^14 rows, a
+few seconds each — so that what it refuses (PR 21: every bitcast out of
+f64, which the chip holds as two f32) or bloats fails here, on every PR,
+at no chip time.  Nothing runs on a device and nothing here is a timing.
+
+Everything that touches the topology lives in module-scoped fixtures that
+are not autouse: only the xdist worker that is given this file loads the
+TPU library, and a worker that cannot describe the chip skips.
+"""
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+from conftest import REPO, cpu_worker_env
+
+import spark_rapids_tpu  # noqa: F401  (x64 on, pytrees registered)
+from spark_rapids_tpu import types as T
+from spark_rapids_tpu.data.column import DeviceBatch, DeviceColumn
+
+ROWS = 1 << 14
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """Four described v5e chips (2x2), with jax's persistent cache off
+    around the module (an entry compiled for a described chip is
+    written but cannot be read back without one)."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def as_tpu(monkeypatch):
+    """The engine picks its float64 paths from ``jax.default_backend()``,
+    which is ``cpu`` in this process: make it answer ``tpu`` so the
+    chip's branch is the one traced."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _shape(one_chip, shape, dtype):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=one_chip)
+
+
+def _column(one_chip, dtype, width=None):
+    """A DeviceColumn of shapes (no arrays: a described chip holds none)."""
+    valid = _shape(one_chip, (ROWS,), np.bool_)
+    if dtype.is_string:
+        return DeviceColumn(dtype, _shape(one_chip, (ROWS, width), np.uint8),
+                            valid, _shape(one_chip, (ROWS,), np.int32))
+    return DeviceColumn(dtype, _shape(one_chip, (ROWS,), dtype.np_dtype),
+                        valid)
+
+
+def _compile(fn, *args):
+    import jax
+
+    return jax.jit(fn).lower(*args).compile()
+
+
+# --------------------------------------------------------------------------
+# sort keys and hashes: every dtype family the chip treats differently
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype,width", [(T.FLOAT64, None), (T.INT64, None),
+                                         (T.STRING, 25)],
+                         ids=["float64", "int64", "string"])
+def test_lexsort_compiles_for_v5e(one_chip, as_tpu, dtype, width):
+    """The device lexsort (sort, group-by, join and range exchange all
+    go through it) keyed on each dtype branch of the key encoding, one
+    descending nulls-last column like q3's ``ORDER BY revenue DESC``."""
+    from spark_rapids_tpu.ops.kernels import segment as seg
+
+    def order(col, pad_valid):
+        return seg.lexsort_device([col], [True], [False], pad_valid)
+
+    compiled = _compile(order, _column(one_chip, dtype, width),
+                        _shape(one_chip, (ROWS,), np.bool_))
+    # one sort in the program however many words the key has
+    assert compiled.as_text().count(" sort(") <= 2
+
+
+def test_bitcast_out_of_float64_is_refused_by_the_chip(one_chip):
+    """The finding the float64 paths are built around.  If a later
+    compiler accepts this, the IEEE-image path can serve the chip too
+    and ``float64_words_pair`` / ``device_hash_gap`` can go."""
+    from spark_rapids_tpu.ops.kernels import segment as seg
+
+    with pytest.raises(Exception, match="X64 element types"):
+        _compile(seg.float64_words_ieee,
+                 _shape(one_chip, (ROWS,), np.float64))
+
+
+def test_int64_device_hash_compiles_and_float64_is_tagged(one_chip,
+                                                           as_tpu):
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.utils import hashing
+
+    seed = _shape(one_chip, (ROWS,), np.uint32)
+    _compile(hashing.hash_device_column, _column(one_chip, T.INT64), seed)
+    _compile(hashing.hash_device_column, _column(one_chip, T.STRING, 25),
+             seed)
+    # float64: no Spark-compatible hash exists on the chip — the plan
+    # rules tag it (hashing.device_hash_gap), a stray trace raises
+    assert "IEEE" in hashing.device_hash_gap(T.FLOAT64)
+    assert hashing.device_hash_gap(T.INT64) is None
+    with pytest.raises(TypeError, match="IEEE"):
+        hashing.hash_device_column(
+            DeviceColumn(T.FLOAT64, jnp.zeros((8,)),
+                         jnp.ones((8,), jnp.bool_)),
+            jnp.zeros((8,), jnp.uint32))
+
+
+def test_float64_hash_key_is_tagged_at_plan_time(as_tpu):
+    """On the chip a hash exchange keyed on a double stays on the host
+    engine and says why in ``explain()`` — never a failed dispatch."""
+    import spark_rapids_tpu as srt
+    from spark_rapids_tpu import f
+
+    sess = srt.Session()
+    df = sess.create_dataframe({"k": [1.5, 2.5, 1.5], "v": [1, 2, 3]})
+    report = df.group_by("k").agg(f.sum("v")).explain()
+    tagged = [ln for ln in report.splitlines()
+              if "ShuffleExchangeExec" in ln]
+    assert tagged and all(ln.strip().startswith("!") and "IEEE" in ln
+                          for ln in tagged), report
+    keyed_on_int = df.group_by("v").agg(f.sum("k")).explain()
+    assert "* ShuffleExchangeExec" in keyed_on_int, keyed_on_int
+
+
+def test_mesh_runner_names_the_float64_hash_gap(as_tpu):
+    """The mesh runner adds hash exchanges of its own (join-colocation
+    repair, complete-mode aggregates, window partition-by) on keys no
+    plan rule looked at.  Here the planned exchange on the double is
+    tagged to the host, the window above it stays on the device, and
+    the runner would re-exchange on the double itself: that ends the
+    lowering with the reason, not with a TypeError inside a trace."""
+    import spark_rapids_tpu as srt
+    from spark_rapids_tpu.ops.windowexprs import over, row_number, window
+    from spark_rapids_tpu.parallel.runner import (DistributedUnsupported,
+                                                  run_distributed)
+
+    sess = srt.Session()
+    df = sess.create_dataframe({"k": [1.5, 2.5, 1.5, 2.5],
+                                "t": [1, 2, 3, 4]}, n_partitions=2)
+    keyed_on_double = df.with_window("w", over(
+        row_number(), window().partition_by("k").order_by("t")))
+    report = keyed_on_double.explain()
+    assert "* WindowExec" in report and "! ShuffleExchangeExec" in report
+    with pytest.raises(DistributedUnsupported, match="IEEE"):
+        run_distributed(sess, keyed_on_double, n_devices=4)
+    keyed_on_int = df.with_window("w", over(
+        row_number(), window().partition_by("t").order_by("k")))
+    assert sorted(run_distributed(sess, keyed_on_int,
+                                  n_devices=4).to_rows()) == [
+        (1.5, 1, 1), (1.5, 3, 1), (2.5, 2, 1), (2.5, 4, 1)]
+
+
+# --------------------------------------------------------------------------
+# compaction and the q1 pipeline
+# --------------------------------------------------------------------------
+def test_compaction_compiles_with_small_temp(one_chip):
+    """Filter compaction (prefix sum + one scatter, no sort) over an
+    f64 + i32 + bool batch: the program's temp must stay a small
+    multiple of its input (a lane-padded layout once cost 512 B/row)."""
+    from spark_rapids_tpu.ops.kernels.gather import compact
+
+    schema = T.Schema([T.Field("a", T.FLOAT64), T.Field("b", T.INT32),
+                       T.Field("c", T.BOOL)])
+    batch = DeviceBatch(schema, [_column(one_chip, f.dtype)
+                                 for f in schema],
+                        _shape(one_chip, (), np.int32))
+    compiled = _compile(compact, batch,
+                        _shape(one_chip, (ROWS,), np.bool_))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 4 * mem.argument_size_in_bytes, mem
+    assert " sort(" not in compiled.as_text()
+
+
+def test_q1_pipeline_compiles_for_v5e(one_chip, as_tpu):
+    """The whole flagship q1 chain — fused filter/project segment,
+    sort-based partial aggregate, final aggregate — as one program."""
+    import jax
+
+    from spark_rapids_tpu.models.flagship import build_q1_pipeline
+
+    fn, example = build_q1_pipeline(n_rows=ROWS, seed=0)
+    shapes = jax.tree_util.tree_map(
+        lambda a: _shape(one_chip, a.shape, a.dtype), example)
+    mem = _compile(fn, shapes).memory_analysis()
+    assert mem.temp_size_in_bytes <= 64 * mem.argument_size_in_bytes, mem
+
+
+def test_mesh_exchange_compiles_for_four_chips(topo):
+    """The distributed runner's stage shape — a collective exchange
+    (``all_to_all`` of i64 and f64 tiles) plus the capacity demand
+    replicated with ``pmax`` — for a 2x2 mesh.  The demand goes as
+    int32: the chip's compiler lowers no 64-bit all-reduce but a sum."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_rapids_tpu.parallel import exchange as X
+    from spark_rapids_tpu.parallel.runner import _max_dest_count
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("dp",))
+    spread = NamedSharding(mesh, P("dp"))
+    schema = T.Schema([T.Field("k", T.INT64), T.Field("v", T.FLOAT64)])
+
+    def stacked(shape, dtype):
+        return jax.ShapeDtypeStruct((4,) + shape, np.dtype(dtype),
+                                    sharding=spread)
+
+    batch = DeviceBatch(
+        schema, [DeviceColumn(f.dtype, stacked((ROWS,), f.dtype.np_dtype),
+                              stacked((ROWS,), np.bool_))
+                 for f in schema], stacked((), np.int32))
+
+    def stage(demand_dtype):
+        def per_shard(b):
+            b = X.squeeze_leading(b)
+            pids = X.device_partition_ids(b, [0], 4)
+            out = X.collective_exchange(b, pids, 4, "dp", capacity=ROWS)
+            demand = _max_dest_count(pids, 4).astype(demand_dtype)
+            return X.unsqueeze_leading(out), jax.lax.pmax(demand, "dp")
+
+        return jax.shard_map(per_shard, mesh=mesh, in_specs=P("dp"),
+                             out_specs=(P("dp"), P()))
+
+    assert "all-to-all(" in _compile(stage(jnp.int32), batch).as_text()
+    with pytest.raises(Exception, match="Sum all reduce"):
+        _compile(stage(jnp.int64), batch)
+
+
+# --------------------------------------------------------------------------
+# what the chip's float64 branch computes (runs on the CPU backend)
+# --------------------------------------------------------------------------
+def test_float64_pair_words_order_like_the_oracle(as_tpu):
+    """``float64_words_pair`` on doubles that a pair of float32 holds
+    exactly — all the chip can hold — orders them as the numpy oracle
+    orders their IEEE images, NaN, infinities and signed zeros
+    included, ascending and descending."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.data.column import HostColumn
+    from spark_rapids_tpu.ops.kernels import segment as seg
+
+    rng = np.random.default_rng(3)
+    hi = (rng.standard_normal(4000) * 1e4).astype(np.float32)
+    lo = (hi * rng.uniform(-1, 1, 4000) * 2.0 ** -26).astype(np.float32)
+    vals = np.concatenate([
+        hi.astype(np.float64) + lo.astype(np.float64),
+        hi[:500].astype(np.float64),  # equal hi words, lo decides
+        [0.0, -0.0, np.inf, -np.inf, np.nan, np.nan, 1.0, 1.0]])
+    valid = rng.random(len(vals)) > 0.05
+    col = DeviceColumn(T.FLOAT64, jnp.asarray(vals), jnp.asarray(valid))
+    host = HostColumn(T.FLOAT64, vals, valid)
+    for desc in (False, True):
+        got = np.asarray(seg.lexsort_device([col], [desc], [not desc]))
+        want = seg.lexsort_np([host], [desc], [not desc])
+        np.testing.assert_array_equal(got, want)
+
+
+# --------------------------------------------------------------------------
+# the compile cache's one rule, and the smoke's rehearsal
+# --------------------------------------------------------------------------
+def test_compile_cache_dir_rule(monkeypatch, tmp_path):
+    from spark_rapids_tpu.utils import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache.cache_dir() == os.path.join(REPO, ".jax_cache")
+
+
+def _smoke(*args):
+    return subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), *args],
+        capture_output=True, text=True, env=cpu_worker_env(), cwd=REPO,
+        timeout=540)
+
+
+def test_chip_smoke_refuses_to_run_without_a_chip():
+    r = _smoke()
+    assert r.returncode != 0
+    assert "'cpu'" in r.stderr and "needs a TPU" in r.stderr
+    assert '"ok"' not in r.stdout
+
+
+def _rehearse(*args):
+    """Run a rehearsal; returns its phase notes (progress lines left
+    out) and its last line, which must never claim a chip."""
+    r = _smoke("--rehearse", *args)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
+    assert '"ok": true' not in r.stdout
+    lines = [json.loads(ln) for ln in r.stdout.splitlines()
+             if ln.startswith("{")]
+    last = lines.pop()
+    assert last["device"]["platform"] == "cpu" and last["ok"] is False
+    notes = [ln for ln in lines if ln["phase"] != "compile"]
+    assert all(ln["equals_oracle"] for ln in notes
+               if ln["phase"].startswith("q"))
+    return notes, last
+
+
+def test_chip_smoke_rehearsal_walks_every_phase():
+    notes, last = _rehearse()
+    assert [ln["phase"] for ln in notes] == [
+        "start", "data", "oracle", "session", "q6", "q1", "q3", "q16",
+        "submit", "prepared", "compile_cache"]
+    assert all(ln["warm"]["kernel_programs_compiled"] == 0
+               for ln in notes if ln["phase"].startswith("q"))
+    assert last["device"]["count"] == 8
+
+
+def test_chip_smoke_mesh_rehearsal_says_where_it_is():
+    """``--chips 4``: only the mesh path, over four of the virtual
+    devices; every stage program is announced before it is dispatched
+    and after it answers, so a cut run has said where it was."""
+    notes, last = _rehearse("--chips", "4")
+    assert last["device"]["count"] == 4
+    said = [ln["said"] for ln in notes if ln["phase"] == "mesh.program"]
+    assert [ln["phase"] for ln in notes
+            if ln["phase"] != "mesh.program"] == [
+        "start", "data", "q3.mesh", "q5.mesh"]
+    assert said[0].startswith("stage[0] attempt 0: dispatching") and \
+        "answered in" in said[1]
+    assert sum("dispatching" in s for s in said) == \
+        sum("answered in" in s for s in said) >= 8
+    for ln in notes:
+        if ln["phase"].endswith(".mesh"):
+            assert ln["shard_devices"] == 4
