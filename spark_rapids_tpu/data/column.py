@@ -25,6 +25,7 @@ from typing import Any, List, Optional, Sequence
 import numpy as np
 
 from ..types import DType, Field, Schema, TypeId, STRING, from_numpy
+from ..utils.tracing import trace_range
 from . import strings as dstrings
 
 
@@ -503,7 +504,23 @@ def device_to_host_many(batches: List[DeviceBatch],
 
     if not batches:
         return []
-    ns = [int(n) for n in jax.device_get([b.num_rows for b in batches])]
+    # the row counts come out of the programs that make the arrays:
+    # reading them back IS the wait for the device, and the copy below
+    # finds the arrays done (but for the trim it dispatches itself)
+    with trace_range("DeviceToHost.wait"):
+        ns = [int(n)
+              for n in jax.device_get([b.num_rows for b in batches])]
+    with trace_range("DeviceToHost.copy"):
+        return _copy_to_host(batches, ns, trim)
+
+
+def _copy_to_host(batches: List[DeviceBatch], ns: List[int],
+                  trim: bool) -> List[HostBatch]:
+    """One ``jax.device_get`` of every array of every batch (trimmed on
+    the device to the rows' bucket first), then the host-side trim and
+    string decode."""
+    import jax
+
     arrs = []
     specs = []  # per batch, per column: has_lengths
     for batch, n in zip(batches, ns):

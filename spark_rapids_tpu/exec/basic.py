@@ -246,7 +246,8 @@ class TpuExpandExec(TpuExec):
                     for k in self._kernels:
                         with trace_range("TpuExpand",
                                          self.metrics[M.TOTAL_TIME]):
-                            yield k(db, metrics=self.metrics)
+                            out = k(db, metrics=self.metrics)
+                        yield out
 
             return it
 

@@ -103,7 +103,8 @@ class TpuCoalesceBatchesExec(TpuExec):
                         return
                     with trace_range("TpuCoalesce.concat",
                                      self.metrics[M.TOTAL_TIME]):
-                        yield concat_device_batches(batches, min_bucket)
+                        out = concat_device_batches(batches, min_bucket)
+                    yield out
                     return
                 if rows_target is not None:
                     if rows_target <= 0:  # disabled: passthrough

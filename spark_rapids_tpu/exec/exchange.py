@@ -149,9 +149,10 @@ class TpuShuffleExchangeExec(TpuExec):
         from .kernel_cache import jit_kernel
 
         # partitioning objects carry bound key state with no canonical
-        # fingerprint — compile privately (key=None); counters still apply
-        self._hash_kernel = jit_kernel(self._hash_pids)
-        self._slice_kernel = jit_kernel(self._slice)
+        # fingerprint — compile privately (key=None); counters still
+        # apply, and ``kind`` names the programs for the exchange
+        self._hash_kernel = jit_kernel(self._hash_pids, kind="shuffle")
+        self._slice_kernel = jit_kernel(self._slice, kind="shuffle")
         # device-resident path: packed partition-build + slice kernels,
         # shared across execs through the kernel cache (module-level
         # bodies keyed by schema layout + fan-out).  Range partitioning
@@ -167,12 +168,17 @@ class TpuShuffleExchangeExec(TpuExec):
         if isinstance(self.partitioning, RangePartitioning):
             self._passes_kernel = jit_kernel(
                 lambda b: range_key_passes(
-                    b, self.partitioning._bound_keys))
+                    b, self.partitioning._bound_keys),
+                kind="shuffle.rangePasses")
             self._range_pid_kernel = jit_kernel(
                 lambda b, bounds: range_pids_from_bounds(
                     range_key_passes(b, self.partitioning._bound_keys),
-                    bounds))
-            self._bounds_pid_kernel = jit_kernel(range_pids_from_bounds)
+                    bounds),
+                kind="shuffle.rangePids")
+            # a module-level body with no closure: shared by its key
+            self._bounds_pid_kernel = jit_kernel(
+                range_pids_from_bounds,
+                key=("shuffle.rangePidsFromBounds",))
             import jax.numpy as jnp
 
             def _sample(passes, nr):
@@ -182,7 +188,7 @@ class TpuShuffleExchangeExec(TpuExec):
                        ) // RANGE_SAMPLES_PER_BATCH
                 return passes[:, idx]
 
-            self._sample_kernel = jit_kernel(_sample)
+            self._sample_kernel = jit_kernel(_sample, kind="shuffle")
 
     @property
     def schema(self):
@@ -361,7 +367,9 @@ class TpuShuffleExchangeExec(TpuExec):
                 if not chunk:
                     return
                 if device_path:
-                    got = DS.fetch_counts([(c, s) for _b, c, s in chunk])
+                    with trace_range("TpuShuffleWrite.counts"):
+                        got = DS.fetch_counts(
+                            [(c, s) for _b, c, s in chunk])
                     for (buf_id, _c, _s), (counts, starts) in zip(
                             chunk, got):
                         counts = np.asarray(counts)
@@ -377,8 +385,9 @@ class TpuShuffleExchangeExec(TpuExec):
                             device_sizes.get(buf_id, 0))
                     chunk.clear()
                     return
-                got = jax.device_get([(nr, samp)
-                                      for _b, nr, samp in chunk])
+                with trace_range("TpuShuffleWrite.counts"):
+                    got = jax.device_get([(nr, samp)
+                                          for _b, nr, samp in chunk])
                 for (buf_id, _nr, _s), (n, samp) in zip(chunk, got):
                     n = int(n)
                     if n == 0:
@@ -662,6 +671,19 @@ class TpuShuffleExchangeExec(TpuExec):
                 import jax
                 import jax.numpy as jnp
 
+                def read_packed(buf_id, start, n):
+                    # one block's read: promotion if it was spilled and
+                    # the slice kernel's dispatch (no sync: ``n`` is a
+                    # host int already)
+                    with trace_range("TpuShuffleRead"):
+                        b = acquire_block(buf_id)
+                        try:
+                            return self._packed_slice_kernel(
+                                b, jnp.int32(start), jnp.int32(n),
+                                metrics=self.metrics)
+                        finally:
+                            fw.release_batch(buf_id)
+
                 if segments is not None:
                     assert device_path, "segment reads are device-path"
                     items_now = materialized()
@@ -671,14 +693,8 @@ class TpuShuffleExchangeExec(TpuExec):
                         if n <= 0:
                             continue
                         F.maybe_inject_fault("exchange.read")
-                        b = acquire_block(buf_id)
-                        try:
-                            out = self._packed_slice_kernel(
-                                b,
-                                jnp.int32(int(starts[p]) + int(row_lo)),
-                                jnp.int32(n), metrics=self.metrics)
-                        finally:
-                            fw.release_batch(buf_id)
+                        out = read_packed(
+                            buf_id, int(starts[p]) + int(row_lo), n)
                         self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
                         yield DeviceBatch(out.schema, out.columns, n)
                     return
@@ -690,13 +706,18 @@ class TpuShuffleExchangeExec(TpuExec):
                 outs = []
 
                 def drain_outs():
-                    counts = jax.device_get([o.num_rows for o in outs])
+                    with trace_range("TpuShuffleRead"):
+                        counts = jax.device_get(
+                            [o.num_rows for o in outs])
                     for out, n in zip(outs, counts):
                         if int(n):
                             self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
                             yield out
                     outs.clear()
 
+                # the write (TpuShuffleWrite) happens inside
+                # materialized(), before the first read range opens;
+                # each read range closes before its batch is handed on
                 for item in materialized():
                     F.maybe_inject_fault("exchange.read")
                     buf_id = item[0]
@@ -708,27 +729,22 @@ class TpuShuffleExchangeExec(TpuExec):
                         n = int(counts[p])
                         if n == 0:
                             continue
-                    b = acquire_block(buf_id)
-                    if device_path:
                         # slice the contiguous row range out of the
                         # packed block; count is a HOST int already, so
                         # the yielded batch needs no num_rows sync
-                        try:
-                            out = self._packed_slice_kernel(
-                                b, jnp.int32(int(starts[p])),
-                                jnp.int32(n), metrics=self.metrics)
-                        finally:
-                            fw.release_batch(buf_id)
+                        out = read_packed(buf_id, int(starts[p]), n)
                         self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
                         yield DeviceBatch(out.schema, out.columns, n)
                         continue
                     rr_start = item[1]
-                    try:
-                        outs.append(self._slice_kernel(
-                            b, pids_of(buf_id, b, rr_start),
-                            jnp.int32(p)))
-                    finally:
-                        fw.release_batch(buf_id)
+                    with trace_range("TpuShuffleRead"):
+                        b = acquire_block(buf_id)
+                        try:
+                            outs.append(self._slice_kernel(
+                                b, pids_of(buf_id, b, rr_start),
+                                jnp.int32(p)))
+                        finally:
+                            fw.release_batch(buf_id)
                     if len(outs) >= 8:
                         yield from drain_outs()
                 if outs:

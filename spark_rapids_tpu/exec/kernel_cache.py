@@ -25,12 +25,19 @@ per-exec ``_jit`` helpers could not:
   (the CPU backend ignores donation, so tests exercise the plumbing
   but never the aliasing).
 
+* **Names** — every program is jitted under a name that starts with
+  its operator's kind (:func:`program_name`), so the profiler's
+  ``XLA Modules`` line — the only place a device trace names what ran
+  — reads ``jit_filter__compute``, ``jit_agg_batch``,
+  ``jit_shuffle_packedBuild``: device seconds by operator.
+
 Conf-gated by ``spark.rapids.tpu.sql.kernelCache.{enabled,maxEntries,
 donation.enabled}``; the cache is process-global like the
 DeviceManager, (re)configured by each device Session.
 """
 from __future__ import annotations
 
+import re
 import threading
 import time
 from collections import OrderedDict
@@ -55,6 +62,51 @@ def expr_signature(exprs) -> Tuple:
     return tuple((e.sql(), str(e.dtype)) for e in exprs)
 
 
+def _identifier(text: str) -> str:
+    return re.sub(r"[^0-9A-Za-z]", "_", text)
+
+
+def program_name(key, fn: Callable, kind: Optional[str] = None) -> str:
+    """The name a kernel is jitted under: ``<kind>_<phase>`` where the
+    key ends in a phase string (``agg_batch``, ``join_count``), else
+    ``<kind>_<function>`` (``filter__compute``), the function left out
+    where the kind already says it (``shuffle_packedBuild``).
+    ``kind`` defaults to the key's leading string, non-alphanumerics
+    turned to ``_``.
+
+    The name becomes part of the HLO module and so of the persistent
+    compile cache's key: it is built from strings in the source alone
+    — no id, address or counter — and is the same in every process."""
+    if kind is None and isinstance(key, tuple) and key \
+            and isinstance(key[0], str):
+        kind = key[0]
+    own = getattr(fn, "__name__", "")
+    if not kind:
+        return own  # unkeyed and unnamed: JAX's own naming
+    kind = _identifier(kind)
+    if isinstance(key, tuple) and len(key) > 1 \
+            and isinstance(key[-1], str):
+        return f"{kind}_{_identifier(key[-1])}"
+    said = kind.replace("_", "").lower()
+    if own.isidentifier() and \
+            own.replace("_", "").lower() not in said:
+        return f"{kind}_{own}"
+    return kind
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` behind a function called ``name`` — what ``jax.jit``
+    names the program after."""
+    if not name or name == getattr(fn, "__name__", None):
+        return fn
+
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 class _CachedKernel:
     """A jitted kernel wrapped with dispatch accounting.
 
@@ -65,12 +117,13 @@ class _CachedKernel:
     attributed to the dispatching exec's ``compileTime`` metric.
     """
 
-    __slots__ = ("_cache", "fn", "_jfn", "donated", "fingerprint")
+    __slots__ = ("_cache", "fn", "_jfn", "donated", "fingerprint", "name")
 
     def __init__(self, cache: "KernelCache", fn: Callable,
                  static_argnums: Tuple[int, ...],
                  donate_argnums: Tuple[int, ...],
-                 fingerprint: Optional[str] = None):
+                 fingerprint: Optional[str] = None,
+                 name: Optional[str] = None):
         import jax
 
         self._cache = cache
@@ -82,7 +135,10 @@ class _CachedKernel:
             kwargs["static_argnums"] = tuple(static_argnums)
         if self.donated:
             kwargs["donate_argnums"] = tuple(donate_argnums)
-        self._jfn = jax.jit(fn, **kwargs)
+        program = _named(fn, name)
+        #: the program's name in a device trace, less jit's ``jit_``
+        self.name = program.__name__
+        self._jfn = jax.jit(program, **kwargs)
 
     def __call__(self, *args, metrics=None):
         # the disabled-profiler cost is this ONE attribute read — no
@@ -194,13 +250,15 @@ class KernelCache:
         return out
 
     # ---------------- the entry point ----------------------------------
-    def get(self, fn: Callable, *, key=None,
+    def get(self, fn: Callable, *, key=None, kind: Optional[str] = None,
             static_argnums: Tuple[int, ...] = (),
             donate_argnums: Tuple[int, ...] = ()) -> _CachedKernel:
         """Wrap ``fn`` for jit dispatch through the cache.
 
         ``key=None`` (or cache disabled) compiles privately per call
-        site — no sharing, but dispatches still count.  A non-None key
+        site — no sharing, but dispatches still count; such a site
+        passes ``kind``, its operator's name for :func:`program_name`
+        (a key would share the first caller's closure).  A non-None key
         MUST capture everything the closure reads (operator kind,
         bound-expression signatures, input/output schema signatures):
         the first caller's closure serves every later caller.
@@ -229,7 +287,8 @@ class KernelCache:
                         self._counters["sharedKernels"] += 1
                         return hit
         kern = _CachedKernel(self, fn, static_argnums, donate_argnums,
-                             fingerprint=kernel_fingerprint(key, fn))
+                             fingerprint=kernel_fingerprint(key, fn),
+                             name=program_name(key, fn, kind))
         if use_key is not None:
             with self._lock:
                 # a concurrent thread may have registered the same key
@@ -250,10 +309,11 @@ class KernelCache:
 GLOBAL = KernelCache()
 
 
-def jit_kernel(fn: Callable, *, key=None,
+def jit_kernel(fn: Callable, *, key=None, kind: Optional[str] = None,
                static_argnums: Tuple[int, ...] = (),
                donate_argnums: Tuple[int, ...] = ()) -> _CachedKernel:
     """Module-level sugar over ``GLOBAL.get`` — the one way execs
     compile kernels (replaces the per-module ``_jit`` helpers)."""
-    return GLOBAL.get(fn, key=key, static_argnums=static_argnums,
+    return GLOBAL.get(fn, key=key, kind=kind,
+                      static_argnums=static_argnums,
                       donate_argnums=donate_argnums)

@@ -29,7 +29,7 @@ from ..ops.expression import as_device_column, as_host_column
 from ..ops.kernels import gather as G
 from ..ops.kernels import segment as seg
 from ..utils import metrics as M
-from ..utils.tracing import trace_range
+from ..utils.tracing import trace_range, trace_steps
 from .base import DevicePartitionedData, TargetSize, TpuExec
 
 
@@ -251,6 +251,15 @@ class TpuSortExec(TpuExec):
                 if first is None:
                     return
                 second = next(batches, None)
+
+                def chunked(runs):
+                    # the out-of-core merge streams, so it runs after
+                    # the range below has closed: each of its steps
+                    # gets a range of its own, closed at the hand-over
+                    return trace_steps("TpuSort",
+                                       self._sort_chunked(runs, rctx),
+                                       self.metrics[M.TOTAL_TIME])
+
                 with trace_range("TpuSort",
                                  self.metrics[M.TOTAL_TIME]):
                     if second is None:
@@ -266,7 +275,7 @@ class TpuSortExec(TpuExec):
                                 # halve and route through the external
                                 # merge: each half is a sorted run
                                 halves = R.split_or_raise(first, rctx)
-                                out = self._sort_chunked(halves, rctx)
+                                out = chunked(halves)
                             else:
                                 # at the floor: plain retries (a split
                                 # request degrades inside retry_call)
@@ -276,8 +285,7 @@ class TpuSortExec(TpuExec):
                     else:
                         from itertools import chain
 
-                        out = self._sort_chunked(
-                            chain([first, second], batches), rctx)
+                        out = chunked(chain([first, second], batches))
                 for b in out:
                     self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
                     yield b

@@ -182,6 +182,7 @@ class HostToDeviceExec(TpuExec):
 
         str_guard = ctx.conf.get(STRING_COLUMN_BYTES_GUARD)
         rctx = R.RetryContext.for_exec(ctx, "HostToDeviceExec")
+        waits = ctx.metrics.metric(f"{self.name}.prefetchWaits")
 
         def upload(hb):
             import time as _time
@@ -339,7 +340,12 @@ class HostToDeviceExec(TpuExec):
                             # shape of the r3 deadlocks
                             if sem:
                                 sem.release_all()
-                            item = _next_prefetched(q, t, err)
+                            # the consumer blocked on the decode queue:
+                            # whatever the device idles here, it idles
+                            # for the producer's ScanDecode
+                            waits.add(1)
+                            with trace_range("PrefetchWait"):
+                                item = _next_prefetched(q, t, err)
                         if item is END:
                             break
                         yield from upload_retry(item)
@@ -379,6 +385,8 @@ class DeviceToHostExec(TpuExec):
         child_data = self.children[0].execute_columnar(ctx)
         self._init_metrics(ctx)
         sem = self._sem(ctx)
+        # what DeviceToHost.copy brought back (estimated: strings sampled)
+        copied = ctx.metrics.metric(f"{self.name}.copiedBytes")
 
         def make(pid):
             def it():
@@ -397,6 +405,7 @@ class DeviceToHostExec(TpuExec):
                     with trace_range("DeviceToHost",
                                      self.metrics[M.TOTAL_TIME]):
                         hbs = device_to_host_many(chunk)
+                    copied.add(sum(hb.estimate_bytes() for hb in hbs))
                     sync = self.metrics.get(M.DEVICE_SYNC_TIME)
                     if sync is not None:  # telemetry-only metric
                         sync.add(_time.perf_counter_ns() - t0)

@@ -86,7 +86,7 @@ class TpuWindowExec(TpuExec):
 
         # window frames/specs have no compact canonical fingerprint —
         # compile privately (key=None), dispatch counters still apply
-        self._kernel = jit_kernel(self._compute)
+        self._kernel = jit_kernel(self._compute, kind="window")
 
     @property
     def schema(self):

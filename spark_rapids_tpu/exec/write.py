@@ -43,7 +43,8 @@ class TpuDataWritingCommandExec(TpuExec):
         self.plan = plan  # physical.DataWritingCommandExec
         from .kernel_cache import jit_kernel
 
-        self._sort_kernel = jit_kernel(self._sort_by_keys)
+        self._sort_kernel = jit_kernel(self._sort_by_keys,
+                                       kind="write")
 
     @property
     def schema(self):

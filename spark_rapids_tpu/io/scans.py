@@ -22,6 +22,7 @@ from ..data.column import HostBatch
 from ..ops import miscexprs
 from ..plan import logical as L
 from ..plan import physical as P
+from ..utils.tracing import trace_steps
 from . import arrow_convert as ac
 
 
@@ -250,6 +251,8 @@ class FileScanExec(P.PhysicalPlan):
         self.metrics_skipped_groups = 0
         self.metrics_skipped_stripes = 0
         self.metrics_skipped_files = 0
+        #: (rows, batches, bytes) metrics of the running execution
+        self._decode_counters = None
         # Hive-partition layout: per-file raw values + the derived
         # constant columns appended to every batch
         self.part_values = part_values or [{} for _ in files]
@@ -265,6 +268,20 @@ class FileScanExec(P.PhysicalPlan):
         return self._schema
 
     def _read_file(self, fi: int):
+        """File ``fi``'s batches, each decoded inside a ``ScanDecode``
+        range on the thread that pulls it (the ``h2d-prefetch-*``
+        producer by default): the reader's step, the Arrow-to-host
+        conversion and the partition columns.  The range is closed
+        when the batch is handed on."""
+        rows, batches, nbytes = self._decode_counters or (None,) * 3
+        for hb in trace_steps("ScanDecode", self._decode_file(fi)):
+            if rows is not None:
+                rows.add(hb.num_rows)
+                batches.add(1)
+                nbytes.add(hb.estimate_bytes())
+            yield hb
+
+    def _decode_file(self, fi: int):
         import numpy as np
 
         path = self.files[fi]
@@ -493,6 +510,13 @@ class FileScanExec(P.PhysicalPlan):
         return kept
 
     def execute(self, ctx):
+        # what ScanDecode produced (estimated bytes: strings sampled)
+        reg = ctx.metrics
+        self._decode_counters = (
+            reg.metric(f"{self.name}.decodedRows"),
+            reg.metric(f"{self.name}.decodedBatches"),
+            reg.metric(f"{self.name}.decodedBytes"))
+
         def make(fi):
             return lambda: self._read_file(fi)
 

@@ -48,6 +48,7 @@ from ..memory.semaphore import DeviceSemaphoreTimeout
 from ..telemetry import spans as tspans
 from ..telemetry.events import emit_event
 from ..utils import hashing
+from ..utils.tracing import trace_range
 from . import exchange as X
 from .mesh import DATA_AXIS
 
@@ -1088,18 +1089,26 @@ class DistributedRunner:
             # (every shard holds all of them, num_rows alike), so the
             # same trim applies: the consuming stage is traced at the
             # build side's row count, not at n_shards x its bucket
-            env_stacked[key] = self._retile(self._run_program(
-                build_kid, env_stacked, caps,
-                f"stage[{stage.sid}].broadcast[{i}]",
-                post=self.transport.replicate))
+            with trace_range("MeshStage"):
+                out = self._run_program(
+                    build_kid, env_stacked, caps,
+                    f"stage[{stage.sid}].broadcast[{i}]",
+                    post=self.transport.replicate)
+            with trace_range("MeshTrim"):
+                env_stacked[key] = self._retile(out)
 
     def _run_stage(self, stage: _Stage, env_stacked: Dict,
                    caps: Dict) -> DeviceBatch:
         """jit + shard_map one stage; returns the stacked output batch.
         Retries with doubled join capacity on overflow."""
         self._prepare_broadcasts(stage, env_stacked, caps)
-        return self._retile(self._run_program(
-            stage.root, env_stacked, caps, f"stage[{stage.sid}]"))
+        # trace, compile, dispatch and the overflow verdict's readback
+        # (the wait for the stage), every attempt
+        with trace_range("MeshStage"):
+            out = self._run_program(
+                stage.root, env_stacked, caps, f"stage[{stage.sid}]")
+        with trace_range("MeshTrim"):
+            return self._retile(out)
 
     def _retile(self, stacked: DeviceBatch) -> DeviceBatch:
         """Host-side bucket trim between stages: shapes grow through

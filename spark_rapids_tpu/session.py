@@ -82,6 +82,11 @@ class Session:
         import weakref
 
         self._plan_cache = weakref.WeakKeyDictionary()
+        #: numbers this session's requests: the ``Query`` range's
+        #: ``query_id`` in a profiler trace
+        import itertools
+
+        self._query_ids = itertools.count(1)
         # concurrent query scheduler — created lazily on first submit()
         # so plain execute() sessions never pay for its threads
         import threading as _threading
@@ -210,6 +215,24 @@ class Session:
         time (``_exec_lock``, non-blocking): execs carry per-execution
         state (metrics registries), so a concurrent collect of the same
         DataFrame gets a freshly planned tree instead of sharing."""
+        import time
+
+        from .utils.tracing import trace_range
+
+        # what THIS request pays for planning: the cache lookups, the
+        # planner on a miss, the context, the recovery stamp
+        t0 = time.perf_counter_ns()
+        with trace_range("Plan"):
+            phys, ctx = self._prepare_execution(
+                plan, scheduled=scheduled, cancel_token=cancel_token,
+                force_host_shuffle=force_host_shuffle, recovery=recovery)
+        # the registry is made inside the range, so it is told after
+        ctx.metrics.metric("Session.planTime", "ns").add(
+            time.perf_counter_ns() - t0)
+        return phys, ctx
+
+    def _prepare_execution(self, plan, *, scheduled, cancel_token,
+                           force_host_shuffle, recovery):
         import threading
 
         from .exec.kernel_cache import GLOBAL as _kernel_cache
@@ -469,6 +492,18 @@ class Session:
                         ctx_sink: Optional[Dict] = None,
                         force_host_shuffle: bool = False,
                         recovery=None) -> HostBatch:
+        from .utils.tracing import trace_range
+
+        # the request inside the program, planning to rows on the host
+        with trace_range("Query", query_id=next(self._query_ids)):
+            return self._execute_planned(
+                plan, scheduled=scheduled, cancel_token=cancel_token,
+                ctx_sink=ctx_sink, force_host_shuffle=force_host_shuffle,
+                recovery=recovery)
+
+    def _execute_planned(self, plan, *, scheduled, cancel_token,
+                         ctx_sink, force_host_shuffle,
+                         recovery) -> HostBatch:
         phys, ctx = self.prepare_execution(
             plan, scheduled=scheduled, cancel_token=cancel_token,
             force_host_shuffle=force_host_shuffle, recovery=recovery)

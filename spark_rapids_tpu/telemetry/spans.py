@@ -355,9 +355,12 @@ def span(name: str, kind: str = "span", **attrs):
 # ----- trace_range coupling ------------------------------------------------
 def push_range(name: str):
     """Range-stack push for ``utils.tracing.trace_range`` (re-entrant,
-    thread-local): returns an opaque token, or None when inactive."""
+    thread-local): returns an opaque token, or None when inactive.  A
+    finished query's binding lingers on its thread until the next
+    query begins: a range opened in between (``Query``, ``Plan``)
+    belongs to neither query's table."""
     tele = current()
-    if tele is None:
+    if tele is None or tele.finished:
         return None
     st = getattr(_tl, "ranges", None)
     if st is None:

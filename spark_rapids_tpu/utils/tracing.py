@@ -35,41 +35,53 @@ def _telemetry_spans():
 
 
 @contextmanager
-def trace_range(name: str, metric=None):
+def trace_range(name: str, metric=None, **annotation):
     """A named profiler range; if ``metric`` is given, elapsed nanoseconds
     are added to it (reference: NvtxWithMetrics.scala:44).  The range is
     also pushed on the active telemetry span stack, so its wall
-    aggregates under the current span (no-op when telemetry is off)."""
+    aggregates under the current span (no-op when telemetry is off).
+    ``annotation`` is metadata for the profiler's event alone (the
+    event keeps the plain ``name``).
+
+    Never hold one open across a ``yield``: the consumer's time would
+    be charged to the range (:func:`trace_steps` drives a generator
+    with the range closed at every hand-over)."""
     spans = _telemetry_spans()
     start = time.perf_counter_ns()
-    annotation = None
+    profiled = None
     if _ENABLED:
         import jax.profiler
 
-        annotation = jax.profiler.TraceAnnotation(name)
-        annotation.__enter__()
+        profiled = jax.profiler.TraceAnnotation(name, **annotation)
+        profiled.__enter__()
     token = spans.push_range(name)
     try:
         yield
     finally:
         elapsed = time.perf_counter_ns() - start
         spans.pop_range(token, elapsed)
-        if annotation is not None:
-            annotation.__exit__(None, None, None)
+        if profiled is not None:
+            profiled.__exit__(None, None, None)
         if metric is not None:
             metric.add(elapsed)
 
 
-class DebugRange:
-    """Benchmark-facing range wrapper (reference:
-    integration_tests/.../DebugRange.scala)."""
+_DONE = object()
 
-    def __init__(self, name: str):
-        self._cm = trace_range(name)
 
-    def __enter__(self):
-        self._cm.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        return self._cm.__exit__(*exc)
+def trace_steps(name: str, steps, metric=None):
+    """Drive the iterator ``steps`` with each step — the work between
+    two of its yields — inside ``trace_range(name)``, closed before the
+    item is handed on."""
+    steps = iter(steps)
+    try:
+        while True:
+            with trace_range(name, metric):
+                item = next(steps, _DONE)
+            if item is _DONE:
+                return
+            yield item
+    finally:
+        close = getattr(steps, "close", None)
+        if close is not None:  # an abandoned drain closes its source
+            close()
