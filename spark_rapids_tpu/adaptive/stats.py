@@ -4,15 +4,18 @@
 exchange write drain ALREADY knows once a stage materializes:
 
 * device path — the per-partition count vectors of every packed block,
-  pulled to the host in the drain's one gated ``fetch_counts`` batch
-  readback (``exec/exchange.py:flush``).  Summing them gives the exact
-  per-partition row histogram of the exchange, per-item so a skewed
-  partition can later be cut into contiguous sub-slices.
+  pulled to the host by the second of a flush's two gated
+  ``fetch_counts`` batch readbacks (``exec/exchange.py:flush``; the
+  first reads the inputs' row counts, which size the blocks).
+  Summing them gives the exact per-partition row histogram of the
+  exchange, per-item so a skewed partition can later be cut into
+  contiguous sub-slices.
 * host path — per-batch row counts from the same gated readback
   (round-robin placement has no per-partition vector; totals only,
   except the trivial single-partition case).
 * bytes — the arena-accounting byte sizes the write path tracks per
-  block for spill bookkeeping (metadata math, no device touch).
+  block for spill bookkeeping (metadata math, no device touch): what
+  was packed, not the padding the input arrived in.
 
 Everything in here is host-side numpy on numbers that were already
 host-resident: this module MUST NOT import jax or call any host-sync

@@ -25,11 +25,13 @@ order (balance, never correctness, depends on the prefix).
 """
 from __future__ import annotations
 
+import functools
 from typing import List
 
 import numpy as np
 
-from ..data.column import DeviceBatch, DeviceColumn
+from ..config import BUCKET_MIN_ROWS
+from ..data.column import DeviceBatch, DeviceColumn, bucket_rows
 from ..fault import injector as F
 from ..fault.errors import TpuPayloadCorruption
 from ..memory import retry as R
@@ -153,14 +155,16 @@ class TpuShuffleExchangeExec(TpuExec):
         # apply, and ``kind`` names the programs for the exchange
         self._hash_kernel = jit_kernel(self._hash_pids, kind="shuffle")
         self._slice_kernel = jit_kernel(self._slice, kind="shuffle")
-        # device-resident path: packed partition-build + slice kernels,
-        # shared across execs through the kernel cache (module-level
-        # bodies keyed by schema layout + fan-out).  Range partitioning
-        # never takes the packed path (its placement needs sampled
-        # bounds that only exist after the full write drain).
+        # device-resident path: trim, packed partition-build and slice
+        # kernels, shared across execs through the kernel cache
+        # (module-level bodies keyed by schema layout + fan-out).
+        # Range partitioning never takes the packed path (its
+        # placement needs sampled bounds that only exist after the
+        # full write drain).
         if not isinstance(self.partitioning, RangePartitioning):
             from ..shuffle import device_shuffle as DS
 
+            self._trim_kernel = DS.trim_kernel(self.schema)
             self._build_kernel = DS.packed_build_kernel(
                 self.schema, self.n_out)
             self._packed_slice_kernel = DS.packed_slice_kernel(
@@ -313,7 +317,19 @@ class TpuShuffleExchangeExec(TpuExec):
         device_sizes: dict = {}
         fw = SpillFramework.get()
         rctx = R.RetryContext.for_exec(ctx, "TpuShuffleExchangeExec")
-        rr_state = {"rr": None}  # device round-robin offset (no sync)
+        min_bucket = ctx.conf.get(BUCKET_MIN_ROWS)
+
+        def drop(ids):
+            """Forget buffers of this exchange: their catalog slots,
+            their spill entries and what the exec caches per id."""
+            for bid in ids:
+                pid_cache.pop(bid, None)
+                device_sizes.pop(bid, None)
+            if catalog is not None:
+                catalog.drop_buffers(shuffle_id, ids)
+            else:
+                for bid in ids:
+                    fw.remove_batch(bid)
 
         def write_one(b):
             # registering a map-output batch is the write-side
@@ -328,14 +344,36 @@ class TpuShuffleExchangeExec(TpuExec):
                 F.maybe_inject_fault("exchange.write")
                 return fw.add_batch(b, site="exchange.write")
             F.maybe_inject_fault("exchange.write.device")
-            pids = self._pids(b, rr_state["rr"], None)
-            block, counts, starts = self._build_kernel(
-                b, pids, self.n_out, metrics=self.metrics)
-            buf_id = fw.add_batch(block, site="exchange.write.device")
-            size = block.device_bytes()
-            device_sizes[buf_id] = size
-            DS.GLOBAL.add("deviceBytes", size)
-            return buf_id, counts, starts
+            # the device path only parks its input here, spillable and
+            # accounted like the block it will become: nothing is
+            # hashed or packed before the flush has read the row count
+            # (pack_one, whose block is the device write site's payload)
+            buf_id = fw.add_batch(b)
+            device_sizes[buf_id] = b.device_bytes()
+            return buf_id
+
+        def pack_one(buf_id, n, rr_start):
+            """Build one parked input's packed block, at the bucket of
+            its ``n`` live rows when that is smaller than its padding
+            (live rows are at the front, so the trim cuts padding
+            only).  Returns the block's id, its count/start handles,
+            its bytes and the padded rows the trim saved."""
+            b = fw.acquire_batch(buf_id)
+            try:
+                padded = b.padded_rows
+                bucket = bucket_rows(n, min_bucket)
+                if bucket < padded:
+                    cut = self._trim_kernel(b, bucket,
+                                            metrics=self.metrics)
+                    b = DeviceBatch(cut.schema, cut.columns, n)
+                pids = self._pids(b, rr_start, None)
+                block, counts, starts = self._build_kernel(
+                    b, pids, self.n_out, metrics=self.metrics)
+            finally:
+                fw.release_batch(buf_id)
+            return (fw.add_batch(block, site="exchange.write.device"),
+                    counts, starts, block.device_bytes(),
+                    padded - block.padded_rows)
 
         def _drain_child():
             import jax
@@ -353,37 +391,59 @@ class TpuShuffleExchangeExec(TpuExec):
             # (batches past the cap recompute pids at first read)
             pend_budget = 64 * 1024 * 1024
             # chunk entries hold NO batch reference — only the buffer
-            # id plus tiny device handles (count/starts vectors, sample
-            # tile) — so a spill of a chunk member actually frees its HBM
+            # id plus tiny handles (the row count, the sample tile) —
+            # so a spill of a chunk member actually frees its HBM
             chunk = []
-            rr_state["rr"] = jnp.int32(0)
             stat_state["bytes"] = 0  # fresh per attempt (re-drains)
 
             def flush():
-                # ONE batched readback of the chunk's tiny per-block
-                # vectors — a per-batch int(num_rows) is a full device
-                # sync each
+                # batched readbacks of the chunk's tiny handles — a
+                # per-batch int(num_rows) is a full device sync each
                 nonlocal rr
                 if not chunk:
                     return
                 if device_path:
+                    # the row counts first: they decide each block's
+                    # bucket.  This is where the client waits for the
+                    # device to finish the child's programs.
+                    with trace_range("TpuShuffleWrite.counts"):
+                        ns = DS.fetch_counts([nr for _b, _p, nr in chunk])
+                    packed = []
+                    for (buf_id, pid, _nr), n in zip(chunk, ns):
+                        n = int(n)
+                        if n:
+                            block_id, counts, starts, size, cut = \
+                                R.retry_call(functools.partial(
+                                    pack_one, buf_id, n, rr), rctx)
+                            # round-robin offset: same write order as
+                            # the host path → bit-identical placement
+                            rr = (rr + n) % self.n_out
+                            added.append(block_id)
+                            if catalog is not None:
+                                catalog.add_buffer(shuffle_id, pid,
+                                                   block_id)
+                            device_sizes[block_id] = size
+                            DS.GLOBAL.add("deviceBytes", size)
+                            if cut:
+                                DS.GLOBAL.add("trimmedBlocks")
+                                DS.GLOBAL.add("trimmedRows", cut)
+                            packed.append((block_id, size, counts,
+                                           starts))
+                        # the input goes, packed or empty (and then
+                        # never built): its memory now, its slot below
+                        fw.remove_batch(buf_id)
+                    drop([buf_id for buf_id, _p, _nr in chunk])
+                    chunk.clear()
                     with trace_range("TpuShuffleWrite.counts"):
                         got = DS.fetch_counts(
-                            [(c, s) for _b, c, s in chunk])
-                    for (buf_id, _c, _s), (counts, starts) in zip(
-                            chunk, got):
-                        counts = np.asarray(counts)
-                        if not counts.sum():
-                            device_sizes.pop(buf_id, None)
-                            fw.remove_batch(buf_id)
-                            continue
-                        items.append((buf_id, counts,
+                            [(c, s) for _b, _z, c, s in packed])
+                    for (block_id, size, _c, _s), (counts, starts) in \
+                            zip(packed, got):
+                        items.append((block_id, np.asarray(counts),
                                       np.asarray(starts)))
                         # arena-accounting block size: metadata math,
                         # no device touch — AQE's byte estimate
-                        stat_state["bytes"] += int(
-                            device_sizes.get(buf_id, 0))
-                    chunk.clear()
+                        stat_state["bytes"] += size
                     return
                 with trace_range("TpuShuffleWrite.counts"):
                     got = jax.device_get([(nr, samp)
@@ -405,25 +465,15 @@ class TpuShuffleExchangeExec(TpuExec):
                                  self.metrics[M.TOTAL_TIME]):
                     for pid in range(child.n_partitions):
                         for b in child.iterator(pid):
-                            out = R.retry_call(
+                            buf_id = R.retry_call(
                                 lambda b=b: write_one(b), rctx)
-                            if device_path:
-                                buf_id, counts, starts = out
-                                chunk.append((buf_id, counts, starts))
-                                # round-robin offset advances on device
-                                # (same write order as the host path →
-                                # bit-identical placement, no sync)
-                                rr_state["rr"] = (
-                                    rr_state["rr"] + jnp.asarray(
-                                        b.num_rows, dtype=jnp.int32)
-                                ) % self.n_out
-                            else:
-                                buf_id = out
                             added.append(buf_id)
                             if catalog is not None:
                                 catalog.add_buffer(shuffle_id, pid,
                                                    buf_id)
-                            if not device_path:
+                            if device_path:
+                                chunk.append((buf_id, pid, b.num_rows))
+                            else:
                                 if mode == "host":
                                     # the host-staged path: serialize +
                                     # CRC-stamp NOW, not at spill time
@@ -455,18 +505,12 @@ class TpuShuffleExchangeExec(TpuExec):
                                 flush()
                     flush()
             except BaseException:
-                for bid in added:
-                    device_sizes.pop(bid, None)
                 # a failed attempt must not leave its partial map
                 # output resident until query end — the re-armed retry
                 # registers a full fresh set.  The catalog slots go
                 # with the buffers: a retried stage must not leak the
                 # dead attempt's ids in the shuffle index.
-                if catalog is not None:
-                    catalog.drop_buffers(shuffle_id, added)
-                else:
-                    for bid in added:
-                        fw.remove_batch(bid)
+                drop(added)
                 raise
             if is_range and samples:
                 import jax.numpy as jnp
@@ -626,15 +670,7 @@ class TpuShuffleExchangeExec(TpuExec):
                 state["writer"] = False
                 state["error"] = cause
                 done.clear()
-            ids = [it[0] for it in old]
-            for bid in ids:
-                pid_cache.pop(bid, None)
-                device_sizes.pop(bid, None)
-            if catalog is not None:
-                catalog.drop_buffers(shuffle_id, ids)
-            else:
-                for bid in ids:
-                    fw.remove_batch(bid)
+            drop([it[0] for it in old])
 
         def acquire_block(buf_id):
             # promotion of a spilled map-output batch is an

@@ -6,6 +6,11 @@ host memory on the happy path.  The TPU form: a shuffle write runs ONE
 jitted partition-build kernel per input batch that groups rows by
 destination partition inside a single flat HBM block (stable sort by
 partition id), and records per-partition ``counts``/``starts`` vectors.
+The write counts before it packs: a batch whose live rows fit a
+smaller power-of-two bucket than it arrived in (a partial aggregate's
+four groups in a 2^22-row bucket) is first cut to that bucket by the
+trim kernel, so hash, build and every reader's slice run at the size
+the data has.
 Readers slice their partition out of the resident block with a shared
 gather kernel — no d2h, no host CRC, no h2d.  CRC32C stamping moves to
 the spill/host boundary: it happens exactly when a block is demoted off
@@ -13,16 +18,17 @@ the device tier (``SpillableBuffer.to_host``), which is also where the
 ``shuffle.hostBytes`` metric accrues.
 
 Layout note: the LOCAL block is the sorted-flat ragged form (block
-padded size == input padded size).  The padded ``[n_parts, max_rows]``
+padded size == the bucket of the input's live rows, never more than
+the input's padded size).  The padded ``[n_parts, max_rows]``
 tile form lives in ``parallel/exchange.py`` (``bucket_rows`` /
 ``collective_exchange``) where the fused ``lax.all_to_all`` collective
 needs equal-capacity lanes per destination; a local exchange with
 ``n_out`` readers over one process would pay ``n_out×`` HBM for the
 same information the flat block carries in ``1×``.
 
-Both kernels register in the process-wide kernel cache keyed by schema
+The kernels register in the process-wide kernel cache keyed by schema
 signature, so every exchange of the same layout shares one compiled
-build and one compiled slice program.
+trim, build and slice program per row bucket.
 """
 from __future__ import annotations
 
@@ -31,7 +37,7 @@ import time
 from contextlib import contextmanager
 from typing import Dict
 
-from ..data.column import DeviceBatch
+from ..data.column import DeviceBatch, DeviceColumn
 from ..ops.kernels.gather import gather_batch, gather_column
 
 
@@ -41,8 +47,11 @@ from ..ops.kernels.gather import gather_batch, gather_column
 # ``metrics_since(mark)`` into last_metrics under ``shuffle.*``)
 # ==========================================================================
 class ShuffleStats:
+    #: trimmedBlocks: blocks packed at a smaller bucket than their
+    #: input arrived in; trimmedRows: padded rows in - padded rows packed
     _KEYS = ("deviceBytes", "hostBytes", "collectiveTimeNs",
-             "numFallbacks", "checkpointBytes")
+             "numFallbacks", "checkpointBytes", "trimmedBlocks",
+             "trimmedRows")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -131,6 +140,31 @@ def packed_slice(block: DeviceBatch, start, count) -> DeviceBatch:
                        jnp.asarray(count, dtype=jnp.int32))
 
 
+def trim(batch: DeviceBatch, out_rows: int) -> DeviceBatch:
+    """The leading ``out_rows`` rows of every column: data, validity
+    and lengths.  A batch's live rows are at the front
+    (``DeviceBatch.row_mask``), so with ``out_rows >= num_rows`` only
+    padding is cut.  ``out_rows`` is static — a bucket, so the number
+    of programs is bounded by the powers of two."""
+    cols = [DeviceColumn(
+        c.dtype, c.data[:out_rows], c.validity[:out_rows],
+        None if c.lengths is None else c.lengths[:out_rows])
+        for c in batch.columns]
+    return DeviceBatch(batch.schema, cols, batch.num_rows)
+
+
+def trim_kernel(schema):
+    """The jitted trim, named ``jit_shuffle_trim``: one entry per
+    schema layout, one program per output bucket (static), found by
+    the persistent compile cache — not an eager ``x[:n]`` whose shape
+    follows the data."""
+    from ..exec.kernel_cache import jit_kernel, schema_signature
+
+    return jit_kernel(
+        trim, key=("shuffle.trim", schema_signature(schema)),
+        static_argnums=(1,))
+
+
 def packed_build_kernel(schema, n_out: int):
     """The jitted build kernel, shared across execs via the kernel
     cache (key: schema layout + fan-out; ``n_out`` is static — it
@@ -153,11 +187,14 @@ def packed_slice_kernel(schema):
 
 def fetch_counts(handles):
     """The ONE gated host readback of the device exchange write path:
-    a single batched ``jax.device_get`` of the flush chunk's
-    counts/starts vectors (tiny int32[n_out] pairs — per-block syncs
-    would be a device RTT each).  Named so the host-sync analysis
-    rule can gate exactly this function as the device path's host
-    materialization point."""
+    a single batched ``jax.device_get`` of tiny handles — per-block
+    syncs would be a device RTT each.  A flush calls it twice: with
+    the chunk's input row counts (scalars; one that is already a
+    Python int passes through untouched), which decide each block's
+    bucket before anything is packed, and then with the packed
+    blocks' counts/starts vectors (int32[n_out] pairs).  Named so the
+    host-sync analysis rule can gate exactly this function as the
+    device path's host materialization point."""
     import jax
 
     return jax.device_get(list(handles))

@@ -201,6 +201,70 @@ def test_skew_split_trigger_and_equality():
     assert "aqe_skew_split" in _events(sess)
 
 
+def _drained_exchange(mode):
+    """A hash exchange over filtered batches — ~50 live rows each in
+    the 4096-row bucket of their input — executed directly, so its
+    readers can be asked for whole partitions and for segments."""
+    from spark_rapids_tpu.exec.exchange import TpuShuffleExchangeExec
+    from spark_rapids_tpu.plan.physical import ExecContext
+
+    sess = _sess(SHUFFLED, {"spark.rapids.tpu.shuffle.mode": mode},
+                 adaptive=False)
+    rng = np.random.RandomState(17)
+    n = 5000
+    df = sess.create_dataframe(
+        {"k": rng.randint(0, 1000, n).tolist(),
+         "v": [round(float(v), 6) for v in rng.rand(n)]},
+        n_partitions=2)
+    plan = df.filter(df["v"] < 0.02).repartition(3, "k").plan
+
+    def find(p):
+        if isinstance(p, TpuShuffleExchangeExec):
+            return p
+        for c in p.children:
+            hit = find(c)
+            if hit is not None:
+                return hit
+
+    ex = find(sess.physical_plan(plan))
+    return ex.execute_columnar(ExecContext(sess.conf, sess))
+
+
+def _rows_of(batches):
+    from spark_rapids_tpu.data.column import device_to_host
+
+    return [r for b in batches for r in device_to_host(b).to_rows()]
+
+
+def test_segment_read_over_trimmed_block_keeps_row_sequence():
+    """Segments index a block's rows by the counts/starts of the build
+    that made it; a block packed at the bucket of its live rows must
+    give the same sequence — whole, split, and as the host path has
+    it."""
+    from spark_rapids_tpu.shuffle import device_shuffle as DS
+
+    mark = DS.GLOBAL.counters()
+    dev = _drained_exchange("device")
+    items = dev.aqe_materialize()
+    trimmed = DS.GLOBAL.metrics_since(mark)
+    assert trimmed["shuffle.trimmedBlocks"] == len(items) == 2, trimmed
+    assert trimmed["shuffle.trimmedRows"] == 2 * (4096 - 128), trimmed
+    item_counts = [it[1] for it in items]
+    whole = [_rows_of(dev.aqe_read(p)()) for p in range(3)]
+    assert sum(map(len, whole)) \
+        == int(sum(c.sum() for c in item_counts)) > 0
+    for p in range(3):
+        for k in (2, 3, 7):
+            slices = split_partition_segments(item_counts, p, k)
+            got = [r for segs in slices
+                   for r in _rows_of(dev.aqe_read(p, segs)())]
+            assert got == whole[p], (p, k)
+    # a new session installs a new spill framework: the device
+    # exchange's blocks are read out before the host one is made
+    host = _drained_exchange("host")
+    assert [_rows_of(host.aqe_read(p)()) for p in range(3)] == whole
+
+
 def test_skew_split_no_trigger_at_default_factor():
     # uniform keys never exceed 4x the median
     sess = _sess(SHUFFLED, TELE, {

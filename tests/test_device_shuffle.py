@@ -14,6 +14,7 @@ import pytest
 from conftest import REPO, cpu_worker_env
 
 import spark_rapids_tpu as srt
+from spark_rapids_tpu import types as T
 from spark_rapids_tpu.plan import functions as F
 from spark_rapids_tpu.shuffle import device_shuffle as DS
 
@@ -183,6 +184,152 @@ def test_tpch_mode_bit_identity(qnum):
         return tpch.QUERIES[qnum](tables).collect()
 
     assert _norm(run("device")) == _norm(run("host"))
+
+
+# ==========================================================================
+# count before pack: blocks built at the bucket of their live rows
+# ==========================================================================
+def _record_dispatches(monkeypatch, factory, what):
+    """Wrap the kernel a ``DS`` factory hands the exchange so that
+    every dispatch appends ``what(batch, *args)`` to the returned
+    list."""
+    calls = []
+    make = getattr(DS, factory)
+
+    def counting(*key):
+        kern = make(*key)
+
+        def call(batch, *args, **kw):
+            calls.append(what(batch, *args))
+            return kern(batch, *args, **kw)
+
+        return call
+
+    monkeypatch.setattr(DS, factory, counting)
+    return calls
+
+
+@pytest.fixture
+def trim_calls(monkeypatch):
+    """The ``out_rows`` of every ``jit_shuffle_trim`` dispatch."""
+    return _record_dispatches(monkeypatch, "trim_kernel",
+                              lambda batch, out_rows: out_rows)
+
+
+@pytest.fixture
+def build_calls(monkeypatch):
+    """The padded rows of every ``jit_shuffle_packedBuild`` dispatch."""
+    return _record_dispatches(monkeypatch, "packed_build_kernel",
+                              lambda batch, *args: batch.padded_rows)
+
+
+def _four_groups(sess, n=5000):
+    rng = np.random.RandomState(3)
+    df = sess.create_dataframe({"k": rng.randint(0, 4, n).tolist(),
+                                "v": rng.rand(n).tolist()},
+                               n_partitions=1)
+    return df.group_by("k").agg(F.sum("v").alias("s"),
+                                F.count("v").alias("c"))
+
+
+def test_sparse_block_is_packed_at_its_live_bucket(trim_calls,
+                                                   build_calls):
+    """Four groups leave the partial aggregate in its input's bucket
+    (8192 rows): the write must pack them at 128."""
+    sess = srt.Session(_mode_conf("device"))
+    dev = _four_groups(sess).collect()
+    m = sess.last_metrics
+    assert m["shuffle.trimmedBlocks"] >= 1, m
+    assert m["shuffle.trimmedRows"] == 8192 - 128, m
+    assert trim_calls == [128] and build_calls == [128]
+    # one int64 column of the untrimmed block alone is 64 KiB
+    assert 0 < m["shuffle.deviceBytes"] < 8192 * 8, m
+    host = _four_groups(srt.Session(_mode_conf("host"))).collect()
+    assert _norm(dev) == _norm(host)
+    assert len(dev) == 4
+
+
+def test_dense_exchange_is_not_trimmed(trim_calls, build_calls):
+    """Inputs that fill their bucket are packed as they arrive: no
+    trim program, the build at the input's padding."""
+    conf = _mode_conf(
+        "device", **{"spark.rapids.tpu.sql.adaptive.enabled": False})
+    sess = srt.Session(conf)
+    n = 300
+    df = sess.create_dataframe({"x": list(range(n))},
+                               n_partitions=1).repartition(3)
+    assert sorted(r[0] for r in df.collect()) == list(range(n))
+    m = sess.last_metrics
+    assert m["shuffle.trimmedBlocks"] == 0, m
+    assert m["shuffle.trimmedRows"] == 0, m
+    assert trim_calls == [] and build_calls == [512]
+    assert m["shuffle.deviceBytes"] > 0, m
+
+
+def test_empty_input_batch_is_dropped_unbuilt(trim_calls, build_calls):
+    """A batch whose rows were all filtered away is removed at the
+    flush: nothing is hashed, trimmed or built for it."""
+    conf = _mode_conf(
+        "device", **{"spark.rapids.tpu.sql.adaptive.enabled": False})
+    sess = srt.Session(conf)
+    df = sess.create_dataframe({"x": list(range(300))}, n_partitions=1)
+    assert df.filter(df["x"] < 0).repartition(3).collect() == []
+    m = sess.last_metrics
+    assert trim_calls == [] and build_calls == []
+    assert m["shuffle.deviceBytes"] == 0, m
+    assert m["shuffle.trimmedBlocks"] == 0, m
+
+
+_TRIM_COLUMNS = {
+    # the widest value among the first rows: one byte-matrix width
+    "string": (T.STRING, lambda i: None if i % 7 == 3
+               else ("abcdefgh-%d" % (i % 10))[:1 + 9 * ((i + 1) % 3)]),
+    "nullable_numeric": (T.FLOAT64, lambda i: None if i % 5 == 1
+                         else i * 0.5),
+    "date": (T.DATE32, lambda i: 19000 + i),
+}
+
+
+@pytest.mark.parametrize("buckets", [(1024, 128), (4096, 512)])
+@pytest.mark.parametrize("kind", sorted(_TRIM_COLUMNS))
+def test_trim_program_keeps_live_rows(kind, buckets):
+    """The trim cuts padding only — rows, validity, lengths and
+    ``num_rows`` survive — and compiles once per output bucket."""
+    from spark_rapids_tpu.data.column import (DeviceBatch, HostBatch,
+                                              device_to_host,
+                                              host_to_device)
+    from spark_rapids_tpu.exec import kernel_cache
+
+    big, small = buckets
+    dtype, value = _TRIM_COLUMNS[kind]
+    schema = T.Schema([T.Field("c", dtype), T.Field("i", T.INT64)])
+    kern = DS.trim_kernel(schema)
+    assert kern.name == "shuffle_trim"
+
+    def check(n):
+        hb = HostBatch.from_pydict(
+            {"c": [value(i) for i in range(n)], "i": list(range(n))},
+            schema)
+        b = host_to_device(hb, min_bucket_rows=big)
+        assert b.padded_rows == big
+        cut = kern(b, small)
+        assert cut.padded_rows == small and cut.num_rows == n
+        for have, had in zip(cut.columns, b.columns):
+            np.testing.assert_array_equal(
+                np.asarray(have.validity), np.asarray(had.validity)[:small])
+            if had.lengths is None:
+                assert have.lengths is None
+            else:
+                np.testing.assert_array_equal(
+                    np.asarray(have.lengths),
+                    np.asarray(had.lengths)[:small])
+        got = device_to_host(DeviceBatch(cut.schema, cut.columns, n))
+        assert got.to_rows() == hb.to_rows()
+
+    check(small - 3)
+    misses = kernel_cache.GLOBAL.counters()["misses"]
+    check(small // 2 + 1)  # another count, the same bucket
+    assert kernel_cache.GLOBAL.counters()["misses"] == misses
 
 
 # ==========================================================================

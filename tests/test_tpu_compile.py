@@ -222,6 +222,32 @@ def test_q1_pipeline_compiles_for_v5e(one_chip, as_tpu):
     assert mem.temp_size_in_bytes <= 64 * mem.argument_size_in_bytes, mem
 
 
+def test_exchange_trim_and_build_compile_for_v5e(one_chip, as_tpu):
+    """The exchange's write of a sparse batch: the trim to a 128-row
+    bucket, then partition ids' sort and the packed build at that
+    size — a string, an f64 and an i64 column, as q1's groups are.
+    What leaves the trim is 128 rows, whatever came in."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.shuffle import device_shuffle as DS
+
+    schema = T.Schema([T.Field("s", T.STRING), T.Field("x", T.FLOAT64),
+                       T.Field("n", T.INT64)])
+    batch = DeviceBatch(schema, [_column(one_chip, f.dtype, 16)
+                                 for f in schema],
+                        _shape(one_chip, (), np.int32))
+    mem = _compile(lambda b: DS.trim(b, 128), batch).memory_analysis()
+    assert mem.output_size_in_bytes * 64 <= mem.argument_size_in_bytes, mem
+
+    def write(b):
+        cut = DS.trim(b, 128)
+        pids = jnp.arange(128, dtype=jnp.int32) % 2
+        return DS.packed_build(cut, pids, 2)
+
+    block, counts, _starts = _compile(write, batch).out_info
+    assert block.padded_rows == 128 and counts.shape == (2,)
+
+
 def test_mesh_exchange_compiles_for_four_chips(topo):
     """The distributed runner's stage shape — a collective exchange
     (``all_to_all`` of i64 and f64 tiles) plus the capacity demand
