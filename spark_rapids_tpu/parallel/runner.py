@@ -31,15 +31,19 @@ mesh — the analogue of Spark tasks producing the map-side input.
 """
 from __future__ import annotations
 
+import itertools
 import logging
 import random
 import time
+from types import SimpleNamespace
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..data.column import (DeviceBatch, DeviceColumn, HostBatch,
                            bucket_rows, device_to_host, host_to_device)
+from ..exec.kernel_cache import (_CachedKernel, expr_signature,
+                                 jit_kernel, schema_signature)
 from ..fault.errors import (TpuPayloadCorruption, TpuStageCrash,
                             TpuStageTimeout)
 from ..fault.injector import maybe_inject_fault
@@ -116,6 +120,169 @@ class _BcastRef:
         self.op = op
 
 
+class _InputRef:
+    """The ``slot``-th input of a stage program, in the tree ``_detach``
+    cuts loose from its request: what is left of a _LeafRef, of a
+    _StageRef (its partitioning, which the lowering reads) or of a
+    precomputed broadcast build side."""
+
+    def __init__(self, slot: int, partitioning=None):
+        self.slot = slot
+        self.partitioning = partitioning
+
+
+class _Unsignable(Exception):
+    """The signature cannot say all the lowering reads of an operator:
+    its stage's program is compiled for the request and not shared."""
+
+
+def _sort_signature(keys, bound: bool = True) -> Tuple:
+    """Sort keys as TpuSortExec's own kernel key says them; an unbound
+    key (a range partitioning keeps its plan's) has no dtype to say."""
+    return tuple((k.expr.sql(), str(k.expr.dtype) if bound else None,
+                  bool(k.ascending), bool(k.nulls_first))
+                 for k in keys or ())
+
+
+def _partitioning_signature(part):
+    """What the lowering reads of a partitioning: the kind, and the
+    keys as they print (they are bound against a schema the
+    operator's signature holds)."""
+    from ..shuffle.partitioning import (HashPartitioning,
+                                        RangePartitioning,
+                                        RoundRobinPartitioning,
+                                        SinglePartitioning)
+
+    if part is None:
+        return None
+    if isinstance(part, HashPartitioning):
+        return ("hash", tuple(k.sql() for k in part.keys))
+    if isinstance(part, RangePartitioning):
+        return ("range", _sort_signature(part._bound_keys),
+                _sort_signature(part.sort_keys, bound=False))
+    if isinstance(part, (SinglePartitioning, RoundRobinPartitioning,
+                         _ResumedPartitioning)):
+        return (type(part).__name__,)
+    raise _Unsignable(type(part).__name__)
+
+
+def _operator_signature(op) -> Tuple:
+    """What ``_lower`` and the operator's raw body read of ``op``: its
+    kind, bound expressions and schemas, said as the operator's own
+    kernel keys say them (exec/*.py).  An operator not listed here is
+    _Unsignable, never guessed at."""
+    from ..exec import basic as B
+    from ..exec.aggregate import TpuHashAggregateExec
+    from ..exec.coalesce import TpuCoalesceBatchesExec
+    from ..exec.exchange import TpuShuffleExchangeExec
+    from ..exec.fused import TpuFusedSegmentExec, _member_fingerprint
+    from ..exec.generate import TpuGenerateExec
+    from ..exec.joins import TpuHashJoinExec
+    from ..exec.sort import TpuSortExec
+
+    kind = type(op).__name__
+    if isinstance(op, TpuCoalesceBatchesExec):
+        return (kind,)                  # lowered as its child
+    head = (kind, tuple(schema_signature(c.schema) for c in op.children))
+    if isinstance(op, TpuShuffleExchangeExec):
+        return head + (_partitioning_signature(op.partitioning),)
+    if isinstance(op, TpuHashJoinExec):
+        return head + (
+            op.how, expr_signature(op.left_keys),
+            expr_signature(op.right_keys),
+            tuple(k.sql() for k in op.plan.left_keys),
+            tuple(k.sql() for k in op.plan.right_keys),
+            op.condition.sql() if op.condition is not None else None,
+            schema_signature(op.schema))
+    if isinstance(op, TpuFusedSegmentExec):
+        return head + tuple(_member_fingerprint(m) for m in op.members)
+    if isinstance(op, B.TpuProjectExec):
+        return head + (expr_signature(op.exprs),
+                       schema_signature(op.schema))
+    if isinstance(op, B.TpuFilterExec):
+        return head + (expr_signature([op.condition]),)
+    if isinstance(op, B.TpuExpandExec):
+        return head + (tuple(expr_signature(ps) for ps in op.projections),
+                       schema_signature(op.schema))
+    if isinstance(op, B.TpuUnionExec):
+        return head + (schema_signature(op.schema),)
+    if isinstance(op, B.TpuLocalLimitExec):
+        return head + (int(op.n),)
+    if isinstance(op, TpuSortExec):
+        return head + (_sort_signature(op.keys),)
+    if isinstance(op, TpuHashAggregateExec):
+        return head + (op.mode, expr_signature(op.keys),
+                       tuple(sp.func.sql() for sp in op.specs),
+                       schema_signature(op.schema))
+    if isinstance(op, TpuGenerateExec):
+        return head + (expr_signature(op.elements), bool(op.position),
+                       str(op._out_dtype), schema_signature(op.schema))
+    raise _Unsignable(kind)
+
+
+class _StageProgram:
+    """The body of one stage program: the lowering of a detached stage
+    tree under ``shard_map``, with its capacities as a static argument
+    (as a join's ``_expand`` takes its own).  It is what the kernel
+    cache keeps, and so holds no request: a runner that only lowers,
+    twins of the operators, the names of the capacity demands.
+
+    Beside it, by the inputs' shapes: ``used``, the capacities each
+    trace was built at (the overflow verdict compares demands with
+    them), and ``settled``, those the last request ended on, where the
+    next one starts."""
+
+    def __init__(self, lowering, tree, aux_keys: List[str], post):
+        self.__name__ = self.__qualname__ = "stage"
+        self.lowering = lowering
+        self.tree = tree
+        self.aux_keys = aux_keys
+        self.post = post
+        self.used: Dict = {}
+        self.settled: Dict = {}
+
+    def __call__(self, caps: Tuple, *stacked):
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec as P
+
+        low = self.lowering
+        used: Dict[str, int] = {}
+
+        def per_shard(*shards):
+            env = [X.squeeze_leading(b) for b in shards]
+            aux: Dict = {}
+            out = low._lower(self.tree, env, aux, dict(caps), used)
+            if self.post is not None:
+                out = self.post(out)
+            # aux (capacity demands) replicated via pmax so EVERY
+            # controller process reads the same overflow verdict and
+            # takes the same retry path (multi-process SPMD needs
+            # identical host control flow on all controllers).
+            # As int32, saturated: the TPU compiler lowers no 64-bit
+            # all-reduce but a sum ("Supported lowering only of Sum
+            # all reduce"), and a row count past 2^31 must still
+            # read as an overflow
+            return (X.unsqueeze_leading(out),
+                    tuple(jax.lax.pmax(
+                        jnp.minimum(aux[k].reshape(()), 2 ** 31 - 1)
+                        .astype(jnp.int32), low.axis)
+                        for k in self.aux_keys))
+
+        spec = P(low.axis)
+        out = jax.shard_map(
+            per_shard, mesh=low.mesh, in_specs=(spec,) * len(stacked),
+            out_specs=(spec, (P(),) * len(self.aux_keys)))(*stacked)
+        self.used[caps, _shapes(stacked)] = used
+        return out
+
+
+def _shapes(batches) -> Tuple:
+    import jax
+
+    return tuple(a.shape for a in jax.tree_util.tree_leaves(batches))
+
+
 class _Stage:
     def __init__(self, sid: int, root):
         self.sid = sid
@@ -143,6 +310,21 @@ class DistributedRunner:
         #: mesh run that never left device 0 shows here, as
         #: ``distributed.numShardDevices`` in the session's metrics
         self.shard_device_ids: set = set()
+        #: this request's stage-program dispatches: those that traced
+        #: nothing, those that traced (and so compiled, or read the
+        #: persistent cache), and the attempts a capacity overflow cost
+        self.stage_hits = 0
+        self.stage_compiles = 0
+        self.stage_retries = 0
+
+    def metrics(self) -> Dict[str, int]:
+        """The request's ``distributed.*`` counters, for
+        ``Session.last_metrics``."""
+        return {
+            "distributed.numShardDevices": len(self.shard_device_ids),
+            "distributed.stagePrograms.hits": self.stage_hits,
+            "distributed.stagePrograms.compiles": self.stage_compiles,
+            "distributed.stageRetries": self.stage_retries}
 
     # ---------------- fault tolerance ---------------------------------
     @staticmethod
@@ -417,7 +599,8 @@ class DistributedRunner:
                       else _empty_batch(node.schema)
                       for bs in shard_lists]
         shards = self._verify_host_roundtrip(shards, ctx)
-        placed = self._place(self._stack_host(shards))
+        with trace_range("MeshPlace"):
+            placed = self._place(self._stack_host(shards))
         self.shard_device_ids.update(
             s.device.id for s in placed.num_rows.addressable_shards)
         return placed
@@ -725,8 +908,10 @@ class DistributedRunner:
 
     def _lower(self, node, env: Dict, aux: Dict, caps: Dict,
                used_caps: Dict) -> DeviceBatch:
-        """Trace-time recursive lowering: returns the (traced) output
-        batch of ``node`` given leaf/stage inputs in ``env``."""
+        """Trace-time recursive lowering of a detached stage tree
+        (``_detach``): returns the (traced) output batch of ``node``
+        given the program's inputs in ``env``, by slot.  Capacities are
+        named by their operator's place in the tree."""
         import jax.numpy as jnp
 
         from ..exec import basic as B
@@ -740,8 +925,8 @@ class DistributedRunner:
         from ..exec.sort import TpuSortExec
         from ..exec.window import TpuWindowExec
 
-        if isinstance(node, (_LeafRef, _StageRef)):
-            return env[self._env_key(node)]
+        if isinstance(node, _InputRef):
+            return env[node.slot]
         if isinstance(node, tuple):
             op, *kids = node
             if isinstance(op, TpuShuffleExchangeExec):
@@ -755,19 +940,16 @@ class DistributedRunner:
                 # cap the per-destination tile so exchange output stops
                 # inflating padded size P-fold (Weak #3): start at ~2x
                 # the even share, detect overflow, retry bigger
-                return self._capped_exchange(body, pids, f"exch{id(op)}",
+                return self._capped_exchange(body, pids, f"exch{op.place}",
                                              aux, caps, used_caps)
             if isinstance(op, (TpuCoalesceBatchesExec,)):
                 return self._lower(kids[0], env, aux, caps, used_caps)
             if isinstance(op, TpuHashJoinExec):
                 lb = self._lower(kids[0], env, aux, caps, used_caps)
-                if isinstance(op, TpuBroadcastHashJoinExec):
-                    rb = env.get(f"bcast{id(op)}")
-                    if rb is None:  # no precompute (nested build side)
-                        rb = self.transport.replicate(self._lower(
-                            kids[1], env, aux, caps, used_caps))
-                else:
-                    rb = self._lower(kids[1], env, aux, caps, used_caps)
+                # a broadcast join's build side is an input: the
+                # replicated batch _prepare_broadcasts gathered
+                rb = self._lower(kids[1], env, aux, caps, used_caps)
+                if not isinstance(op, TpuBroadcastHashJoinExec):
                     # colocation is a correctness invariant, not a
                     # planner courtesy: verify both sides arrive
                     # hash-partitioned on the join keys (or single)
@@ -780,18 +962,18 @@ class DistributedRunner:
                             lb, self._hash_pids_by_exprs(
                                 lb, op.plan.left_keys,
                                 op.children[0].schema),
-                            f"jexl{id(op)}", aux, caps, used_caps)
+                            f"jexl{op.place}", aux, caps, used_caps)
                         rb = self._capped_exchange(
                             rb, self._hash_pids_by_exprs(
                                 rb, op.plan.right_keys,
                                 op.children[1].schema),
-                            f"jexr{id(op)}", aux, caps, used_caps)
+                            f"jexr{op.place}", aux, caps, used_caps)
                     elif verdict == "unsupported":
                         raise DistributedUnsupported(
                             "shuffled join children are not colocated "
                             "on the join keys — plan shape would "
                             "produce wrong rows")
-                key = f"join{id(op)}"
+                key = f"join{op.place}"
                 cap = caps.get(key)
                 if cap is None:
                     cap = bucket_rows(
@@ -833,7 +1015,7 @@ class DistributedRunner:
                 if not self._sort_presorted(kids[0], op):
                     pids = self._range_pids(child, op.keys)
                     child = self._capped_exchange(
-                        child, pids, f"rexch{id(op)}", aux, caps,
+                        child, pids, f"rexch{op.place}", aux, caps,
                         used_caps)
                 return op._compute(child)
             if isinstance(op, TpuWindowExec):
@@ -887,30 +1069,75 @@ class DistributedRunner:
         return f"stage{ref.stage_id}"
 
     # ---------------- stage execution ---------------------------------
-    def _collect_refs(self, node, out: List, cut_broadcast=False):
-        """Inputs of a stage program in trace order.  With
-        ``cut_broadcast`` the build subtree of each broadcast join is
-        replaced by its precomputed _BcastRef input."""
-        from ..exec.joins import TpuBroadcastHashJoinExec
+    def _detach(self, node, inputs: List, place):
+        """The tree a stage program is lowered from, cut loose from
+        its request: every operator a children-detached twin numbered
+        by its ``place`` in the tree (what its capacities are named
+        by), every leaf, earlier stage and broadcast build side an
+        _InputRef.  The refs they stood for go to ``inputs``, in slot
+        order.  A program outlives its request in the kernel cache; a
+        live operator would pin its plan subtree, and through a leaf
+        the uploads below it."""
+        from ..exec.joins import TpuBroadcastHashJoinExec, TpuHashJoinExec
 
         if isinstance(node, (_LeafRef, _StageRef)):
-            out.append(node)
-        elif isinstance(node, tuple):
-            if cut_broadcast and isinstance(node[0],
-                                            TpuBroadcastHashJoinExec):
-                self._collect_refs(node[1], out, cut_broadcast)
-                out.append(_BcastRef(node[0]))
-                return
-            for k in node[1:]:
-                self._collect_refs(k, out, cut_broadcast)
+            inputs.append(node)
+            return _InputRef(len(inputs) - 1,
+                             getattr(node, "partitioning", None))
+        op, *kids = node
+        twin = op.kernel_twin()
+        twin.place = next(place)
+        # the lowering calls raw bodies only, and a kernel its operator
+        # jitted for itself (key=None: the exchange's) is bound to the
+        # live operator, children and all
+        for name, held in list(vars(twin).items()):
+            if isinstance(held, _CachedKernel):
+                delattr(twin, name)
+        if hasattr(twin, "plan"):
+            # the host plan node holds its subtree; the lowering reads
+            # a join's keys off it and nothing else
+            twin.plan = SimpleNamespace(
+                left_keys=op.plan.left_keys,
+                right_keys=op.plan.right_keys) \
+                if isinstance(op, TpuHashJoinExec) else None
+        if isinstance(op, TpuBroadcastHashJoinExec):
+            # the build side is gathered once a query, as a program of
+            # its own (_prepare_broadcasts), and comes in replicated
+            left = self._detach(kids[0], inputs, place)
+            inputs.append(_BcastRef(op))
+            return (twin, left, _InputRef(len(inputs) - 1))
+        return (twin, *[self._detach(k, inputs, place) for k in kids])
 
-    def _collect_aux_keys(self, node, out: List[str],
-                          cut_broadcast=False):
-        """Keys of capacity-checked collectives in this stage: joins
-        (static output capacity) and capped exchanges (per-destination
-        tile capacity).  With ``cut_broadcast``, broadcast build
-        subtrees are skipped (their collectives run in the precompute
-        program, not this stage's)."""
+    def _signature(self, node) -> Tuple:
+        if isinstance(node, _InputRef):
+            return ("in", _partitioning_signature(node.partitioning))
+        op, *kids = node
+        return (_operator_signature(op),
+                *[self._signature(k) for k in kids])
+
+    def _program_key(self, tree, post, what: str):
+        """The kernel-cache key of a stage program — all its trace
+        reads but the capacities (its static argument) and the inputs'
+        shapes (the jit's own): the operators, the mesh, the transport,
+        the row bucket, the ``post`` hook.  None where an operator
+        cannot be signed: that program is compiled for this request."""
+        try:
+            sig = self._signature(tree)
+        except _Unsignable as e:
+            log.info("%s: no signature for %s; its program is compiled "
+                     "for this request only", what, e)
+            return None
+        devices = self.mesh.devices
+        return ("mesh", sig, tuple(int(d.id) for d in devices.flat),
+                tuple(devices.shape), tuple(self.mesh.axis_names),
+                type(self.transport).__module__,
+                type(self.transport).__qualname__, self.min_bucket,
+                getattr(post, "__name__", None), "stage")
+
+    def _collect_aux_keys(self, node, out: List[str]):
+        """Keys of capacity-checked collectives in a detached stage
+        tree: joins (static output capacity) and capped exchanges
+        (per-destination tile capacity)."""
         from ..exec.exchange import TpuShuffleExchangeExec
         from ..exec.joins import (TpuBroadcastHashJoinExec,
                                   TpuHashJoinExec)
@@ -918,28 +1145,22 @@ class DistributedRunner:
         from ..shuffle.partitioning import SinglePartitioning
 
         if isinstance(node, tuple):
-            if cut_broadcast and isinstance(node[0],
-                                            TpuBroadcastHashJoinExec):
-                out.append(f"join{id(node[0])}")
-                self._collect_aux_keys(node[1], out, cut_broadcast)
-                return
-            if isinstance(node[0], TpuHashJoinExec):
-                op = node[0]
-                out.append(f"join{id(op)}")
+            op = node[0]
+            if isinstance(op, TpuHashJoinExec):
+                out.append(f"join{op.place}")
                 if not isinstance(op, TpuBroadcastHashJoinExec) and \
                         self._join_colocation(
                             op, node[1], node[2]) == "repair":
-                    out.append(f"jexl{id(op)}")
-                    out.append(f"jexr{id(op)}")
-            if isinstance(node[0], TpuShuffleExchangeExec) and \
-                    not isinstance(node[0].partitioning,
-                                   SinglePartitioning):
-                out.append(f"exch{id(node[0])}")
-            if isinstance(node[0], TpuSortExec) and \
-                    not self._sort_presorted(node[1], node[0]):
-                out.append(f"rexch{id(node[0])}")
+                    out.append(f"jexl{op.place}")
+                    out.append(f"jexr{op.place}")
+            if isinstance(op, TpuShuffleExchangeExec) and \
+                    not isinstance(op.partitioning, SinglePartitioning):
+                out.append(f"exch{op.place}")
+            if isinstance(op, TpuSortExec) and \
+                    not self._sort_presorted(node[1], op):
+                out.append(f"rexch{op.place}")
             for k in node[1:]:
-                self._collect_aux_keys(k, out, cut_broadcast)
+                self._collect_aux_keys(k, out)
 
     def _collect_broadcasts(self, node, out: List):
         """Broadcast joins of this stage in post-order (inner builds
@@ -954,20 +1175,20 @@ class DistributedRunner:
 
     def _run_program(self, root, env_stacked: Dict, caps: Dict,
                      what: str, post=None) -> DeviceBatch:
-        """jit + shard_map the lowering of ``root``; retries with grown
-        capacities on collective overflow.  ``post`` (traced hook) runs
-        on the per-shard output before unstacking — the broadcast
-        precompute passes the replicate here.
+        """Dispatch the stage program that lowers ``root``; retries
+        with grown capacities on collective overflow.  ``post`` (traced
+        hook) runs on the per-shard output before unstacking — the
+        broadcast precompute passes the replicate here.
 
-        Every attempt is its own program (capacities are shapes), so it
-        compiles again: each is logged under ``what`` as it is
-        dispatched and as it answers, with its seconds and the demands
-        that overflowed — a run that is cut short still says which
-        program it was in, on which attempt."""
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
+        The program comes from the kernel cache (``jit_mesh_stage``):
+        a request whose stage signs like an earlier one's, over inputs
+        in the same row buckets, starts at the capacities that one
+        ended on, traces nothing and dispatches once.  A capacity is a
+        shape, so an attempt at a new one compiles: each attempt is
+        logged under ``what`` as it is dispatched and as it answers,
+        with its seconds and the demands that overflowed — a run that
+        is cut short still says which program it was in, on which
+        attempt."""
         from ..shuffle.device_shuffle import collective_timer
         from .elastic import guarded_call
 
@@ -978,88 +1199,82 @@ class DistributedRunner:
         maybe_inject_fault("stage.run")
 
         refs: List = []
-        self._collect_refs(root, refs, cut_broadcast=True)
-        in_keys = [self._env_key(r) for r in refs]
-        ins = [env_stacked[k] for k in in_keys]
-
+        tree = self._detach(root, refs, itertools.count())
+        ins = tuple(env_stacked[self._env_key(r)] for r in refs)
         aux_keys: List[str] = []
-        self._collect_aux_keys(root, aux_keys, cut_broadcast=True)
-        aux_keys = sorted(aux_keys)
+        self._collect_aux_keys(tree, aux_keys)
+        aux_keys.sort()
+
+        kern = jit_kernel(
+            _StageProgram(DistributedRunner(self.mesh, self.min_bucket,
+                                            self.transport),
+                          tree, aux_keys, post),
+            key=self._program_key(tree, post, what), kind="mesh",
+            static_argnums=(0,))
+        # on a hit the first caller's body: what it traced is what runs
+        program = kern.fn
+        shapes = _shapes(ins)
+        # this program's capacities, by name: what an earlier attempt
+        # of this request grew, else what the last request settled on
+        mine = caps.setdefault(what, {})
+        if not mine:
+            mine.update(program.settled.get(shapes, ()))
+        # same dispatch discipline as exchange_step: a cancelled
+        # query must not join a mesh-wide collective its peers
+        # will wait on, and the dispatch wall of an
+        # exchange-bearing program accrues to shuffle.collectiveTime.
+        # guarded_call layers the elastic deadline/heartbeat watch on
+        # top (fault.peer.collectiveTimeoutMs) so a dead peer turns
+        # into TpuPeerLost instead of an indefinite hang.
+        collective = post is not None or self._has_collective(tree)
 
         for attempt in range(_MAX_JOIN_RETRIES):
-            used_caps: Dict = {}
+            at = tuple(sorted(mine.items()))
+            # a trace leaves its capacities in ``used``: none there
+            # yet means this dispatch traces, and so compiles
+            compiles = (at, shapes) not in program.used
             t0 = time.perf_counter()
             log.info("%s attempt %d: dispatching (%d inputs, up to %d "
                      "rows a shard)", what, attempt, len(ins),
                      max((b.columns[0].data.shape[1] for b in ins),
                          default=0))
-
-            def per_shard(*stacked):
-                env = {k: X.squeeze_leading(b)
-                       for k, b in zip(in_keys, stacked)}
-                aux: Dict = {}
-                out = self._lower(root, env, aux, caps, used_caps)
-                if post is not None:
-                    out = post(out)
-                # aux (capacity demands) replicated via pmax so EVERY
-                # controller process reads the same overflow verdict and
-                # takes the same retry path (multi-process SPMD needs
-                # identical host control flow on all controllers).
-                # As int32, saturated: the TPU compiler lowers no 64-bit
-                # all-reduce but a sum ("Supported lowering only of Sum
-                # all reduce"), and a row count past 2^31 must still
-                # read as an overflow
-                return (X.unsqueeze_leading(out),
-                        tuple(jax.lax.pmax(
-                            jnp.minimum(aux[k].reshape(()), 2 ** 31 - 1)
-                            .astype(jnp.int32), self.axis)
-                            for k in aux_keys))
-
-            spec = P(self.axis)
-            spmd = jax.jit(jax.shard_map(
-                per_shard, mesh=self.mesh,
-                in_specs=(spec,) * len(ins),
-                out_specs=(spec, (P(),) * len(aux_keys))))
-            # same dispatch discipline as exchange_step: a cancelled
-            # query must not join a mesh-wide collective its peers
-            # will wait on, and the dispatch wall of an
-            # exchange-bearing program accrues to shuffle.collectiveTime.
-            # guarded_call layers the elastic deadline/heartbeat watch on
-            # top (fault.peer.collectiveTimeoutMs) so a dead peer turns
-            # into TpuPeerLost instead of an indefinite hang.
-            if post is not None or self._has_collective(root):
-                def dispatch(spmd=spmd, ins=tuple(ins)):
+            if collective:
+                def dispatch(at=at):
                     with collective_timer():
-                        return spmd(*ins)
+                        return kern(at, *ins)
                 out, aux_vals = guarded_call(dispatch)
             else:
                 out, aux_vals = guarded_call(
-                    lambda spmd=spmd, ins=tuple(ins): spmd(*ins),
-                    site="stage.dispatch")
+                    lambda at=at: kern(at, *ins), site="stage.dispatch")
+            if compiles:
+                self.stage_compiles += 1
+            else:
+                self.stage_hits += 1
+            used = program.used[at, shapes]
             overflow = {}
             for k, v in zip(aux_keys, aux_vals):
                 total = int(np.asarray(v))
-                if total > used_caps.get(k, 0):
-                    caps[k] = bucket_rows(total, self.min_bucket)
+                if total > used.get(k, 0):
+                    mine[k] = bucket_rows(total, self.min_bucket)
                     overflow[k.rstrip("0123456789")] = (
-                        used_caps.get(k, 0), total)
-            log.info("%s attempt %d: answered in %.1f s (trace, compile "
-                     "and run)%s", what, attempt,
-                     time.perf_counter() - t0,
+                        used.get(k, 0), total)
+            log.info("%s attempt %d: answered in %.1f s%s%s", what,
+                     attempt, time.perf_counter() - t0,
+                     " (trace, compile and run)" if compiles else "",
                      f"; (capacity, demand) overflowed: {overflow}"
                      if overflow else "")
             if not overflow:
+                program.settled[shapes] = dict(mine)
                 return out
+            self.stage_retries += 1
         raise RuntimeError(
             f"{what}: collective capacity retries exhausted")
 
     @staticmethod
     def _has_collective(node) -> bool:
         """True when lowering ``node`` dispatches a mesh collective (a
-        shuffle exchange inside the program).  Precomputed broadcast
-        replicates run as their own program and are timed there via
-        ``post``; the rare inline nested-build replicate rides along
-        untimed rather than tagging every broadcast-join stage."""
+        shuffle exchange inside the program).  Broadcast replicates run
+        as programs of their own and are timed there via ``post``."""
         from ..exec.exchange import TpuShuffleExchangeExec
 
         stack = [node]
@@ -1158,8 +1373,10 @@ class DistributedRunner:
         # of launching the next one.
         for leaf in leaves:
             check_cancel(f"runner.leaf[{leaf.idx}]")
+            # one device runs the subtree, the host takes its rows and
+            # stacks them, the mesh gets the stack (MeshPlace inside)
             with tspans.span(f"leaf[{leaf.idx}]", kind="stage",
-                             node=leaf.node.name):
+                             node=leaf.node.name), trace_range("MeshLeaf"):
                 env_stacked[self._env_key(leaf)] = self._recover(
                     lambda leaf=leaf: self._run_leaf(leaf.node, ctx),
                     ctx, f"leaf[{leaf.idx}]")
@@ -1180,7 +1397,8 @@ class DistributedRunner:
             env_stacked[f"stage{stage.sid}"] = out
             self._record_stage_stats(ctx, stage.sid)
             self._maybe_checkpoint_stage(ctx, stage, out)
-        return self._collect_output(out, stages)
+        with trace_range("MeshCollect"):
+            return self._collect_output(out, stages)
 
     # ---------------- elastic checkpoint / resume ---------------------
     def _try_resume_stage(self, ctx, stage, stages):
@@ -1363,6 +1581,13 @@ def run_distributed(session, df, mesh=None, n_devices: int = 8,
     completed stages resume from its checkpoints instead of
     re-executing).  When None, no stage checkpointing happens — the
     behaviour existing callers rely on."""
+    # the request inside the program, planning to rows on the host
+    # (as Session._execute_native opens it)
+    with trace_range("Query", query_id=next(session._query_ids)):
+        return _run_request(session, df, mesh, n_devices, recovery)
+
+
+def _run_request(session, df, mesh, n_devices, recovery) -> HostBatch:
     from ..config import FAULT_PEER_COLLECTIVE_TIMEOUT_MS
     from ..plan.physical import ExecContext
     from .mesh import make_mesh
@@ -1380,6 +1605,8 @@ def run_distributed(session, df, mesh=None, n_devices: int = 8,
     axis = mesh.axis_names[0] if mesh.axis_names else _AX
     prev_deadline = elastic.install_collective_deadline(
         session.conf.get(FAULT_PEER_COLLECTIVE_TIMEOUT_MS))
+    # a runner a request: what outlives it, the stage programs and the
+    # capacities they settled on, is in the kernel cache
     runner = DistributedRunner(
         mesh, transport=make_transport(session.conf, axis))
     try:
@@ -1391,8 +1618,7 @@ def run_distributed(session, df, mesh=None, n_devices: int = 8,
         session.last_metrics = dict(
             getattr(session, "last_metrics", None) or {})
         session.last_metrics.update(_fault_stats.snapshot())
-        session.last_metrics["distributed.numShardDevices"] = \
-            len(runner.shard_device_ids)
+        session.last_metrics.update(runner.metrics())
         from ..shuffle.device_shuffle import GLOBAL as _shuffle_stats
 
         session.last_metrics.update(_shuffle_stats.metrics_since(

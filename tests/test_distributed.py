@@ -416,3 +416,381 @@ def test_distributed_range_sort_desc_nulls():
     got = run_distributed(sess, q(sess), mesh=_mesh(8)).to_rows()
     exp = q(Session(tpu_enabled=False)).collect()
     assert got == exp
+
+
+# ---------------------------------------------------------------------
+# stage programs that outlive their request (kernel cache, PR 28)
+# ---------------------------------------------------------------------
+def _stage_counters(sess):
+    m = sess.last_metrics
+    return (m["distributed.stagePrograms.compiles"],
+            m["distributed.stagePrograms.hits"],
+            m["distributed.stageRetries"])
+
+
+def _mesh_entries():
+    """(key, kernel) of every stage program the kernel cache holds."""
+    from spark_rapids_tpu.exec.kernel_cache import GLOBAL
+
+    with GLOBAL._lock:
+        return [(k[0], v) for k, v in GLOBAL._entries.items()
+                if k[0][0] == "mesh"]
+
+
+@pytest.fixture()
+def q3_cell(tmp_path):
+    """The four-chip cell's own files at 1/1000 of its rows: session,
+    q3's DataFrame, the pandas reference's rows, entry and config."""
+    import json
+    import os
+
+    from benchmark.harness import BENCHMARK_DIR, datagen, load_module
+    from benchmark.run import reference_answers
+    from spark_rapids_tpu import Session
+
+    with open(os.path.join(BENCHMARK_DIR, "configs",
+                           "tpch_sf1_chip4.json")) as f:
+        config = json.load(f)
+    q3 = load_module("queries", "q3")
+    rows = {t: max(4, n // 1000) for t, n in config["rows"].items()}
+    made = datagen.write_tables(str(tmp_path), sorted(q3.TABLES), rows, 5,
+                                config["parquet"])
+    sess = Session(dict(config["conf"]))
+    tables = {t: sess.read_parquet(os.path.join(str(tmp_path), t))
+              for t in made}
+    want = reference_answers(str(tmp_path), {"q3": q3})["q3"]
+    yield (sess, q3.build(tables), want, q3,
+           load_module("entries", "run_distributed"), config)
+    sess.close()
+
+
+def _q3_difference(q3, want, got, config):
+    from benchmark.harness import compare
+
+    return compare.difference(
+        want, got, q3.ORDERED,
+        config["guarantees"]["f64_relative_tolerance"])
+
+
+def test_q3_shuffled_on_four_devices_equals_the_reference(q3_cell):
+    sess, df, want, q3, entry, config = q3_cell
+    assert config["conf"][
+        "spark.rapids.tpu.sql.broadcastSizeThreshold"] == 0
+    got = entry.run(sess, df, config)
+    assert len(want) == 10
+    assert _q3_difference(q3, want, got, config) is None
+    assert entry.faults(sess.last_metrics, config) == []
+    compiles, hits, retries = _stage_counters(sess)
+    assert compiles >= 8 and hits == 0
+    # every stage went through the kernel cache under the one name
+    entries = _mesh_entries()
+    assert len(entries) == compiles - retries
+    assert {k.name for _key, k in entries} == {"mesh_stage"}
+
+
+def test_second_identical_request_compiles_nothing(q3_cell):
+    sess, df, want, q3, entry, config = q3_cell
+    from benchmark.harness.probes import CompileWatch
+
+    first = entry.run(sess, df, config)
+    stages = len(_mesh_entries())
+    watch = CompileWatch()
+    mark = watch.snapshot()
+    second = entry.run(sess, df, config)
+    assert _stage_counters(sess) == (0, stages, 0)
+    # by JAX's own events too: the leaves, trims and collect included
+    assert watch.since(mark)["xla_compiles"] == 0
+    assert second == first
+    assert _q3_difference(q3, want, second, config) is None
+    assert len(_mesh_entries()) == stages
+
+
+def _filter_agg(sess, data, bound):
+    from spark_rapids_tpu.plan import functions as F
+
+    df = sess.create_dataframe(dict(data))
+    return (df.filter(df["v"] > bound).group_by("k")
+            .agg(F.sum("v").alias("s"), F.count("v").alias("c")))
+
+
+def test_a_request_differing_in_one_literal_misses_the_stage_cache():
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.parallel.runner import run_distributed
+
+    rng = np.random.RandomState(3)
+    data = {"k": rng.randint(0, 20, 300), "v": rng.rand(300) * 100}
+    sess, cpu = Session(), Session(tpu_enabled=False)
+    run_distributed(sess, _filter_agg(sess, data, 10.0), mesh=_mesh(4))
+    stages = len(_mesh_entries())
+    run_distributed(sess, _filter_agg(sess, data, 10.0), mesh=_mesh(4))
+    assert _stage_counters(sess) == (0, stages, 0)
+    got = run_distributed(sess, _filter_agg(sess, data, 11.0),
+                          mesh=_mesh(4)).to_rows()
+    compiles, hits, _ = _stage_counters(sess)
+    # the stage that holds the filter is another program; the stages
+    # above it read no literal and are found again
+    assert compiles >= 1 and compiles + hits == stages
+    _assert_rows_equal(got, _filter_agg(cpu, data, 11.0).collect())
+
+
+def test_another_schema_misses_the_stage_cache():
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.parallel.runner import run_distributed
+
+    rng = np.random.RandomState(6)
+    data = {"k": rng.randint(0, 20, 300), "v": rng.rand(300) * 100}
+    whole = dict(data, v=data["v"].astype(np.int64))
+    sess, cpu = Session(), Session(tpu_enabled=False)
+    run_distributed(sess, _filter_agg(sess, data, 10.0), mesh=_mesh(4))
+    stages = len(_mesh_entries())
+    # the same plan text over a bigint column: every stage reads or
+    # hands on another dtype, so none is found
+    got = run_distributed(sess, _filter_agg(sess, whole, 10.0),
+                          mesh=_mesh(4)).to_rows()
+    assert _stage_counters(sess)[:2] == (stages, 0)
+    _assert_rows_equal(got, _filter_agg(cpu, whole, 10.0).collect())
+
+
+def test_another_mesh_misses_the_stage_cache():
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.parallel.runner import run_distributed
+
+    rng = np.random.RandomState(4)
+    data = {"k": rng.randint(0, 20, 300), "v": rng.rand(300) * 100}
+    sess, cpu = Session(), Session(tpu_enabled=False)
+    run_distributed(sess, _filter_agg(sess, data, 10.0), mesh=_mesh(4))
+    stages = len(_mesh_entries())
+    got = run_distributed(sess, _filter_agg(sess, data, 10.0),
+                          mesh=_mesh(2)).to_rows()
+    assert _stage_counters(sess)[:2] == (stages, 0)
+    assert len(_mesh_entries()) == 2 * stages
+    _assert_rows_equal(got, _filter_agg(cpu, data, 10.0).collect())
+
+
+def _skewed_join(sess, n_equal):
+    """600 x 100 rows; the first ``n_equal`` left keys meet every right
+    row, so the join's demand is ``n_equal * 100`` at the same shapes."""
+    k = np.arange(1, 601, dtype=np.int64) * 1000
+    k[:n_equal] = 0
+    l = sess.create_dataframe({"k": k,
+                               "v": np.arange(600, dtype=np.int64)})
+    r = sess.create_dataframe({"rk": np.zeros(100, dtype=np.int64),
+                               "w": np.arange(100, dtype=np.int64)})
+    return l.join(r, on=(["k"], ["rk"]), how="inner")
+
+
+def test_next_request_starts_at_the_settled_capacities():
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.parallel.runner import run_distributed
+
+    conf = {"spark.rapids.tpu.sql.broadcastSizeThreshold": 0}
+    sess, cpu = Session(dict(conf)), Session(tpu_enabled=False)
+    first = run_distributed(sess, _skewed_join(sess, 100),
+                            mesh=_mesh(4)).to_rows()
+    compiles, _hits, retries = _stage_counters(sess)
+    assert retries >= 1, "expected a capacity overflow"
+    stages = compiles - retries
+    again = run_distributed(sess, _skewed_join(sess, 100),
+                            mesh=_mesh(4)).to_rows()
+    # one dispatch a stage, from where the last request ended
+    assert _stage_counters(sess) == (0, stages, 0)
+    _assert_rows_equal(again, first)
+    _assert_rows_equal(first, _skewed_join(cpu, 100).collect())
+    # new data that overflows the settled capacity still grows it,
+    # and compiles for that
+    more = run_distributed(sess, _skewed_join(sess, 600),
+                           mesh=_mesh(4)).to_rows()
+    compiles, _hits, retries = _stage_counters(sess)
+    assert retries >= 1 and compiles >= 1
+    _assert_rows_equal(more, _skewed_join(cpu, 600).collect())
+
+
+def test_a_cached_stage_program_keeps_no_request_alive():
+    import gc
+    import weakref
+
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.parallel.runner import DistributedRunner
+    from spark_rapids_tpu.plan.physical import ExecContext
+
+    rng = np.random.RandomState(5)
+    data = {"k": rng.randint(0, 20, 300), "v": rng.rand(300) * 100}
+    sess = Session()
+    phys = sess.physical_plan(_filter_agg(sess, data, 10.0).plan)
+    runner = DistributedRunner(_mesh(4))
+    ctx = ExecContext(sess.conf, sess)
+    assert runner.run(phys, ctx).num_rows > 0
+    assert runner.stage_compiles >= 2
+    from spark_rapids_tpu.exec.base import TpuExec
+
+    nodes, todo = [], [phys]
+    while todo:
+        nodes.append(todo.pop())
+        todo.extend(nodes[-1].children)
+    # every device operator, the leaves' uploads among them.  (A host
+    # scan below them is kept by the aggregate's own kernel twin, whose
+    # ``plan`` is the host node: exec/aggregate.py, with or without a
+    # mesh.)
+    nodes = [n for n in nodes if isinstance(n, TpuExec)]
+    assert len(nodes) >= 5
+    gone = [weakref.ref(o) for o in (runner, ctx, *nodes)]
+    del runner, ctx, phys, nodes
+    gc.collect()
+    entries = _mesh_entries()
+    assert len(entries) >= 2
+    assert [w() for w in gone if w() is not None] == []
+    # what an entry does keep: a runner that only lowers
+    for _key, kern in entries:
+        low = kern.fn.lowering
+        assert not low.shard_device_ids and low.stage_compiles == 0
+
+
+def _mesh_program_identities():
+    """(name, fingerprint, capacity names) of every stage program of a
+    shuffled join + aggregate, for the two-process test below."""
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.parallel.runner import run_distributed
+    from spark_rapids_tpu.plan import functions as F
+
+    sess = Session({"spark.rapids.tpu.sql.broadcastSizeThreshold": 0})
+    j = _skewed_join(sess, 100)
+    run_distributed(sess, j.group_by("k").agg(F.sum("w").alias("s")),
+                    mesh=_mesh(4))
+    return sorted((k.name, k.fingerprint, list(k.fn.aux_keys))
+                  for _key, k in _mesh_entries())
+
+
+def test_two_processes_agree_on_every_stage_program():
+    # name, key and the order of the capacity outputs are part of the
+    # HLO module or decide it, so of the persistent compile cache's
+    # key: an id or an address in any of them makes every process
+    # compile every stage again
+    import json
+    import os
+    import subprocess
+    import sys
+
+    from conftest import cpu_worker_env
+
+    code = ("import json, sys; sys.path.insert(0, %r); "
+            "import conftest, test_distributed as t; "
+            "print('IDS' + json.dumps(t._mesh_program_identities()))"
+            % os.path.dirname(os.path.abspath(__file__)))
+    outs = []
+    for seed in ("1", "2"):
+        env = cpu_worker_env()
+        env["PYTHONHASHSEED"] = seed
+        p = subprocess.run([sys.executable, "-c", code], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert p.returncode == 0, p.stderr[-2000:]
+        outs.append([ln for ln in p.stdout.splitlines()
+                     if ln.startswith("IDS")][-1])
+    assert outs[0] == outs[1]
+    ids = json.loads(outs[0][3:])
+    assert len(ids) >= 3 and {i[0] for i in ids} == {"mesh_stage"}
+    assert any(i[2] for i in ids), "no capacity-checked program"
+
+
+# ---------------------------------------------------------------------
+# the stage signature against the operators' own kernel keys
+# ---------------------------------------------------------------------
+# parallel/runner.py:_operator_signature says again, by hand, what each
+# operator's own kernel key says in its __init__ (exec/*.py).  A stage
+# program is shared by that signature, so a field an operator's key
+# gains and the signature lacks would share a wrong program silently:
+# wrong rows, not a cache miss.  Until the operators hand their key out
+# themselves (ROADMAP Design 1), this is what fails first.
+def _own_kernel_keys(op):
+    """The keys ``op`` registered its own kernels under."""
+    from spark_rapids_tpu.exec.kernel_cache import GLOBAL, _CachedKernel
+
+    held = [k for v in vars(op).values()
+            for k in (v if isinstance(v, (list, tuple)) else [v])
+            if isinstance(k, _CachedKernel)]
+    with GLOBAL._lock:
+        return [key[0] for key, kern in GLOBAL._entries.items()
+                if any(kern is h for h in held)]
+
+
+def _parts(sig):
+    yield sig
+    if isinstance(sig, tuple):
+        for s in sig:
+            yield from _parts(s)
+
+
+def _unsaid(keys, signature):
+    """The fields of an operator's kernel keys that its stage signature
+    does not hold: all but the leading kind and a trailing phase
+    (``"count"``, ``"batch"``), which name the kernel and not what it
+    reads.  A tuple of fields may be said field by field (the fused
+    segment's members)."""
+    said = set(_parts(signature))
+    out = []
+    for key in keys:
+        fields = list(key[1:])
+        if fields and isinstance(fields[-1], str):
+            fields.pop()
+        out += [(key[0], f) for f in fields
+                if f not in said and not (
+                    isinstance(f, tuple) and f
+                    and all(p in said for p in f))]
+    return out
+
+
+def _signed_plan(kind):
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.plan import functions as F
+    from spark_rapids_tpu.plan import logical as L
+
+    sess = Session({
+        "spark.rapids.tpu.sql.fusion.enabled": kind == "TpuFusedSegmentExec",
+        "spark.rapids.tpu.sql.broadcastSizeThreshold": 0})
+    df = sess.create_dataframe({"k": np.arange(64, dtype=np.int64) % 5,
+                                "v": np.arange(64, dtype=np.float64)})
+    other = sess.create_dataframe({"rk": np.arange(5, dtype=np.int64),
+                                   "w": np.arange(5, dtype=np.int64)})
+    if kind == "TpuExpandExec":
+        q = L.DataFrame(sess, L.Expand(
+            df.plan, [[F.col("k").expr, F.col("v").expr],
+                      [F.col("k").expr, (F.col("v") * F.lit(2)).expr]],
+            ["k", "v"]))
+    elif kind == "TpuGenerateExec":
+        q = df.explode([F.col("k"), F.col("k") + 1], name="e")
+    else:
+        q = (df.filter(df["v"] > 3.0).with_column("u", F.col("v") * 2)
+             .join(other, on=(["k"], ["rk"]), how="inner")
+             .group_by("k").agg(F.sum("u").alias("s")).sort("k"))
+    return sess.physical_plan(q.plan)
+
+
+@pytest.mark.parametrize("kind", [
+    "TpuProjectExec", "TpuFilterExec", "TpuExpandExec", "TpuGenerateExec",
+    "TpuFusedSegmentExec", "TpuHashJoinExec", "TpuHashAggregateExec",
+    "TpuSortExec"])
+def test_stage_signature_says_all_the_operators_own_key_says(kind):
+    from spark_rapids_tpu.parallel.runner import _operator_signature
+
+    nodes, todo = [], [_signed_plan(kind)]
+    while todo:
+        nodes.append(todo.pop())
+        todo.extend(nodes[-1].children)
+    ops = [n for n in nodes
+           if kind in [c.__name__ for c in type(n).__mro__]]
+    assert ops, sorted({type(n).__name__ for n in nodes})
+    for op in ops:
+        keys = _own_kernel_keys(op)
+        assert keys, f"{kind} registered no keyed kernel"
+        assert _unsaid(keys, _operator_signature(op)) == []
+
+
+def test_a_field_the_signature_lacks_is_found():
+    from spark_rapids_tpu.parallel.runner import _operator_signature
+
+    plan = _signed_plan("TpuFilterExec")
+    while type(plan).__name__ != "TpuFilterExec":
+        plan = plan.children[0]
+    (key,) = _own_kernel_keys(plan)
+    grown = key + (("ansi", True),)
+    assert _unsaid([grown], _operator_signature(plan)) == [
+        ("filter", ("ansi", True))]

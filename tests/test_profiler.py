@@ -78,10 +78,18 @@ def test_q1_attribution_reconciles_with_scan_input():
         if s.in_padded_known:
             assert s.in_rows <= s.in_padded_known
         assert 0.0 <= s.padding_waste <= 1.0
-    # q1 is agg-dominated: the top-3 kernels by wall carry the
-    # majority of attributed compute
+    # q1 is agg-dominated: the top-3 kernels by wall carry the bulk of
+    # the attributed compute.  Since the exchange trims before it packs
+    # (PR 26) a warm q1 is twelve kernels of about a millisecond of
+    # enqueue each and the top three's share sits at 0.48-0.53, on
+    # either side of the half this asked for with the machine's load;
+    # three of twelve alike would carry 0.25
     walls = sorted((s.wall_ns for s in per), reverse=True)
-    assert sum(walls[:3]) >= 0.5 * sum(walls)
+    assert sum(walls[:3]) >= 0.35 * sum(walls)
+    # and the majority of the attributed input bytes, which follow
+    # from shapes and not from the clock
+    read = sorted((s.in_bytes for s in per), reverse=True)
+    assert sum(read[:3]) >= 0.5 * sum(read)
     # roofline rows are ranked by wall and carry derived rates
     rows = roofline_rows(stats, sess.last_h2d_ceiling_bps, top_n=10)
     assert rows == sorted(rows, key=lambda r: -r["wall_s"])
