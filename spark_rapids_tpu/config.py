@@ -234,11 +234,14 @@ continuous queries (`spark_rapids_tpu/streaming/`, docs/streaming.md):
   `<recovery.dir>/streams/<stream-fingerprint>/` (relocatable via
   `streaming.stateDir`) commits atomically AFTER each batch; a crash
   between batches replays the tick idempotently
-  (`Session.resume_stream` in a fresh process, bit-identical results,
+  (`Session.resume_stream` in a fresh process, the same results,
   `recovery.numStagesResumed > 0`).
-* Every decision emits a `stream_*` telemetry event; results are
-  bit-identical to a cold recompute of the same cumulative input,
-  including under fault injection and ladder degradation."""
+* Every decision emits a `stream_*` telemetry event; results equal a
+  cold recompute of the same cumulative input, including under fault
+  injection and ladder degradation: to the bit, except that a float
+  SUM/AVG the device computes equals the host engine's to rounding
+  (docs/streaming.md; `sql.variableFloatAgg.enabled=false` makes that
+  bit for bit too)."""
 
 
 _SERVING_CACHE_DOC = """\
@@ -989,10 +992,13 @@ INCOMPATIBLE_OPS = conf("spark.rapids.tpu.sql.incompatibleOps.enabled").doc(
     "Allow ops whose results may diverge from the host engine in corner "
     "cases (reference: spark.rapids.sql.incompatibleOps.enabled)").boolean_conf(False)
 ALLOW_FLOAT_AGG = conf("spark.rapids.tpu.sql.variableFloatAgg.enabled").doc(
-    "Allow floating-point aggregation on device.  Device partial sums "
-    "reduce in segment order, which differs from the host oracle's "
-    "order, so extreme values (±max, ±inf) can produce different — "
-    "equally valid — float results (reference: "
+    "Allow floating-point aggregation on device.  The device adds a "
+    "group's sorted rows block by block (and a TPU holds a float64 as "
+    "two float32), the host oracle row by row, so a float SUM/AVG "
+    "equals the host's to rounding, not to the bit, and extreme values "
+    "(±max, ±inf) can produce different — equally valid — results.  "
+    "Set false where a streaming tick or a degraded rung must match "
+    "the device's answer bit for bit (reference: "
     "spark.rapids.sql.variableFloatAgg.enabled; default true here "
     "because the device order is deterministic for a fixed plan)"
 ).boolean_conf(True)

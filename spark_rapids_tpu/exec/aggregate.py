@@ -4,9 +4,10 @@ Reference analogue: GpuHashAggregateExec (aggregate.scala:227-396) — the
 mode-aware (partial/final/complete) columnar aggregate.  The reference
 lowers to cudf's hash groupBy; hash tables scatter randomly, which is
 hostile to the TPU memory model, so this exec is sort-based: lexsort rows
-by key, derive segment ids at key-change boundaries, then segment
-reductions with a *static* segment count (the row bucket) so shapes stay
-XLA-friendly (SURVEY §7 Hard parts: sort + segment-reduce).
+by key, flag the key-change boundaries, then reduce the sorted segments
+by scans (``ops/kernels/segment.reduce_sorted``: no scatter, and a
+*static* output size, the row bucket, so shapes stay XLA-friendly;
+SURVEY §7 Hard parts: sort + segment-reduce).
 
 The whole aggregate — key eval, sort, segment ids, every buffer reduction,
 and the finalize expressions — traces into ONE jitted XLA program per
@@ -14,8 +15,6 @@ and the finalize expressions — traces into ONE jitted XLA program per
 reduction loops.
 """
 from __future__ import annotations
-
-from typing import List
 
 from .. import types as T
 from ..data.column import DeviceBatch, DeviceColumn
@@ -27,30 +26,6 @@ from ..ops.kernels import segment as seg
 from ..utils import metrics as M
 from ..utils.tracing import trace_range
 from .base import DevicePartitionedData, TpuExec
-
-
-def _string_minmax_device(col: DeviceColumn, valid, seg_ids,
-                          n_segments: int, op: str):
-    """min/max over a string column per segment via rank encoding:
-    lexsort the values once, invert to per-row ranks, reduce ranks per
-    segment, then gather the winning rows."""
-    import jax.numpy as jnp
-
-    n = col.data.shape[0]
-    order = seg.lexsort_device([col], pad_valid=valid)
-    rank = jnp.zeros((n,), dtype=jnp.int32).at[order].set(
-        jnp.arange(n, dtype=jnp.int32))
-    big = n + 1
-    key = jnp.where(valid, rank, big if op == "min" else -1)
-    import jax
-
-    fn = jax.ops.segment_min if op == "min" else jax.ops.segment_max
-    picked_rank = fn(key, seg_ids, num_segments=n_segments)
-    safe = jnp.clip(picked_rank, 0, n - 1).astype(jnp.int32)
-    picked_row = order[safe]
-    data = col.data[picked_row]
-    lengths = col.lengths[picked_row]
-    return data, lengths
 
 
 class TpuHashAggregateExec(TpuExec):
@@ -137,7 +112,6 @@ class TpuHashAggregateExec(TpuExec):
         expressions over raw input rows; "merge" treats the batch as
         buffer-form (keys + buffers).  ``emit``: "buffers" outputs the
         grouped buffer form; "final" applies the finalize expressions."""
-        import jax
         import jax.numpy as jnp
 
         nkeys = len(self.keys)
@@ -154,129 +128,72 @@ class TpuHashAggregateExec(TpuExec):
                                  c.lengths) for c in key_cols]
 
         # ----- sort + segments -----------------------------------------
+        idx = jnp.arange(padded, dtype=jnp.int32)
+        # padding rows sort last, each its own segment
+        pad_sorted = idx < rm.sum()
         if nkeys:
             order = seg.lexsort_device(key_cols, pad_valid=rm)
             sorted_keys = [G.gather_column(c, order) for c in key_cols]
-            pad_sorted = rm[order]
-            seg_ids = seg.segment_ids_device(sorted_keys,
-                                             pad_valid=pad_sorted)
-            total = rm.sum().astype(jnp.int32)
-            n_real = jnp.where(
-                total > 0,
-                seg_ids[jnp.clip(total - 1, 0, padded - 1)] + 1, 0)
+            change = seg.segment_change_device(sorted_keys,
+                                               pad_valid=pad_sorted)
+            n_real = (change & pad_sorted).sum().astype(jnp.int32)
         else:
-            order = jnp.arange(padded, dtype=jnp.int32)
-            pad_sorted = rm
-            seg_ids = jnp.where(rm, 0,  # padding rows -> own segments
-                                jnp.arange(padded, dtype=jnp.int32) + 1
-                                ).astype(jnp.int32)
-            sorted_keys = []
+            order = None
+            change = ~pad_sorted        # row 0 starts the one real segment
             n_real = jnp.asarray(1, dtype=jnp.int32)
+        out_valid_seg = idx < n_real
 
-        out_valid_seg = jnp.arange(padded, dtype=jnp.int32) < n_real
-
-        # output key columns = first row of each segment
-        idx = jnp.arange(padded, dtype=jnp.int64)
-        seg_starts = jax.ops.segment_min(idx, seg_ids, num_segments=padded)
-        safe_starts = jnp.clip(seg_starts, 0, padded - 1).astype(jnp.int32)
-        out_keys = []
-        for c in sorted_keys:
-            g = G.gather_column(c, safe_starts, out_valid_seg)
-            out_keys.append(g)
-
-        # ----- reductions ----------------------------------------------
+        # ----- reductions: every buffer's, and each key's first row, as
+        # (column over the rows as they stand, op, buffer dtype) ---------
+        specs = [(c, "first_any", c.dtype) for c in key_cols]
         if phase == "update":
-            buffers = self._update_buffers(
-                batch, order, pad_sorted, seg_ids, padded, out_valid_seg)
+            specs += self._update_specs(batch, rm)
         else:
-            buffers = self._merge_buffers(
-                batch, order, pad_sorted, seg_ids, padded, out_valid_seg,
-                nkeys)
+            specs += self._merge_specs(batch, rm, nkeys)
+        out_cols = []
+        for (data, valid, lengths), (_, _, dtype) in zip(
+                seg.reduce_sorted(change, order, [sp[:2] for sp in specs]),
+                specs):
+            if lengths is None and data.dtype != dtype.jnp_dtype:
+                data = data.astype(dtype.jnp_dtype)
+            out_cols.append(DeviceColumn(dtype, data, valid & out_valid_seg,
+                                         lengths))
 
         if emit == "buffers":
-            out_cols = out_keys + buffers
             return DeviceBatch(self.buffer_schema, out_cols, n_real)
-        return self._finalize(out_keys, buffers, n_real, padded,
-                              out_valid_seg)
+        return self._finalize(out_cols[:nkeys], out_cols[nkeys:], n_real,
+                              padded, out_valid_seg)
 
     # ------------------------------------------------------------------
-    def _update_buffers(self, batch, order, pad_sorted, seg_ids, padded,
-                        out_valid_seg) -> List[DeviceColumn]:
+    def _update_specs(self, batch, rm) -> list:
         import jax.numpy as jnp
 
-        buffers = []
+        padded = batch.padded_rows
+        specs = []
         for sp in self.specs:
             func: AggregateFunction = sp.func
             if func.child is None:  # count(*)
-                inputs = [(jnp.ones((padded,), dtype=jnp.int64),
-                           pad_sorted, None)]
+                inputs = [DeviceColumn(
+                    T.INT64, jnp.ones((padded,), dtype=jnp.int64), rm)]
             else:
                 c = as_device_column(func.child.eval_tpu(batch), padded)
-                valid = (c.validity & batch.row_mask())[order]
-                inputs = [(c.data[order], valid,
-                           c.lengths[order] if c.lengths is not None
-                           else None)]
+                inputs = [DeviceColumn(c.dtype, c.data, c.validity & rm,
+                                       c.lengths)]
             for (op, which), bt in zip(func.updates, func.buffer_dtypes()):
-                vals, valid, lens = inputs[which]
-                buffers.append(self._reduce_one(
-                    vals, valid, lens, seg_ids, padded, op, bt,
-                    out_valid_seg, present=pad_sorted))
-        return buffers
+                specs.append((inputs[which], op, bt))
+        return specs
 
-    def _merge_buffers(self, batch, order, pad_sorted, seg_ids, padded,
-                       out_valid_seg, nkeys) -> List[DeviceColumn]:
-        buffers = []
+    def _merge_specs(self, batch, rm, nkeys) -> list:
+        specs = []
         col_idx = nkeys
         for sp in self.specs:
             func: AggregateFunction = sp.func
             for op, bt in zip(func.merges, func.buffer_dtypes()):
                 c = batch.columns[col_idx]
-                valid = (c.validity & batch.row_mask())[order]
-                lens = c.lengths[order] if c.lengths is not None else None
-                buffers.append(self._reduce_one(
-                    c.data[order], valid, lens, seg_ids, padded, op, bt,
-                    out_valid_seg, present=pad_sorted))
+                specs.append((DeviceColumn(c.dtype, c.data, c.validity & rm,
+                                           c.lengths), op, bt))
                 col_idx += 1
-        return buffers
-
-    def _reduce_one(self, vals, valid, lens, seg_ids, padded, op,
-                    buf_dtype: T.DType, out_valid_seg,
-                    present=None) -> DeviceColumn:
-        import jax.numpy as jnp
-
-        if buf_dtype.id is T.TypeId.STRING:
-            col = DeviceColumn(buf_dtype, vals, valid, lens)
-            if op in ("min", "max"):
-                data, lengths = _string_minmax_device(
-                    col, valid, seg_ids, padded, op)
-                import jax
-
-                counts = jax.ops.segment_sum(
-                    valid.astype(jnp.int32), seg_ids, num_segments=padded)
-                ok = (counts > 0) & out_valid_seg
-                return DeviceColumn(buf_dtype, data, ok, lengths)
-            # first / last pick a row index; gather bytes+lengths by it
-            if op in ("first_any", "last_any"):
-                eligible = present if present is not None \
-                    else jnp.ones_like(valid)
-            else:
-                eligible = valid
-            safe, has = seg.segment_pick_device(eligible, seg_ids,
-                                                padded, op)
-            ok = has & out_valid_seg
-            if op in ("first_any", "last_any"):
-                ok = ok & valid[safe]
-            return DeviceColumn(buf_dtype, vals[safe], ok, lens[safe])
-
-        data, ok = seg.segment_reduce_device(vals, valid, seg_ids, padded,
-                                             op, present=present)
-        if op == "count":
-            ok = out_valid_seg
-        else:
-            ok = ok & out_valid_seg
-        if data.dtype != buf_dtype.jnp_dtype:
-            data = data.astype(buf_dtype.jnp_dtype)
-        return DeviceColumn(buf_dtype, data, ok)
+        return specs
 
     # ------------------------------------------------------------------
     def _finalize(self, out_keys, buffers, n_real, padded,

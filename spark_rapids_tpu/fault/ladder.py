@@ -16,9 +16,11 @@ retries, the query walks DOWN the ladder instead of failing —
     rung 2: the CPU-exec plan (``plan.overrides.cpu_exec_plan`` — no
             TPU overrides at all; the oracle engine)
 
-Every rung produces bit-identical results by construction (the host
-engine is the equality oracle the device plan is tested against), so
-degradation trades throughput for availability, never correctness.
+Every rung produces the same results by construction (the host engine
+is the oracle the device plan is tested against; a float SUM/AVG of a
+device rung equals the CPU rung's to rounding, everything else to the
+bit: docs/fault_tolerance.md), so degradation trades throughput for
+availability, never correctness.
 
 The final rung is surfaced as ``fault.degradeLevel`` in
 ``Session.last_metrics`` next to the retry counters, and a DEGRADED

@@ -35,7 +35,7 @@ _SCAN_BLOCK = 1024
 
 
 def prefix_sum(x):
-    """Inclusive prefix sum of a 1-D array.
+    """Inclusive prefix sum along the last axis of an array.
 
     Two levels — a scan inside blocks of ``_SCAN_BLOCK`` rows, then the
     blocks' totals scanned the same way and added back — because the
@@ -44,12 +44,13 @@ def prefix_sum(x):
     join-expansion program holds one."""
     import jax.numpy as jnp
 
-    n = x.shape[0]
+    n = x.shape[-1]
     if n <= _SCAN_BLOCK or n % _SCAN_BLOCK:
-        return jnp.cumsum(x)
-    inner = jnp.cumsum(x.reshape(n // _SCAN_BLOCK, _SCAN_BLOCK), axis=1)
-    totals = inner[:, -1]
-    return (inner + (prefix_sum(totals) - totals)[:, None]).reshape(n)
+        return jnp.cumsum(x, axis=-1)
+    inner = jnp.cumsum(
+        x.reshape(x.shape[:-1] + (n // _SCAN_BLOCK, _SCAN_BLOCK)), axis=-1)
+    totals = inner[..., -1]
+    return (inner + (prefix_sum(totals) - totals)[..., None]).reshape(x.shape)
 
 
 def partition_order(first):

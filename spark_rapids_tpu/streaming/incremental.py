@@ -19,7 +19,7 @@ a fingerprint delta per exchange occurrence.  This module
    cumulative query then resumes that exchange from the merged
    checkpoint instead of rescanning history.
 
-Correctness of the merge (why append == recompute, bit for bit): merges
+Correctness of the merge (why append == recompute): merges
 are attempted only for HashPartitioning exchanges over per-row
 content-addressed partition ids, with nothing between scan and exchange
 except row-local operators (filter/project/expand/generate) and at most
@@ -30,8 +30,18 @@ order — which is exactly the order the cold cumulative execution
 produces, because discovery is sorted and the prefix is
 fingerprint-stable.  The FINAL aggregate above the exchange merges
 partials with order-insensitive buffers per group, so the cumulative
-query over the merged checkpoint is bit-identical to a cold full
-recompute.  Anything outside this shape (range/round-robin
+query over the merged checkpoint sees the frames a cold full recompute
+would, in its order.  One thing differs between them: the delta's
+partial aggregates are the HOST engine's, a cold recompute's the
+engine's that runs it.  Both give the same keys, counts, integer sums,
+minima, maxima and picks, to the bit.  A float64 SUM the host engine
+adds row by row; the device engine adds a group's sorted rows block by
+block (``ops/kernels/segment.segmented_scan``) and, on a TPU, in
+float32 pairs: the same sum in another order, equal to rounding (1e-12
+relative is what tests/test_streaming.py holds it to; 7e-16 is what it
+reads) and not to the bit.  ``sql.variableFloatAgg.enabled=false``
+keeps float aggregates on the host engine everywhere, and the identity
+is bit for bit again.  Anything outside this shape (range/round-robin
 partitioning, final/complete aggregates below the exchange, joins in
 the subtree) is skipped with a ``stream_incremental_skip`` event and
 recomputes from scratch — correct, just not incremental.
@@ -299,7 +309,7 @@ def _repartition_frames(base, schema, partitioning, new_n: int):
     called for partial-aggregate exchanges: there every group's rows —
     ≤1 per input file — live in exactly one old partition (hashed by
     group key) and stay in file order through the stable re-split, so
-    per-group merge order matches a cold recompute bit for bit."""
+    per-group merge order is a cold recompute's."""
     import numpy as np
 
     from ..native import serializer

@@ -10,11 +10,14 @@ cumulative plan through the PR-11 scheduler path with the stream's
 (``streaming.batchDeadlineMs``) attached.  Untouched exchanges resume
 from CRC-verified checkpoints; only affected partitions recompute.
 
-Every batch result is bit-identical to a cold full recompute of the
-same cumulative input — the stream never serves an "approximately
-right" answer, it only saves work.  The ledger commit after the result
-materializes is the exactly-once marker; a crash anywhere before it
-re-runs an idempotent tick.
+Every batch result equals a cold full recompute of the same cumulative
+input — the stream never serves a stale or partial answer, it only
+saves work.  Equal is to the bit for keys, counts, integer sums, minima,
+maxima and picks; a floating-point SUM or AVG computed on the device is
+equal to rounding (streaming/incremental.py says why; with
+``sql.variableFloatAgg.enabled=false`` it is to the bit as well).  The
+ledger commit after the result materializes is the exactly-once marker;
+a crash anywhere before it re-runs an idempotent tick.
 
 Triggers: ``trigger_ms > 0`` runs a daemon tick loop;
 ``trigger_ms == 0`` means manual ticks via :meth:`StreamHandle

@@ -5,6 +5,7 @@ Reference analogues: HashAggregatesSuite, hash_aggregate_test.py.
 import pytest
 
 from spark_rapids_tpu import f
+from spark_rapids_tpu.ops.kernels import gather as G
 from spark_rapids_tpu.testing import datagen as dg
 from spark_rapids_tpu.testing.asserts import (
     assert_tpu_and_cpu_are_equal_collect,
@@ -188,3 +189,72 @@ def test_functions_accept_column_names():
         lambda df: df.group_by("k").agg(f.sum("v").alias("s"),
                                         f.max("v").alias("m")),
         {"k": [1, 1, 2], "v": [10, 20, 30]}, ignore_order=True)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in eqn.params.values():
+            for j in sub if isinstance(sub, (list, tuple)) else [sub]:
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def test_q1_shaped_aggregate_scatters_nothing_row_wide():
+    """A partial aggregate of q1's shape (2 string keys, 11 buffers)
+    reduces its sorted rows by scans: no scatter takes an index a row
+    (the 2^22-bin scatter-adds it once was must not come back)."""
+    import jax
+
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.data.column import HostBatch, host_to_device
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+
+    n = 3000
+    data = dg.gen_batch({
+        "flag": dg.StringGen(max_len=1, nullable=False),
+        "status": dg.StringGen(max_len=1, nullable=False),
+        "qty": dg.FloatGen(dg.T.FLOAT64, no_nans=True),
+        "price": dg.FloatGen(dg.T.FLOAT64, no_nans=True),
+        "disc": dg.FloatGen(dg.T.FLOAT64, no_nans=True),
+        "tax": dg.FloatGen(dg.T.FLOAT64, no_nans=True),
+    }, n, 7)
+    sess = Session()
+    df = sess.create_dataframe(data, n_partitions=1)
+    disc_price = df["price"] * (f.lit(1.0) - df["disc"])
+    q = df.group_by("flag", "status").agg(
+        f.sum(df["qty"]).alias("sum_qty"),
+        f.sum(df["price"]).alias("sum_base_price"),
+        f.sum(disc_price).alias("sum_disc_price"),
+        f.sum(disc_price * (f.lit(1.0) + df["tax"])).alias("sum_charge"),
+        f.avg(df["qty"]).alias("avg_qty"),
+        f.avg(df["price"]).alias("avg_price"),
+        f.avg(df["disc"]).alias("avg_disc"),
+        f.count(df["qty"]).alias("count_order"))
+    todo, partial = [sess.physical_plan(q.plan)], None
+    while todo:
+        node = todo.pop()
+        if isinstance(node, TpuHashAggregateExec) and node.mode == "partial":
+            partial = node
+        todo.extend(node.children)
+    assert partial is not None and len(partial.keys) == 2
+    assert len(partial.buffer_schema.fields) == 2 + 11
+
+    batch = host_to_device(data if isinstance(data, HostBatch)
+                           else HostBatch.from_pydict(data))
+    padded = batch.padded_rows
+    assert padded == 4096
+    jaxpr = jax.make_jaxpr(partial.compute_batch)(batch)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    wide = [(e.primitive.name, e.invars[1].aval.shape) for e in eqns
+            if e.primitive.name.startswith("scatter")
+            and e.invars[1].aval.shape[:1] >= (padded // 2,)]
+    assert not wide, wide
+    assert any(e.primitive.name == "scan" for e in eqns)
+    # the counts: one prefix sum over a stack with a row a distinct
+    # validity (11 buffers, 8 inputs: an average's two share theirs)
+    stacked = [e.invars[0].aval.shape for e in eqns
+               if e.primitive.name == "cumsum" and e.invars[0].aval.ndim == 3]
+    assert stacked == [(8, padded // G._SCAN_BLOCK, G._SCAN_BLOCK)], stacked

@@ -321,6 +321,54 @@ def test_session_ladder_degrades_to_cpu_rung():
 
 
 @pytest.mark.fault_injection
+@pytest.mark.parametrize("device_float_sums", [True, False])
+def test_ladder_rungs_agree_past_a_scan_block(device_float_sums):
+    """5000 rows in three groups, so every group crosses blocks of the
+    device's segmented scan: the CPU rung's float sums (row by row) are
+    the device rung's (block by block) to rounding, 1e-12 relative;
+    counts, integer sums and keys to the bit.  With ``variableFloatAgg``
+    off the float sums never leave the host engine and the rungs are
+    identical."""
+    import math
+
+    from spark_rapids_tpu.ops.kernels.segment import _SCAN_BLOCK
+
+    rng = np.random.RandomState(11)
+    n = 5000
+    assert n // 3 > _SCAN_BLOCK
+    data = {"k": rng.randint(0, 3, n).tolist(),
+            "v": (rng.rand(n) * 1000).tolist(),
+            "i": rng.randint(-1000, 1000, n).tolist()}
+    conf = {"spark.rapids.tpu.sql.broadcastSizeThreshold": 0,
+            "spark.rapids.tpu.sql.taskRetries": 0,
+            "spark.rapids.tpu.sql.variableFloatAgg.enabled":
+                device_float_sums}
+
+    def query(sess):
+        df = sess.create_dataframe(data, n_partitions=1)
+        return sorted(df.group_by("k").agg(
+            F.sum("v").alias("s"), F.avg("v").alias("a"),
+            F.sum("i").alias("si"), F.count("v").alias("n"),
+            F.max("v").alias("m")).collect())
+
+    clean_sess = srt.Session(dict(conf))
+    clean = query(clean_sess)
+    assert clean_sess.last_metrics.get("fault.degradeLevel") == 0
+    sess = srt.Session(_inject("always", "stage_crash",
+                               site="exchange.write", **conf))
+    got = query(sess)
+    assert sess.last_metrics.get("fault.degradeLevel") == 2
+    rtol = 1e-12 if device_float_sums else 0.0
+    assert len(got) == len(clean) == 3
+    for g, c in zip(got, clean):
+        for (a, b), exact in zip(zip(g, c), [1, 0, 0, 1, 1, 1]):
+            if exact:
+                assert a == b, (g, c)
+            else:
+                assert math.isclose(a, b, rel_tol=rtol, abs_tol=0.0), (g, c)
+
+
+@pytest.mark.fault_injection
 def test_degrade_disabled_surfaces_the_fault():
     conf = _inject("always", "stage_crash", site="exchange.write", **{
         "spark.rapids.tpu.sql.broadcastSizeThreshold": 0,
