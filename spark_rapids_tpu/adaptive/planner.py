@@ -12,7 +12,7 @@ Every rewrite function emits its structured ``aqe_*`` decision event —
 the ``decision-event`` analysis rule enforces the pairing
 mechanically —
 and bumps an ``aqe.*`` int counter that rides ``Session.last_metrics``
-into bench.py and the Prometheus export.
+into the Prometheus export.
 
 Bit-identity argument per rewrite:
 

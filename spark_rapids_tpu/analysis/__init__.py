@@ -35,8 +35,7 @@ Run it::
 
 The engine is pure stdlib ``ast`` over the source tree — no jax, no
 imports of the analyzed modules — so it runs in well under the 10s
-budget and is the fast-fail first step of the tier-1 flow (ROADMAP.md)
-and the gate ``bench.py`` consults before writing perf artifacts.
+budget and is the fast-fail first step of the tier-1 flow (ROADMAP.md).
 """
 from .engine import AnalysisContext, Rule, all_rules, get_rule, run_rules
 from .findings import Finding, Severity

@@ -2,12 +2,10 @@
 
 A :class:`Project` roots at the repository directory (the parent of the
 ``spark_rapids_tpu`` package) and discovers every analyzable source
-file once: the whole package tree plus the top-level bench drivers
-(``bench.py``, ``bench_streaming.py``, ``bench_serving.py``) — the
-drift rules cross-check artifact schema constants there.  Parses are
-cached per file, so the N rules that walk overlapping scopes cost one
-``ast.parse`` per file, which is what keeps the full engine run well
-under its 10s budget.
+file once: the whole package tree.  Parses are cached per file, so
+the N rules that walk overlapping scopes cost one ``ast.parse`` per
+file, which is what keeps the full engine run well under its 10s
+budget.
 """
 from __future__ import annotations
 
@@ -16,9 +14,6 @@ import os
 from typing import Dict, Iterable, List, Optional
 
 PACKAGE = "spark_rapids_tpu"
-
-#: top-level driver scripts included in discovery (drift rules)
-TOP_LEVEL_FILES = ("bench.py", "bench_streaming.py", "bench_serving.py")
 
 
 def default_root() -> str:
@@ -54,9 +49,6 @@ class Project:
                     rel = os.path.relpath(os.path.join(dirpath, fn),
                                           self.root)
                     out.append(rel.replace(os.sep, "/"))
-        for fn in TOP_LEVEL_FILES:
-            if os.path.isfile(os.path.join(self.root, fn)):
-                out.append(fn)
         self._files = sorted(out)
         return self._files
 
@@ -97,8 +89,8 @@ class Project:
         if rel in self.parse_errors:
             return None
         if not os.path.isfile(self.path(rel)):
-            # rules may probe well-known paths (custodian modules,
-            # bench drivers) that a partial tree simply lacks
+            # rules may probe well-known paths (custodian modules)
+            # that a partial tree simply lacks
             return None
         try:
             tree = ast.parse(self.source(rel), filename=rel)

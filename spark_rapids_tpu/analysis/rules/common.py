@@ -158,14 +158,8 @@ def scoped(ctx, prefixes: Iterable[str] = (), files: Iterable[str] = (),
     """Package-prefixed scope selection."""
     return ctx.project.select(
         prefixes=[PKG + p for p in prefixes],
-        files=[_pkg(f) for f in files],
-        exclude=[_pkg(f) for f in exclude])
-
-
-def _pkg(f: str) -> str:
-    # top-level drivers (bench*.py) are addressed without the package
-    # prefix; everything else is package-relative
-    return f if f.startswith("bench") else PKG + f
+        files=[PKG + f for f in files],
+        exclude=[PKG + f for f in exclude])
 
 
 def func_loc(fi: FuncInfo) -> str:

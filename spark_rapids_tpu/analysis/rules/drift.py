@@ -1,11 +1,10 @@
-"""Drift rules: conf-drift, event-drift, schema-drift, decision-event.
+"""Drift rules: conf-drift, event-drift, decision-event.
 
 Drift is the failure mode of every registry that is documented (or
 mirrored) somewhere else: conf keys vs ``docs/configs.md``, emitted
-event names vs the telemetry catalog, artifact ``schema_version``
-constants vs the single source of truth in ``bench.py``, and the
-"every admission/preemption/AQE/streaming decision emits its event"
-contract the observability docs promise.
+event names vs the telemetry catalog, and the "every
+admission/preemption/AQE/streaming decision emits its event" contract
+the observability docs promise.
 """
 from __future__ import annotations
 
@@ -207,47 +206,6 @@ class EventDriftRule(Rule):
                     out.add(e.value)
             return out
         return None
-
-
-class SchemaDriftRule(Rule):
-    id = "schema-drift"
-    title = "bench artifact schema_version constants stay in lockstep"
-
-    FILES = ("bench.py", "bench_streaming.py", "bench_serving.py")
-
-    def run(self, ctx: AnalysisContext) -> Iterable[Finding]:
-        out: List[Finding] = []
-        versions: Dict[str, Optional[int]] = {}
-        for rel in self.FILES:
-            mi = ctx.resolver.module(rel)
-            if mi is None:
-                out.append(self.finding(
-                    "missing", rel, 0,
-                    f"{rel} missing or unparseable — cannot verify "
-                    f"artifact schema_version lockstep"))
-                continue
-            value = mi.module_assigns.get("SCHEMA_VERSION")
-            if isinstance(value, ast.Constant) and \
-                    isinstance(value.value, int):
-                versions[rel] = value.value
-            else:
-                versions[rel] = None
-                out.append(self.finding(
-                    "missing", rel, 0,
-                    f"{rel} does not define a literal module-level "
-                    f"SCHEMA_VERSION",
-                    detail=f"{rel}:SCHEMA_VERSION"))
-        truth = versions.get("bench.py")
-        if truth is not None:
-            for rel, v in versions.items():
-                if v is not None and v != truth:
-                    out.append(self.finding(
-                        "forked", rel, 0,
-                        f"{rel} SCHEMA_VERSION={v} != bench.py's "
-                        f"{truth} — the cross-schema compare refusal "
-                        f"would silently fork",
-                        detail=f"{rel}:{v}!={truth}"))
-        return out
 
 
 #: scheduler decision functions allowed to skip emission, with why

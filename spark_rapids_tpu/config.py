@@ -347,9 +347,9 @@ The `shuffle.*` confs (table above) configure the exchange data path
   `degrade` event, counted in `fault.numShuffleFallbacks`) before the
   CPU rung.
 * **Observability** — `shuffle.deviceBytes` / `shuffle.hostBytes` /
-  `shuffle.collectiveTime` land in `Session.last_metrics`; bench.py
-  reports device vs host `shuffle_write` GB/s and a `q3_exchange`
-  wall breakdown."""
+  `shuffle.collectiveTime` land in `Session.last_metrics`; on the
+  chip the exchange's seconds are `exchange_device_s` and
+  `shuffle_write_idle_s` of a traced benchmark run."""
 
 
 _SCHEDULING_DOC = """\
@@ -489,8 +489,9 @@ docs/perf_tuning.md):
   single-consumer.  Hit/miss/compile-wall counters land in
   `Session.last_metrics` under `kernelCache.*`, a per-exec
   `compileTime` metric attributes compile wall to operators in
-  EXPLAIN ANALYZE, and `bench.py` reports cold (compile-inclusive) vs
-  warm timings plus the per-query hit rate."""
+  EXPLAIN ANALYZE; the benchmark reports the cold side as
+  `first_query_s` / `setup_compile_s` and holds a warm window to
+  `compiles_in_window` 0."""
 
 
 _OBSERVABILITY_DOC = """\
@@ -524,8 +525,8 @@ docs/observability.md):
   every jitted-kernel dispatch to a stable kernel fingerprint
   (dispatches, wall, rows/bytes, padding waste) and renders a roofline
   table against the measured host->device ceiling in
-  `Session.profile_report()` and the BENCH `kernels` section; the
-  disabled cost is one attribute read per dispatch (docs/profiling.md).
+  `Session.profile_report()`; the disabled cost is one attribute read
+  per dispatch (docs/profiling.md).
 * **Trace timelines** — `telemetry.trace.dir` exports one
   Chrome-trace/Perfetto JSON per query (span tree as duration tracks,
   HBM watermark as a counter track, ring events as instants), written
@@ -1200,7 +1201,7 @@ TELEMETRY_PROFILER_ENABLED = conf(
     "Per-kernel dispatch profiler: accumulates dispatch count, wall "
     "time, rows/bytes and shape-bucketing padding waste per kernel "
     "fingerprint (telemetry/profiler.py), rendered as a roofline table "
-    "in Session.profile_report() and the BENCH JSON kernels section.  "
+    "in Session.profile_report().  "
     "Independent of telemetry.enabled; the disabled hot-path cost is "
     "one attribute read per dispatch").boolean_conf(False)
 TELEMETRY_TRACE_DIR = conf("spark.rapids.tpu.telemetry.trace.dir").doc(

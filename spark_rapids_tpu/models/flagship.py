@@ -8,7 +8,7 @@ as a chain of cudf kernel launches; here the whole chain traces into a
 single jitted program so XLA fuses the elementwise work into the sort +
 segment-reduce of the aggregate.
 
-Used by __graft_entry__.entry(), bench.py, and the pipeline test.
+Used by __graft_entry__.entry() and the pipeline test.
 """
 from __future__ import annotations
 

@@ -135,9 +135,6 @@ class TenantRegistry:
         self._conf = conf
         self._hist_window_s = max(1, conf.get(TELEMETRY_HISTOGRAM_WINDOW_S))
         self.tenants: Dict[str, TenantState] = {}
-        #: dispatch order, (tenant, query_id) — test/bench-visible
-        #: evidence of the fair-share interleave
-        self.dispatch_log: List = []
 
     # ----- tenant lookup ---------------------------------------------------
     def get_locked(self, name: str) -> TenantState:
@@ -240,7 +237,6 @@ class TenantRegistry:
         wait_ms = max(0.0, (now - handle._queued_at) * 1000.0)
         t.counters["dispatched"] += 1
         t.counters["queueWaitMsTotal"] += wait_ms
-        self.dispatch_log.append((handle.tenant, handle.query_id))
         return wait_ms
 
     def note_done_locked(self, handle, counter: Optional[str]) -> None:
@@ -265,7 +261,7 @@ class TenantRegistry:
         counts as submitted AND finished for the tenant (the caller got
         a FINISHED handle) but never dispatches, so its near-zero
         latency goes straight into the tenant histogram — the warm-path
-        p50 the serving bench asserts on is this population."""
+        p50 of a serving tier is this population."""
         t = self.get_locked(tenant)
         t.counters["submitted"] += 1
         t.counters["finished"] += 1
@@ -327,7 +323,7 @@ class OverloadMonitor:
     arrive).  Transitions emit ``overload_enter`` / ``overload_exit``
     events and are recorded in :attr:`history` (the monitor thread
     usually has no query-telemetry binding, so the history is the
-    test- and bench-visible record).  Hysteresis: overload exits only
+    test-visible record).  Hysteresis: overload exits only
     once every enabled signal drops below half its threshold."""
 
     def __init__(self, conf, queued_waits_ms: Callable[[], List[float]],
@@ -350,7 +346,7 @@ class OverloadMonitor:
         #: cumulative buckets feed the prometheus histogram exposition)
         self.wait_hist = LatencyHistogram(window_s=30.0)
         self._overloaded = False
-        #: enter/exit transition records (test/bench-visible)
+        #: enter/exit transition records (test-visible)
         self.history: List[Dict] = []
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None

@@ -883,7 +883,7 @@ class QueryScheduler:
         dispatched, finished, shed, preempted, queue waits, live
         depths, latency percentiles) plus the overload state and the
         queue-wait percentiles — the serving-tier observability
-        surface (bench_serving.py, docs/qos.md)."""
+        surface (docs/qos.md)."""
         with self._cv:
             out = self.qos.metrics_locked()
         out["scheduler.overloaded"] = \

@@ -40,8 +40,8 @@ def kernel_fingerprint(key, fn: Callable) -> str:
     ``<head>#<md5-6>`` where head is the operator kind from the cache
     key (or the function's qualname for anonymous kernels) and the
     suffix is a deterministic content hash of the full key — stable
-    across processes (unlike ``hash()``) so bench artifacts from
-    different runs can be diffed kernel-by-kernel.
+    across processes (unlike ``hash()``) so profiles from different
+    runs can be diffed kernel-by-kernel.
     """
     if key is None:
         head = fn.__qualname__.replace("<locals>.", "")
@@ -224,8 +224,8 @@ def roofline_rows(stats: Dict[str, KernelStat],
                   h2d_ceiling_bps: float = 0.0,
                   top_n: Optional[int] = None) -> List[dict]:
     """Derive the roofline table from a stats snapshot: one dict per
-    kernel, sorted by wall descending — the JSON form consumed by the
-    BENCH ``kernels`` section and ``bench.py --compare``."""
+    kernel, sorted by wall descending — the rows
+    ``Session.profile_report()`` renders."""
     rows = []
     for fp, st in sorted(stats.items(), key=lambda kv: -kv[1].wall_ns):
         wall_s = st.wall_ns / 1e9

@@ -1,5 +1,4 @@
-"""Atomic filesystem helpers shared by checkpointing, spill and the
-bench artifacts.
+"""Atomic filesystem helpers shared by checkpointing and spill.
 
 The one durable-write idiom of this engine: serialize into a temp file
 in the SAME directory as the target, flush + fsync, then ``os.replace``
@@ -67,9 +66,7 @@ def sweep_tmp_files(directory: str) -> int:
     try:
         for root, _dirs, files in os.walk(directory):
             for name in files:
-                if name.startswith(TMP_PREFIX) or (
-                        name.startswith(".bench-")
-                        and name.endswith(".tmp")):
+                if name.startswith(TMP_PREFIX):
                     try:
                         os.unlink(os.path.join(root, name))
                         removed += 1

@@ -12,10 +12,14 @@ Three layers:
    is clean, not that a rule went inert.
 3. **Baseline add/expire semantics and CLI exit codes** (the latter
    via subprocess, the supported entry point).
+
+Beside them, a check the engine has no part in: the documents a reader
+starts from cite only files the tree holds.
 """
 import json
 import glob
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -98,7 +102,7 @@ OLD_LINT_INVENTORY = {
 
 #: rules with no retired-lint ancestor (net-new whole-program checks)
 NEW_RULE_IDS = {"lock-order", "race-global", "resource-pair",
-                "conf-drift", "schema-drift"}
+                "conf-drift"}
 
 
 def test_rule_registry_covers_retired_lint_inventory():
@@ -436,27 +440,6 @@ def test_overloaded_hint_requires_retry_after_ms(tmp_path):
     assert not hits
 
 
-def test_schema_drift_flags_forked_version(tmp_path):
-    hits = _findings(tmp_path, {
-        "spark_rapids_tpu/__init__.py": "",
-        "bench.py": "SCHEMA_VERSION = 2\n",
-        "bench_streaming.py": "SCHEMA_VERSION = 3\n",
-        "bench_serving.py": "SCHEMA_VERSION = 2\n",
-    }, "schema-drift", "forked")
-    assert len(hits) == 1
-    assert hits[0].file == "bench_streaming.py"
-
-
-def test_schema_drift_quiet_in_lockstep(tmp_path):
-    hits = _findings(tmp_path, {
-        "spark_rapids_tpu/__init__.py": "",
-        "bench.py": "SCHEMA_VERSION = 2\n",
-        "bench_streaming.py": "SCHEMA_VERSION = 2\n",
-        "bench_serving.py": "SCHEMA_VERSION = 2\n",
-    }, "schema-drift", "forked", "missing")
-    assert not hits
-
-
 def test_parse_error_surfaces_as_engine_finding(tmp_path):
     ctx = _mini(tmp_path, {
         "spark_rapids_tpu/exec/broken.py": "def oops(:\n",
@@ -543,58 +526,79 @@ def _cli(tmp_path, *argv):
         timeout=120)
 
 
-def _write_bench_tree(tmp_path, streaming_version):
-    (tmp_path / "spark_rapids_tpu").mkdir(parents=True, exist_ok=True)
-    (tmp_path / "spark_rapids_tpu" / "__init__.py").write_text("")
-    (tmp_path / "bench.py").write_text("SCHEMA_VERSION = 2\n")
-    (tmp_path / "bench_streaming.py").write_text(
-        f"SCHEMA_VERSION = {streaming_version}\n")
-    (tmp_path / "bench_serving.py").write_text("SCHEMA_VERSION = 2\n")
+def _write_conf_tree(tmp_path, documented):
+    """A mini-project the conf-drift rule reads whole: ten registered
+    keys (its health floor), the first ``documented`` of them listed in
+    docs/configs.md."""
+    pkg = tmp_path / "spark_rapids_tpu"
+    pkg.mkdir(parents=True, exist_ok=True)
+    (pkg / "__init__.py").write_text("")
+    keys = [f"spark.rapids.tpu.demo.k{i}" for i in range(10)]
+    (pkg / "config.py").write_text(
+        "".join(f'K{i} = conf("{k}")\n' for i, k in enumerate(keys)))
+    (tmp_path / "docs").mkdir(exist_ok=True)
+    (tmp_path / "docs" / "configs.md").write_text(
+        "".join(f"| `{k}` | demo |\n" for k in keys[:documented]))
 
 
 def test_cli_exit_codes_clean_dirty_and_baselined(tmp_path):
     baseline = str(tmp_path / "bl.json")
 
     # clean tree -> 0
-    _write_bench_tree(tmp_path, streaming_version=2)
-    r = _cli(tmp_path, "--rule", "schema-drift", "--no-baseline")
+    _write_conf_tree(tmp_path, documented=10)
+    r = _cli(tmp_path, "--rule", "conf-drift", "--no-baseline")
     assert r.returncode == 0, r.stdout + r.stderr
     assert "0 new finding(s)" in r.stdout
 
-    # forked schema -> 1, finding rendered
-    _write_bench_tree(tmp_path, streaming_version=3)
-    r = _cli(tmp_path, "--rule", "schema-drift", "--no-baseline")
+    # a key the docs lack -> 1, finding rendered
+    _write_conf_tree(tmp_path, documented=9)
+    r = _cli(tmp_path, "--rule", "conf-drift", "--no-baseline")
     assert r.returncode == 1, r.stdout + r.stderr
-    assert "[schema-drift/forked]" in r.stdout
+    assert "[conf-drift/undocumented-key]" in r.stdout
 
     # --update-baseline writes the suppression and exits 0...
-    r = _cli(tmp_path, "--rule", "schema-drift",
+    r = _cli(tmp_path, "--rule", "conf-drift",
              "--baseline", baseline, "--update-baseline")
     assert r.returncode == 0, r.stdout + r.stderr
     # ...after which the same finding is baselined -> 0
-    r = _cli(tmp_path, "--rule", "schema-drift",
+    r = _cli(tmp_path, "--rule", "conf-drift",
              "--baseline", baseline)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "1 baselined" in r.stdout
 
 
-def test_bench_refuses_artifacts_on_new_findings(tmp_path, monkeypatch):
-    sys.path.insert(0, REPO_ROOT)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO_ROOT)
-    p = tmp_path / "BENCH_LAST.json"
-    monkeypatch.setattr(bench, "_ANALYSIS_GATE", False)
-    bench._persist_tpu_artifact({"suite": "x"}, path=str(p))
-    assert not p.exists(), "artifact written despite failed gate"
-    monkeypatch.setattr(bench, "_ANALYSIS_GATE", True)
-    bench._persist_tpu_artifact({"suite": "x"}, path=str(p))
-    assert p.exists()
-
-
 def test_cli_unknown_rule_is_usage_error(tmp_path):
-    _write_bench_tree(tmp_path, streaming_version=2)
+    _write_conf_tree(tmp_path, documented=10)
     r = _cli(tmp_path, "--rule", "no-such-rule")
     assert r.returncode == 2
     assert "unknown rule" in r.stderr
+
+
+# ==========================================================================
+# 4. The documents a reader starts from name only files that exist
+# ==========================================================================
+#: README.md and the guides under docs/.  The histories (CHANGES.md,
+#: PERF.md, ROADMAP.md, SURVEY.md) are left out: they name files of
+#: their day.
+DOCUMENTS = ["README.md"] + sorted(
+    os.path.relpath(p, REPO_ROOT).replace(os.sep, "/")
+    for p in glob.glob(os.path.join(REPO_ROOT, "docs", "*.md")))
+
+#: a back-ticked path ending in .py, .md, .json or / (a ``:line`` or
+#: ``:function`` after it is allowed); patterns and placeholders
+#: (``*``, ``<...>``) are not paths and do not match
+_CITED_PATH_RE = re.compile(
+    r"`([\w./-]+(?:\.py|\.md|\.json|/))(?::[\w.,:-]+)?`")
+
+
+@pytest.mark.parametrize("doc", DOCUMENTS)
+def test_documents_name_only_files_that_exist(doc):
+    """A cited path resolves from the repo root, from the package (the
+    guides write ``exec/joins.py`` for ``spark_rapids_tpu/exec/joins.py``)
+    or from the document's own directory."""
+    with open(os.path.join(REPO_ROOT, doc), encoding="utf-8") as f:
+        cited = sorted(set(_CITED_PATH_RE.findall(f.read())))
+    bases = ("", "spark_rapids_tpu", os.path.dirname(doc))
+    missing = [p for p in cited if not any(
+        os.path.exists(os.path.join(REPO_ROOT, b, p)) for b in bases)]
+    assert not missing, f"{doc} cites files the tree lacks: {missing}"

@@ -247,6 +247,13 @@ def test_distributed_stages_run_at_the_trimmed_width(caplog):
                 if "answered in" in r.message]
     assert len(answered) == len(widths) and \
         not any("overflowed" in a for a in answered), answered
+    # ... in turn, each after its own announcement: a request that is
+    # cut short has said which program it was in
+    said = [r.message for r in caplog.records
+            if "dispatching" in r.message or "answered in" in r.message]
+    assert said[0].startswith("stage[0]") and all(
+        a.split(":")[0] == d.split(":")[0] and "dispatching" in d
+        and "answered in" in a for d, a in zip(said[::2], said[1::2]))
 
 
 def test_distributed_broadcast_build_reused_across_retries():

@@ -635,34 +635,3 @@ def test_every_conf_key_documented_in_configs_md():
     assert not missing, \
         f"conf keys missing from docs/configs.md: {missing} — " \
         "regenerate with config.dump_markdown()"
-
-
-# ==========================================================================
-# bench.py satellite: atomic artifact persistence
-# ==========================================================================
-def test_bench_artifact_written_atomically(tmp_path):
-    import importlib.util
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(repo, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    target = str(tmp_path / "BENCH_LAST.json")
-    bench._persist_tpu_artifact({"metric": "x", "value": 1.0},
-                                path=target)
-    first = json.load(open(target))
-    assert first["value"] == 1.0 and "captured_at" in first
-    # overwrite leaves a complete new file and no temp litter
-    bench._persist_tpu_artifact({"metric": "x", "value": 2.0},
-                                path=target)
-    assert json.load(open(target))["value"] == 2.0
-    assert [f for f in os.listdir(tmp_path)
-            if f.endswith(".tmp")] == []
-    # a failed serialization keeps the previous artifact intact
-    with pytest.raises(TypeError):
-        bench._atomic_write_json(target, {"bad": object()})
-    assert json.load(open(target))["value"] == 2.0
-    assert [f for f in os.listdir(tmp_path)
-            if f.endswith(".tmp")] == []

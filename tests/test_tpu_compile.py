@@ -11,14 +11,11 @@ Everything that touches the topology lives in module-scoped fixtures that
 are not autouse: only the xdist worker that is given this file loads the
 TPU library, and a worker that cannot describe the chip skips.
 """
-import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
-from conftest import REPO, cpu_worker_env
+from conftest import REPO
 
 import spark_rapids_tpu  # noqa: F401  (x64 on, pytrees registered)
 from spark_rapids_tpu import types as T
@@ -319,7 +316,7 @@ def test_float64_pair_words_order_like_the_oracle(as_tpu):
 
 
 # --------------------------------------------------------------------------
-# the compile cache's one rule, and the smoke's rehearsal
+# the compile cache's one rule
 # --------------------------------------------------------------------------
 def test_compile_cache_dir_rule(monkeypatch, tmp_path):
     from spark_rapids_tpu.utils import compile_cache
@@ -328,62 +325,3 @@ def test_compile_cache_dir_rule(monkeypatch, tmp_path):
     assert compile_cache.cache_dir() == str(tmp_path)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     assert compile_cache.cache_dir() == os.path.join(REPO, ".jax_cache")
-
-
-def _smoke(*args):
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO, "chip_smoke.py"), *args],
-        capture_output=True, text=True, env=cpu_worker_env(), cwd=REPO,
-        timeout=540)
-
-
-def test_chip_smoke_refuses_to_run_without_a_chip():
-    r = _smoke()
-    assert r.returncode != 0
-    assert "'cpu'" in r.stderr and "needs a TPU" in r.stderr
-    assert '"ok"' not in r.stdout
-
-
-def _rehearse(*args):
-    """Run a rehearsal; returns its phase notes (progress lines left
-    out) and its last line, which must never claim a chip."""
-    r = _smoke("--rehearse", *args)
-    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
-    assert '"ok": true' not in r.stdout
-    lines = [json.loads(ln) for ln in r.stdout.splitlines()
-             if ln.startswith("{")]
-    last = lines.pop()
-    assert last["device"]["platform"] == "cpu" and last["ok"] is False
-    notes = [ln for ln in lines if ln["phase"] != "compile"]
-    assert all(ln["equals_oracle"] for ln in notes
-               if ln["phase"].startswith("q"))
-    return notes, last
-
-
-def test_chip_smoke_rehearsal_walks_every_phase():
-    notes, last = _rehearse()
-    assert [ln["phase"] for ln in notes] == [
-        "start", "data", "oracle", "session", "q6", "q1", "q3", "q16",
-        "submit", "prepared", "compile_cache"]
-    assert all(ln["warm"]["kernel_programs_compiled"] == 0
-               for ln in notes if ln["phase"].startswith("q"))
-    assert last["device"]["count"] == 8
-
-
-def test_chip_smoke_mesh_rehearsal_says_where_it_is():
-    """``--chips 4``: only the mesh path, over four of the virtual
-    devices; every stage program is announced before it is dispatched
-    and after it answers, so a cut run has said where it was."""
-    notes, last = _rehearse("--chips", "4")
-    assert last["device"]["count"] == 4
-    said = [ln["said"] for ln in notes if ln["phase"] == "mesh.program"]
-    assert [ln["phase"] for ln in notes
-            if ln["phase"] != "mesh.program"] == [
-        "start", "data", "q3.mesh", "q5.mesh"]
-    assert said[0].startswith("stage[0] attempt 0: dispatching") and \
-        "answered in" in said[1]
-    assert sum("dispatching" in s for s in said) == \
-        sum("answered in" in s for s in said) >= 8
-    for ln in notes:
-        if ln["phase"].endswith(".mesh"):
-            assert ln["shard_devices"] == 4
