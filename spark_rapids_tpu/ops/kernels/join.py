@@ -6,14 +6,16 @@ sort-based (SURVEY §7 "Hard parts": hash join on TPU → sort + merge;
 the reference replaces SortMergeJoin with hash join, here the
 replacement is reversed).  Three stages, all static shapes:
 
-  1. group ids: concat both sides' key columns, one lexsort, segment
-     ids at key-change boundaries → per-row int32 ids where equal keys
-     (with Spark null/NaN/-0.0 semantics) share an id across sides.
-  2. probe: the same sort, split by side, is the right rows in id
-     order; per left row, searchsorted gives the contiguous run
-     [lo, lo+cnt) of its matches.  Match counts are
-     exact before any expansion — the same "size before materialize"
-     contract cudf's join APIs give the reference.
+  1. merge: concat both sides' key columns (left first), one stable
+     lexsort.  Equal keys (with Spark null/NaN/-0.0 semantics) now lie
+     together, a key's left rows before its right rows; a key changes
+     where the words the sort compared change.
+  2. probe: per left row the contiguous run [lo, lo+cnt) of its
+     matches among the right rows in key order, read off that order by
+     scans (a prefix sum of the side flag, segmented sums from the
+     front and from the back) — no search, no second lookup.  Match
+     counts are exact before any expansion — the same "size before
+     materialize" contract cudf's join APIs give the reference.
   3. expand: with an output capacity chosen from the exact count, a
      searchsorted over the emit-prefix-sum turns slot t into its
      (left row, k-th match) pair; gathers materialize the output.
@@ -52,65 +54,81 @@ def _concat_key_cols(lc: DeviceColumn, rc: DeviceColumn) -> DeviceColumn:
     return DeviceColumn(lc.dtype, data, validity, lengths)
 
 
-#: id of a row that never joins (null key / padding) in the id-sorted
-#: views — above every real id, so the views stay ascending
-_NEVER = 2 ** 31 - 1
-
-
 class Probe(NamedTuple):
-    gl: object       # int32[Nl] left group ids (-1 = never matches)
-    gr: object       # int32[Nr]
-    order_r: object  # int32[Nr] right rows sorted by group id
-    lo: object       # int32[Nl] first match position in order_r
-    cnt: object      # int32[Nl] number of right matches per left row
+    """What one sort of both sides' keys says about every row, in the
+    rows' own order (not the sorted one)."""
+    order_r: object  # int32[Nr] right rows in key order, never-joining last
+    lo: object       # int32[Nl] a left row's first match, a place in order_r
+    cnt: object      # int32[Nl] its number of right matches (0: none)
     has_r: object    # bool[Nr] right row has a left match
 
 
 def probe(l_keys: List[DeviceColumn], r_keys: List[DeviceColumn],
           l_ok, r_ok) -> Probe:
-    """Group ids and match runs from ONE sort of both sides' keys.
+    """Every left row's run of matches, read off ONE sort of both sides'
+    keys.
 
-    Rows (on either side) with equal, fully-non-null keys share a group
-    id; left rows with null keys/padding get -1, right ones -2 —
-    sentinels that never match anything.  The combined sort already
-    holds each side in id order, so the right rows sorted by id (and
-    the sorted ids of each side, for the run searches) are a stable
-    split of it by side — a prefix sum and a scatter — not two more
-    sorts (each sort in a program costs the TPU compiler ~30 s)."""
+    The sort is stable and the left side is concatenated first, so in
+    sorted order the rows of one key lie together, its left rows before
+    its right rows, and the rows that never join (null key, padding:
+    ``ok`` False) after every key, each a segment of its own.  A left
+    row's matches are then the right rows from it to its segment's end
+    (``cnt``: a segmented sum run from the back), the first of them as
+    far into the right rows' order as there are right rows before it
+    (``lo``: a prefix sum), and a right row is matched when a left row
+    stands before it in its segment (``has_r``: a segmented sum run
+    from the front).  Whole numbers, so exact.  Nothing is searched for
+    and nothing scatters: the key changes come from one stacked gather
+    of the words the sort compared, the answers go back to row order in
+    one sort by ``order`` that carries them, and ``order_r`` is
+    ``order`` with its right rows sorted to the front.  (2^22 + 2^17
+    rows on a v5e: 147 ms where the four searches alone took 1125; the
+    way back 13 ms by that sort, 33 by the inverse permutation's gather,
+    342 as a stacked scatter; ``order_r`` 7 ms, 31 by
+    ``partition_order``; the words 27 ms stacked, 162 a gather a word;
+    PERF.md, PR 31.)"""
     import jax.numpy as jnp
+    from jax import lax
 
     nl, nr = l_ok.shape[0], r_ok.shape[0]
+    n = nl + nr
     combined = [_concat_key_cols(a, b) for a, b in zip(l_keys, r_keys)]
     ok = jnp.concatenate([l_ok, r_ok])
     # null keys never join: fold key validity into row eligibility
     for c in combined:
         ok = ok & c.validity
-    order = seg.lexsort_device(combined, pad_valid=ok)
-    ok_s = ok[order]
-    sorted_cols = [DeviceColumn(c.dtype, c.data[order],
-                                c.validity[order] & ok_s,
-                                c.lengths[order]
-                                if c.lengths is not None else None)
-                   for c in combined]
-    ids_s = seg.segment_ids_device(sorted_cols, pad_valid=ok_s)
-    ids = jnp.zeros((nl + nr,), dtype=jnp.int32).at[order].set(ids_s)
-    gl = jnp.where(ok[:nl], ids[:nl], -1)
-    gr = jnp.where(ok[nl:], ids[nl:], -2)
+    words = seg.key_passes_device(combined, pad_valid=ok)
+    order = seg.sort_permutation(words, n)
 
-    # sorted positions of the right rows, then of the left rows, each
-    # still in id order (never-joining rows last)
-    by_side = partition_order(order >= nl)
-    pos_r, pos_l = by_side[:nr], by_side[nr:]
-    ids_s = jnp.where(ok_s, ids_s, _NEVER)
-    order_r = order[pos_r] - nl
-    sorted_gr, sorted_gl = ids_s[pos_r], ids_s[pos_l]
+    # equal keys have equal words (-0.0 and 0.0, NaN and NaN share
+    # theirs); a string's length rides along, as its bytes are padded
+    rows = words + [c.lengths.astype(jnp.uint32) for c in combined
+                    if c.lengths is not None]
+    keys_s = jnp.stack(rows)[:, order]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    ok_s = pos < ok.sum(dtype=jnp.int32)
+    change = ~ok_s | jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_),
+         (keys_s[:, 1:] != keys_s[:, :-1]).any(axis=0)])
+    seg_end = jnp.concatenate([change[1:], jnp.ones((1,), jnp.bool_)])
 
-    lo = jnp.searchsorted(sorted_gr, gl, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(sorted_gr, gl, side="right").astype(jnp.int32)
-    rlo = jnp.searchsorted(sorted_gl, gr, side="left")
-    rhi = jnp.searchsorted(sorted_gl, gr, side="right")
-    has_r = (rhi > rlo) & (gr >= 0)
-    return Probe(gl, gr, order_r, lo, hi - lo, has_r)
+    is_right = order >= nl
+    rights_before = prefix_sum(is_right.astype(jnp.int32))
+    lefts_before = seg.segmented_scan(
+        (~is_right).astype(jnp.int32)[None], change, jnp.add)[0]
+    rights_after = jnp.flip(seg.segmented_scan(
+        jnp.flip(is_right).astype(jnp.int32)[None], jnp.flip(seg_end),
+        jnp.add)[0])
+
+    # back to row order: a sort by a permutation is its inverse's gather
+    mine = jnp.where(is_right, lefts_before,
+                     jnp.where(ok_s, rights_before, 0))
+    _, mine, rights_after = lax.sort((order, mine, rights_after),
+                                     num_keys=1, is_stable=False)
+    _, right_first = lax.sort((jnp.where(is_right, pos, n + pos), order),
+                              num_keys=1, is_stable=False)
+    return Probe(right_first[:nr] - nl, mine[:nl], rights_after[:nl],
+                 mine[nl:] > 0)
 
 
 def emit_counts(p: Probe, how: str, l_rm, r_rm):
@@ -140,7 +158,7 @@ def expand_pairs(p: Probe, emit, r_extra, c_out: int):
     import jax.numpy as jnp
 
     nl = emit.shape[0]
-    nr = p.gr.shape[0]
+    nr = p.order_r.shape[0]
     offs = prefix_sum(emit)                      # inclusive
     m_left = offs[-1]
     t = jnp.arange(c_out, dtype=jnp.int64)

@@ -185,3 +185,14 @@ def strict_tpu_session():
     from spark_rapids_tpu import Session
 
     return Session({"spark.rapids.tpu.sql.test.enabled": True})
+
+
+def jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr, its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in eqn.params.values():
+            for j in sub if isinstance(sub, (list, tuple)) else [sub]:
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    yield from jaxpr_eqns(inner)

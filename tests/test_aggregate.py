@@ -3,6 +3,7 @@
 Reference analogues: HashAggregatesSuite, hash_aggregate_test.py.
 """
 import pytest
+from conftest import jaxpr_eqns
 
 from spark_rapids_tpu import f
 from spark_rapids_tpu.ops.kernels import gather as G
@@ -191,17 +192,6 @@ def test_functions_accept_column_names():
         {"k": [1, 1, 2], "v": [10, 20, 30]}, ignore_order=True)
 
 
-def _eqns(jaxpr):
-    """Every equation of a jaxpr, its sub-jaxprs included."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in eqn.params.values():
-            for j in sub if isinstance(sub, (list, tuple)) else [sub]:
-                inner = getattr(j, "jaxpr", j)
-                if hasattr(inner, "eqns"):
-                    yield from _eqns(inner)
-
-
 def test_q1_shaped_aggregate_scatters_nothing_row_wide():
     """A partial aggregate of q1's shape (2 string keys, 11 buffers)
     reduces its sorted rows by scans: no scatter takes an index a row
@@ -247,7 +237,7 @@ def test_q1_shaped_aggregate_scatters_nothing_row_wide():
     padded = batch.padded_rows
     assert padded == 4096
     jaxpr = jax.make_jaxpr(partial.compute_batch)(batch)
-    eqns = list(_eqns(jaxpr.jaxpr))
+    eqns = list(jaxpr_eqns(jaxpr.jaxpr))
     wide = [(e.primitive.name, e.invars[1].aval.shape) for e in eqns
             if e.primitive.name.startswith("scatter")
             and e.invars[1].aval.shape[:1] >= (padded // 2,)]

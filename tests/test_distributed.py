@@ -131,6 +131,34 @@ def test_distributed_runner_join_modes(threshold):
     _assert_rows_equal(got, exp)
 
 
+def test_distributed_join_runs_cross_a_scan_block():
+    """Shuffled joins on four devices whose key runs are longer than a
+    block of the probe's scans (1024 rows): a shard's ``join_static``
+    counts a run block by block, and answers as one device does."""
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.ops.kernels.gather import _SCAN_BLOCK
+    from spark_rapids_tpu.parallel.runner import run_distributed
+
+    rng = np.random.RandomState(31)
+    lk = rng.randint(0, 40, 9000).astype(np.int64)
+    lk[rng.rand(9000) < 0.4] = 17           # one run of ~3600 left rows
+    rk = np.concatenate([np.arange(40), [17, 17, 3]]).astype(np.int64)
+    assert (lk == 17).sum() > 3 * _SCAN_BLOCK
+
+    def q(sess, how):
+        l = sess.create_dataframe({"k": lk, "v": np.arange(9000)})
+        r = sess.create_dataframe({"rk": rk, "w": np.arange(len(rk))})
+        return l.join(r, on=(["k"], ["rk"]), how=how)
+
+    conf = {"spark.rapids.tpu.sql.broadcastSizeThreshold": 0}
+    for how in ("inner", "full"):
+        sess = Session(dict(conf))
+        got = run_distributed(sess, q(sess, how), mesh=_mesh(4)).to_rows()
+        one = q(Session(dict(conf)), how).collect()
+        assert len(one) > 9000 + 2 * 3 * _SCAN_BLOCK
+        _assert_rows_equal(got, one)
+
+
 def test_distributed_global_sort_order_preserved():
     """Global sort above a join+agg must come back in sorted order even
     though the range exchange below it executes as a host leaf (the

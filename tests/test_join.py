@@ -2,6 +2,7 @@
 HashAggregatesSuite-style dual-session equality)."""
 import numpy as np
 import pytest
+from conftest import jaxpr_eqns
 
 import spark_rapids_tpu as srt
 from spark_rapids_tpu import f
@@ -168,3 +169,199 @@ def test_broadcast_artifact_reused_across_collects():
     gc.collect()
     reg._purge_dead()
     assert len(reg) == 0
+
+
+# --------------------------------------------------------------------------
+# the probe at the kernel, against a numpy oracle, to the bit
+# --------------------------------------------------------------------------
+def _codes(l_cols, r_cols, l_ok, r_ok):
+    """Dense int64 rank of every row's key under Spark's order and
+    equality (NaN equals NaN and is greatest, -0.0 equals 0.0), over
+    both sides; -1 for a row that never joins (null key, padding)."""
+    nl = len(l_ok)
+    code = np.zeros(nl + len(r_ok), dtype=np.int64)
+    ok = np.concatenate([l_ok, r_ok])
+    for (lv, lvalid), (rv, rvalid) in zip(l_cols, r_cols):
+        vals = np.concatenate([lv, rv])
+        if vals.dtype.kind == "f":
+            vals = np.where(vals == 0.0, 0.0, vals)
+        ok = ok & np.concatenate([lvalid, rvalid])
+        uniq, inv = np.unique(vals, return_inverse=True)
+        code = code * (len(uniq) + 1) + inv.reshape(-1)
+    code = np.where(ok, np.unique(code, return_inverse=True)[1].reshape(-1),
+                    -1)
+    return code[:nl], code[nl:]
+
+
+def _probe_oracle(cl, cr):
+    """(order_r's joining prefix, lo, cnt, has_r) from the key codes."""
+    by_key = np.argsort(np.where(cr >= 0, cr, np.iinfo(np.int64).max),
+                        kind="stable")
+    n_join = int((cr >= 0).sum())
+    keys_r = cr[by_key[:n_join]]
+    lo = np.searchsorted(keys_r, cl, side="left")
+    hi = np.searchsorted(keys_r, cl, side="right")
+    live = cl >= 0
+    lo, cnt = np.where(live, lo, 0), np.where(live, hi - lo, 0)
+    has_r = (cr >= 0) & np.isin(cr, cl[live])
+    return by_key[:n_join], lo, cnt, has_r
+
+
+def _device_key(values, valid):
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.data import strings
+    from spark_rapids_tpu.data.column import DeviceColumn
+
+    if values.dtype.kind == "U":
+        data, lengths = strings.encode(values.astype(object), None)
+        return DeviceColumn(T.STRING, jnp.asarray(data), jnp.asarray(valid),
+                            jnp.asarray(lengths))
+    return DeviceColumn(T.from_numpy(values.dtype), jnp.asarray(values),
+                        jnp.asarray(valid))
+
+
+def _ints(lo, hi):
+    return lambda rng, n, side: rng.integers(lo, hi, n).astype(np.int64)
+
+
+def _long_run(at, length, n):
+    """Keys rising a row, but for one key that ``length`` rows share
+    from sorted position ``at``: a run across the scans' block edges."""
+    def make(rng, m, side):
+        keys = rng.permutation(n)[:m].astype(np.int64) * 2 + 1
+        # a share of each side joins the run; the run's key sorts where
+        # `at` rows lie below it
+        keys[rng.random(m) < length / n] = 2 * at
+        return keys
+    return make
+
+
+def _floats(rng, n, side):
+    return rng.choice(np.array([0.0, -0.0, np.nan, 1.5, -2.25, np.inf,
+                                -np.inf, 3e300]), n)
+
+
+def _strings(rng, n, side):
+    return rng.choice(np.array(["", "a", "ab", "abc", "b", "ba",
+                                "lineitem", "line"]), n)
+
+
+# (left rows, right rows, a key maker (rng, rows, side) a column, share of
+#  null keys, padding rows on each side)
+PROBE_CASES = {
+    "unique_build_keys": (1500, 700, [lambda rng, n, side: rng.permutation(
+        4000)[:n].astype(np.int64)], 0.0, 0),
+    "duplicate_heavy_both_sides": (900, 600, [_ints(0, 8)], 0.0, 0),
+    "one_key_for_every_row": (700, 500, [_ints(7, 8)], 0.0, 0),
+    "disjoint_sides": (600, 600, [lambda rng, n, side: (
+        rng.integers(0, 500, n) * 2 + side).astype(np.int64)], 0.0, 0),
+    "null_keys_and_padding": (1000, 800, [_ints(0, 300)], 0.2, 150),
+    "run_crosses_a_scan_block": (2600, 1900, [_long_run(700, 1800, 4500)],
+                                 0.05, 40),
+    "run_crosses_the_second_level": (
+        1_150_000, 50_000, [_long_run(1_058_000, 20_000, 1_200_000)],
+        0.0, 1000),
+    "rows_not_a_multiple_of_1024": (1531, 1100, [_ints(0, 900)], 0.1, 77),
+    "int64_wide": (800, 800, [lambda rng, n, side: rng.choice(np.array(
+        [-2 ** 63, -2 ** 32 - 1, -1, 0, 1, 2 ** 32, 2 ** 32 + 1,
+         2 ** 63 - 1], dtype=np.int64), n)], 0.1, 30),
+    "float64_nan_and_signed_zero": (900, 700, [_floats], 0.1, 30),
+    "string": (900, 700, [_strings], 0.1, 30),
+    "two_columns": (1200, 900, [_ints(0, 6), _strings], 0.1, 30),
+}
+
+
+@pytest.mark.parametrize("case", list(PROBE_CASES))
+def test_probe_equals_the_numpy_oracle_to_the_bit(case):
+    """``J.probe``'s four arrays against what numpy says of the same
+    keys: for each left row the run ``order_r[lo:lo+cnt]`` is exactly
+    its matches in key order, rows that never join count nothing and
+    stand last in ``order_r``."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.ops.kernels import join as J
+
+    nl, nr, makers, null_share, padding = PROBE_CASES[case]
+    rng = np.random.default_rng(len(case))
+    sides = []
+    for side, n in enumerate((nl, nr)):
+        cols = [(make(rng, n, side), rng.random(n) >= null_share)
+                for make in makers]
+        sides.append((cols, np.arange(n) < n - padding))
+    (l_cols, l_ok), (r_cols, r_ok) = sides
+    cl, cr = _codes(l_cols, r_cols, l_ok, r_ok)
+    if case == "disjoint_sides":
+        assert not np.isin(cl, cr).any()
+    if case.startswith("run_crosses"):
+        # the longest run of one key spans the edge it is named for
+        edge = 1024 if case.endswith("block") else 1024 * 1024
+        both = np.sort(np.concatenate([cl[cl >= 0], cr[cr >= 0]]))
+        top = np.bincount(both).argmax()
+        first, last = np.searchsorted(both, [top, top + 1])
+        assert first // edge < (last - 1) // edge, (first, last)
+
+    p = J.probe([_device_key(*c) for c in l_cols],
+                [_device_key(*c) for c in r_cols],
+                jnp.asarray(l_ok), jnp.asarray(r_ok))
+    order_r, lo, cnt, has_r = (np.asarray(x) for x in p)
+    assert (order_r.dtype, lo.dtype, cnt.dtype, has_r.dtype) == (
+        np.int32, np.int32, np.int32, np.bool_)
+    joining, want_lo, want_cnt, want_has = _probe_oracle(cl, cr)
+    np.testing.assert_array_equal(order_r[:len(joining)], joining)
+    np.testing.assert_array_equal(np.sort(order_r), np.arange(nr))
+    np.testing.assert_array_equal(lo, want_lo)
+    np.testing.assert_array_equal(cnt, want_cnt)
+    np.testing.assert_array_equal(has_r, want_has)
+
+
+def test_count_program_holds_no_search_and_no_row_wide_scatter():
+    """The join's count program finds every run by scans: no
+    ``searchsorted``, no loop but the lexsort's (a search is a ``while``
+    that gathers every query a step), no scatter with an index a row
+    (the id scatter and the split of the sort by side must not come
+    back), and the key changes from one stacked gather by the order."""
+    import jax
+
+    from spark_rapids_tpu.data.column import HostBatch, host_to_device
+    from spark_rapids_tpu.exec.joins import TpuHashJoinExec
+
+    rng = np.random.default_rng(31)
+    left = {"k": rng.integers(0, 900, 3000), "a": rng.random(3000)}
+    right = {"k": rng.permutation(1200)[:1000], "b": rng.random(1000)}
+    sess = srt.Session()
+    q = sess.create_dataframe(left).join(sess.create_dataframe(right),
+                                         on="k", how="inner")
+    todo, join = [sess.physical_plan(q.plan)], None
+    while todo:
+        node = todo.pop()
+        if isinstance(node, TpuHashJoinExec):
+            join = node
+        todo.extend(node.children)
+    assert join is not None, q.explain()
+
+    lb = host_to_device(HostBatch.from_pydict(left))
+    rb = host_to_device(HostBatch.from_pydict(right))
+    n = lb.padded_rows + rb.padded_rows
+    assert n == 4096 + 1024
+    eqns = list(jaxpr_eqns(
+        jax.make_jaxpr(join.kernel_twin()._count)(lb, rb).jaxpr))
+    named = [e.params.get("name") for e in eqns
+             if e.primitive.name in ("pjit", "jit")]
+    assert "searchsorted" not in named, named
+    # one int64 key is three words: the lexsort's loop over two of them
+    # is the one loop that gathers (the scans' loops add, row by row)
+    gathering = [e for e in eqns if e.primitive.name in ("scan", "while")
+                 and any(i.primitive.name == "gather" for i in jaxpr_eqns(
+                     (e.params.get("jaxpr") or e.params["body_jaxpr"]).jaxpr))]
+    assert len(gathering) == 1, gathering
+    wide = [(e.primitive.name, e.invars[1].aval.shape) for e in eqns
+            if e.primitive.name.startswith("scatter")
+            and e.invars[1].aval.shape[:1] >= (rb.padded_rows,)]
+    assert not wide, wide
+    by_order = [e.outvars[0].aval.shape for e in eqns
+                if e.primitive.name == "gather"
+                and e.outvars[0].aval.shape[-1:] == (n,)
+                and e.outvars[0].aval.ndim == 2]
+    assert by_order == [(3, n)], by_order
+    assert sum(e.primitive.name == "sort" for e in eqns) == 4

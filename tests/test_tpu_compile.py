@@ -286,6 +286,43 @@ def test_mesh_exchange_compiles_for_four_chips(topo):
         _compile(stage(jnp.int64), batch)
 
 
+def test_join_probe_and_expand_compile_for_four_chips(topo, as_tpu):
+    """The mesh runner's join stage body (``join_static``: probe, emit
+    counts, expand) under ``shard_map`` on a 2x2 mesh.  The probe's
+    scans start their carries from the inputs and nothing in it
+    scatters, so the chip's compiler takes it (a scatter whose indices
+    and updates both come from an iota aborts its fusion pass); the
+    program holds the lexsort's two sorts and the probe's two."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_rapids_tpu.ops.kernels import join as J
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("dp",))
+    spread = NamedSharding(mesh, P("dp"))
+
+    def stacked(dtype):
+        return jax.ShapeDtypeStruct((4, ROWS), np.dtype(dtype),
+                                    sharding=spread)
+
+    def key():
+        return DeviceColumn(T.INT64, stacked(np.int64), stacked(np.bool_))
+
+    def per_shard(lk, rk, l_ok, r_ok):
+        lk, rk = (DeviceColumn(c.dtype, c.data[0], c.validity[0])
+                  for c in (lk, rk))
+        p = J.probe([lk], [rk], l_ok[0], r_ok[0])
+        emit, r_extra, total = J.emit_counts(p, "full", l_ok[0], r_ok[0])
+        pairs = J.expand_pairs(p, emit, r_extra, ROWS)
+        return tuple(x[None] for x in pairs) + (total[None],)
+
+    stage = jax.shard_map(per_shard, mesh=mesh, in_specs=P("dp"),
+                          out_specs=P("dp"))
+    text = _compile(stage, key(), key(), stacked(np.bool_),
+                    stacked(np.bool_)).as_text()
+    assert text.count(" sort(") == 4, text.count(" sort(")
+
+
 # --------------------------------------------------------------------------
 # what the chip's float64 branch computes (runs on the CPU backend)
 # --------------------------------------------------------------------------
