@@ -352,7 +352,7 @@ def q16(t):
         & ~col("p_type").like("MEDIUM POLISHED%")
         & col("p_size").isin(49, 14, 23, 45, 19, 3, 36, 9))
     bad_supp = t["supplier"].filter(
-        col("s_comment").contains("Customer Complaints"))
+        col("s_comment").like("%Customer%Complaints%"))
     ps = (t["partsupp"].select("ps_partkey", "ps_suppkey")
           .join(bad_supp.select("s_suppkey"),
                 on=(["ps_suppkey"], ["s_suppkey"]), how="anti")

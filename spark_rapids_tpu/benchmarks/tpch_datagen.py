@@ -126,10 +126,12 @@ def generate(sf: float = 0.001, seed: int = 42):
     # supplier ---------------------------------------------------------------
     sk = np.arange(1, n_supp + 1, dtype=np.int64)
     s_comment = _comment(rng, n_supp)
-    # Q16 needle: some suppliers have complaints
+    # Q16 needle: some suppliers have complaints, with text between the
+    # two words as the specification has it (%Customer%Complaints%)
     mask = rng.random(n_supp) < 0.1
     s_comment[mask] = np.char.add(
-        s_comment[mask].astype(str), " Customer Complaints").astype(object)
+        s_comment[mask].astype(str),
+        " Customer even Complaints").astype(object)
     out["supplier"] = (_schema([("s_suppkey", T.INT64),
                                 ("s_name", T.STRING),
                                 ("s_address", T.STRING),

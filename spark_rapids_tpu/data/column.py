@@ -376,7 +376,8 @@ def host_to_device(batch: HostBatch, min_bucket_rows: int = 128,
         validity[:n] = valid_np
         if c.dtype.id is TypeId.STRING:
             width = (string_widths or {}).get(ci)
-            bm, ln = dstrings.encode(c.data, c.validity, max_len=width)
+            with trace_range("HostToDevice.strings"):
+                bm, ln = dstrings.encode(c.data, c.validity, max_len=width)
             if string_guard_bytes > 0 \
                     and padded * bm.shape[1] > string_guard_bytes:
                 raise RuntimeError(

@@ -142,19 +142,56 @@ def _free_shuffle_buffers(fw, store, spill_listener=None,
             pass
 
 
+def _hash_pids(bound, n_out, batch: DeviceBatch):
+    import jax.numpy as jnp
+
+    cols = [as_device_column(k.eval_tpu(batch), batch.padded_rows)
+            for k in bound]
+    h = hashing.hash_device_batch(cols)
+    return hashing.pmod(h, n_out).astype(jnp.int32)
+
+
+def _slice(batch: DeviceBatch, pids, p) -> DeviceBatch:
+    return compact(batch, pids == p)
+
+
+def _range_pids(bound_keys, batch: DeviceBatch, bounds):
+    return range_pids_from_bounds(range_key_passes(batch, bound_keys),
+                                  bounds)
+
+
+def _sample(passes, nr):
+    import jax.numpy as jnp
+
+    idx = (jnp.arange(RANGE_SAMPLES_PER_BATCH, dtype=jnp.int32)
+           * jnp.maximum(nr, 1)) // RANGE_SAMPLES_PER_BATCH
+    return passes[:, idx]
+
+
 class TpuShuffleExchangeExec(TpuExec):
     def __init__(self, child, plan):
         super().__init__([child])
         self.plan = plan  # physical.ShuffleExchangeExec
         self.partitioning = plan.partitioning
         self.n_out = plan.n_out
-        from .kernel_cache import jit_kernel
+        from .kernel_cache import (expr_signature, jit_kernel,
+                                   schema_signature)
 
-        # partitioning objects carry bound key state with no canonical
-        # fingerprint — compile privately (key=None); counters still
-        # apply, and ``kind`` names the programs for the exchange
-        self._hash_kernel = jit_kernel(self._hash_pids, kind="shuffle")
-        self._slice_kernel = jit_kernel(self._slice, kind="shuffle")
+        # the exchange's own programs are keyed by what their bodies
+        # read (the child's layout, the bound keys, the fan-out) and
+        # bound to none of this exec: a plan built anew for the same
+        # query finds them compiled (a kernel made privately here cost
+        # every new plan one compile an exchange program).  Each key
+        # ends in the program's name (kernel_cache.program_name)
+        layout = schema_signature(child.schema)
+        self._slice_kernel = jit_kernel(_slice, key=("shuffle", "_slice"))
+        if isinstance(self.partitioning, HashPartitioning):
+            self._hash_kernel = jit_kernel(
+                functools.partial(_hash_pids, self.partitioning._bound,
+                                  self.n_out),
+                key=("shuffle", layout,
+                     expr_signature(self.partitioning._bound), self.n_out,
+                     "_hash_pids"))
         # device-resident path: trim, packed partition-build and slice
         # kernels, shared across execs through the kernel cache
         # (module-level bodies keyed by schema layout + fan-out).
@@ -170,29 +207,22 @@ class TpuShuffleExchangeExec(TpuExec):
             self._packed_slice_kernel = DS.packed_slice_kernel(
                 self.schema)
         if isinstance(self.partitioning, RangePartitioning):
+            bound_keys = self.partitioning._bound_keys
+            key_sig = tuple((k.expr.sql(), str(k.expr.dtype),
+                             bool(k.ascending), bool(k.nulls_first))
+                            for k in bound_keys)
             self._passes_kernel = jit_kernel(
-                lambda b: range_key_passes(
-                    b, self.partitioning._bound_keys),
-                kind="shuffle.rangePasses")
+                functools.partial(range_key_passes, bound_keys=bound_keys),
+                key=("shuffle", layout, key_sig, "rangePasses"))
             self._range_pid_kernel = jit_kernel(
-                lambda b, bounds: range_pids_from_bounds(
-                    range_key_passes(b, self.partitioning._bound_keys),
-                    bounds),
-                kind="shuffle.rangePids")
+                functools.partial(_range_pids, bound_keys),
+                key=("shuffle", layout, key_sig, "rangePids"))
             # a module-level body with no closure: shared by its key
             self._bounds_pid_kernel = jit_kernel(
                 range_pids_from_bounds,
                 key=("shuffle.rangePidsFromBounds",))
-            import jax.numpy as jnp
-
-            def _sample(passes, nr):
-                idx = (jnp.arange(RANGE_SAMPLES_PER_BATCH,
-                                  dtype=jnp.int32)
-                       * jnp.maximum(nr, 1)
-                       ) // RANGE_SAMPLES_PER_BATCH
-                return passes[:, idx]
-
-            self._sample_kernel = jit_kernel(_sample, kind="shuffle")
+            self._sample_kernel = jit_kernel(
+                _sample, key=("shuffle", "_sample"))
 
     @property
     def schema(self):
@@ -209,14 +239,6 @@ class TpuShuffleExchangeExec(TpuExec):
         return [TargetRows(None)]
 
     # ------------------------------------------------------------------
-    def _hash_pids(self, batch: DeviceBatch):
-        import jax.numpy as jnp
-
-        cols = [as_device_column(k.eval_tpu(batch), batch.padded_rows)
-                for k in self.partitioning._bound]
-        h = hashing.hash_device_batch(cols)
-        return hashing.pmod(h, self.n_out).astype(jnp.int32)
-
     def _pids(self, batch: DeviceBatch, rr_start: int = 0, bounds=None):
         import jax.numpy as jnp
 
@@ -230,10 +252,6 @@ class TpuShuffleExchangeExec(TpuExec):
                 return jnp.zeros(batch.padded_rows, dtype=jnp.int32)
             return self._range_pid_kernel(batch, bounds)
         return self._hash_kernel(batch)
-
-    @staticmethod
-    def _slice(batch: DeviceBatch, pids, p) -> DeviceBatch:
-        return compact(batch, pids == p)
 
     # ------------------------------------------------------------------
     def execute_columnar(self, ctx):

@@ -183,6 +183,8 @@ class HostToDeviceExec(TpuExec):
         str_guard = ctx.conf.get(STRING_COLUMN_BYTES_GUARD)
         rctx = R.RetryContext.for_exec(ctx, "HostToDeviceExec")
         waits = ctx.metrics.metric(f"{self.name}.prefetchWaits")
+        # rows x width of the string columns' byte matrices as uploaded
+        matrix_bytes = ctx.metrics.metric(f"{self.name}.stringMatrixBytes")
 
         def upload(hb):
             import time as _time
@@ -203,6 +205,8 @@ class HostToDeviceExec(TpuExec):
                 _PROFILER.record_h2d(hb.estimate_bytes(), dt)
             self.metrics[M.NUM_OUTPUT_ROWS].add(hb.num_rows)
             self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
+            matrix_bytes.add(sum(c.data.size for c in db.columns
+                                 if c.lengths is not None))
             return db
 
         def upload_retry(hb):

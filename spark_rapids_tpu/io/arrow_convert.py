@@ -11,9 +11,11 @@ from typing import Optional
 
 import numpy as np
 import pyarrow as pa
+import pyarrow.compute as pc
 
 from .. import types as T
 from ..data.column import HostBatch, HostColumn
+from ..utils.tracing import trace_range
 
 _ARROW_TO_DTYPE = {
     pa.bool_(): T.BOOL,
@@ -60,7 +62,10 @@ def schema_to_arrow(s: T.Schema) -> pa.Schema:
                                f.nullable) for f in s])
 
 
-def arrow_to_host_batch(tbl, schema: Optional[T.Schema] = None) -> HostBatch:
+def arrow_to_host_batch(tbl, schema: Optional[T.Schema] = None,
+                        string_bytes=None) -> HostBatch:
+    """``string_bytes``: an optional metric that is given the string
+    columns' logical (UTF-8) bytes, read off Arrow's offsets."""
     if isinstance(tbl, pa.RecordBatch):
         tbl = pa.Table.from_batches([tbl])
     if schema is None:
@@ -77,7 +82,11 @@ def arrow_to_host_batch(tbl, schema: Optional[T.Schema] = None) -> HostBatch:
         if arr.null_count:
             validity = np.asarray(arr.is_valid())
         if f.dtype.id is T.TypeId.STRING:
-            data = np.asarray(arr.to_pylist(), dtype=object)
+            # every caller is the scan's decode: a child of ScanDecode
+            with trace_range("ScanDecode.strings"):
+                data = np.asarray(arr.to_pylist(), dtype=object)
+            if string_bytes is not None:
+                string_bytes.add(pc.sum(pc.binary_length(arr)).as_py() or 0)
         elif f.dtype.id is T.TypeId.TIMESTAMP:
             data = arr.cast(pa.timestamp("us")).to_numpy(
                 zero_copy_only=False).astype("datetime64[us]").astype(

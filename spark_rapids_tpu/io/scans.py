@@ -252,7 +252,7 @@ class FileScanExec(P.PhysicalPlan):
         self.metrics_skipped_stripes = 0
         self.metrics_skipped_files = 0
         #: (rows, batches, bytes) metrics of the running execution
-        self._decode_counters = None
+        self._decode_counters = (None,) * 4
         # Hive-partition layout: per-file raw values + the derived
         # constant columns appended to every batch
         self.part_values = part_values or [{} for _ in files]
@@ -273,7 +273,7 @@ class FileScanExec(P.PhysicalPlan):
         producer by default): the reader's step, the Arrow-to-host
         conversion and the partition columns.  The range is closed
         when the batch is handed on."""
-        rows, batches, nbytes = self._decode_counters or (None,) * 3
+        rows, batches, nbytes, _ = self._decode_counters
         for hb in trace_steps("ScanDecode", self._decode_file(fi)):
             if rows is not None:
                 rows.add(hb.num_rows)
@@ -289,6 +289,7 @@ class FileScanExec(P.PhysicalPlan):
         miscexprs.context.input_file_block_start = 0
         miscexprs.context.input_file_block_length = os.path.getsize(path)
         pv = self.part_values[fi] if fi < len(self.part_values) else {}
+        string_bytes = self._decode_counters[3]
 
         def finish(file_batch):
             return self._append_partitions(file_batch, pv, np)
@@ -313,22 +314,24 @@ class FileScanExec(P.PhysicalPlan):
                 return
             for rb in pf.iter_batches(batch_size=self.max_rows,
                                       row_groups=groups, columns=cols):
-                yield finish(ac.arrow_to_host_batch(rb,
-                                                    self._file_schema))
+                yield finish(ac.arrow_to_host_batch(
+                    rb, self._file_schema, string_bytes))
         elif self.fmt == "orc":
             import pyarrow.orc as orc
 
             f = orc.ORCFile(path)
             for i in self._prune_stripes(f, path):
                 stripe = f.read_stripe(i, columns=self._projected_names())
-                batch = ac.arrow_to_host_batch(stripe, self._file_schema)
+                batch = ac.arrow_to_host_batch(stripe, self._file_schema,
+                                               string_bytes)
                 for b in _split_to_target(batch, self.max_rows):
                     yield finish(b)
         elif self.fmt == "csv":
             import pyarrow.csv as pacsv
 
             tbl = pacsv.read_csv(path, **_csv_args(self.options))
-            batch = ac.arrow_to_host_batch(tbl, self._file_schema)
+            batch = ac.arrow_to_host_batch(tbl, self._file_schema,
+                                           string_bytes)
             for b in _split_to_target(batch, self.max_rows):
                 yield finish(b)
         else:
@@ -510,12 +513,14 @@ class FileScanExec(P.PhysicalPlan):
         return kept
 
     def execute(self, ctx):
-        # what ScanDecode produced (estimated bytes: strings sampled)
+        # what ScanDecode produced (estimated bytes: strings sampled;
+        # the string columns' logical bytes exact, from Arrow's offsets)
         reg = ctx.metrics
         self._decode_counters = (
             reg.metric(f"{self.name}.decodedRows"),
             reg.metric(f"{self.name}.decodedBatches"),
-            reg.metric(f"{self.name}.decodedBytes"))
+            reg.metric(f"{self.name}.decodedBytes"),
+            reg.metric(f"{self.name}.decodedStringBytes"))
 
         def make(fi):
             return lambda: self._read_file(fi)

@@ -29,10 +29,17 @@ TRACED = {"spark.rapids.tpu.sql.trace.enabled": True,
           "spark.rapids.tpu.sql.test.enabled": True}
 
 #: spans that do their own work and pull nothing: nothing opens inside
+#: (but a child of their own, see CHILDREN)
 LEAVES = {"Plan", "ScanDecode", "PrefetchWait", "HostToDevice",
           "TpuShuffleWrite.counts", "TpuShuffleRead",
           "DeviceToHost.wait", "DeviceToHost.copy", "TpuFilter",
           "TpuProject", "TpuExpand", "TpuCoalesce.concat", "TpuWindow"}
+
+
+#: the two that name a part of themselves: the string columns'
+#: conversion in the decode, their encoding in the upload (ISSUE 32)
+CHILDREN = {"ScanDecode": {"ScanDecode.strings"},
+            "HostToDevice": {"HostToDevice.strings"}}
 
 
 class Recorder:
@@ -154,8 +161,26 @@ def test_a_parquet_filter_sum_opens_every_span_where_the_work_is(
     # span that pulls nothing (one left open across a ``yield`` would
     # have its consumer's spans inside it)
     assert recorder.misnested == [] and recorder.still_open() == {}
-    inside_a_leaf = [s for s in recorder.spans if s[2] in LEAVES]
+    inside_a_leaf = [s for s in recorder.spans if s[2] in LEAVES
+                     and s[0] not in CHILDREN.get(s[2], ())]
     assert inside_a_leaf == []
+
+
+def test_a_string_column_opens_the_two_children_and_nothing_else(
+        tmp_path, recorder):
+    pq.write_table(pa.table({"k": np.arange(300) % 7,
+                             "s": [f"row {i}" for i in range(300)]}),
+                   os.path.join(str(tmp_path), "part-0.parquet"))
+    sess = srt.Session(dict(TRACED))
+    rows = sess.read_parquet(str(tmp_path)).filter(F.col("k") > 2) \
+        .select("s").collect()
+    assert len(rows) == sum(1 for i in range(300) if i % 7 > 2)
+    assert recorder.parents("ScanDecode.strings") == {"ScanDecode"}
+    assert recorder.parents("HostToDevice.strings") == {"HostToDevice"}
+    assert recorder.misnested == [] and recorder.still_open() == {}
+    assert [s for s in recorder.spans if s[2] in LEAVES | {
+        "ScanDecode.strings", "HostToDevice.strings"}
+        and s[0] not in CHILDREN.get(s[2], ())] == []
 
 
 def test_a_repeat_plans_nothing_but_still_has_its_plan_span(
