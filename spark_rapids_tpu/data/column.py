@@ -19,6 +19,7 @@ TPU-first design decisions (SURVEY §7 architecture mapping):
 """
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, field as dc_field
 from typing import Any, List, Optional, Sequence
 
@@ -32,6 +33,15 @@ from . import strings as dstrings
 # --------------------------------------------------------------------------
 # Host side
 # --------------------------------------------------------------------------
+def _bool_or_none(validity: Optional[np.ndarray]) -> Optional[np.ndarray]:
+    """A column's validity as it is kept: bool, and None when all valid."""
+    if validity is not None and validity.dtype != np.bool_:
+        validity = validity.astype(np.bool_)
+    if validity is not None and bool(validity.all()):
+        validity = None
+    return validity
+
+
 class HostColumn:
     """A host column: numpy data + optional validity (True = valid).
 
@@ -44,11 +54,7 @@ class HostColumn:
                  validity: Optional[np.ndarray] = None):
         self.dtype = dtype
         self.data = data
-        if validity is not None and validity.dtype != np.bool_:
-            validity = validity.astype(np.bool_)
-        if validity is not None and bool(validity.all()):
-            validity = None
-        self.validity = validity
+        self.validity = _bool_or_none(validity)
 
     # ----- construction ----------------------------------------------------
     @staticmethod
@@ -112,6 +118,26 @@ class HostColumn:
     def to_pylist(self) -> List[Any]:
         return [self[i] for i in range(self.num_rows)]
 
+    def arrow_strings(self):
+        """The Arrow array a scanned STRING column still holds (see
+        :class:`ArrowStringColumn`); None for every other column."""
+        return None
+
+    def string_bytes(self) -> int:
+        """A STRING column's logical (UTF-8) bytes.  SAMPLED for an
+        object array (~1k strided rows extrapolated) — an estimate is
+        all the callers need, and the exact per-row encode was a
+        measurable slice of every upload path.  Strided, not prefix,
+        sampling: sorted/clustered columns would bias a prefix sample
+        by orders of magnitude."""
+        n = self.num_rows
+        if not n:
+            return 0
+        sample = self.data[:: max(1, n // 1024)]
+        sampled = sum(len(s.encode("utf-8")) if isinstance(s, str) else 0
+                      for s in sample)
+        return int(sampled * (n / len(sample)))
+
     # ----- transforms -------------------------------------------------------
     def take(self, indices: np.ndarray) -> "HostColumn":
         data = self.data[indices]
@@ -126,15 +152,118 @@ class HostColumn:
     def concat(cols: Sequence["HostColumn"]) -> "HostColumn":
         assert cols, "concat of zero columns"
         dtype = cols[0].dtype
-        data = np.concatenate([c.data for c in cols])
         if any(c.validity is not None for c in cols):
             validity = np.concatenate([c.is_valid() for c in cols])
         else:
             validity = None
+        if all(isinstance(c, ArrowStringColumn) and c._objects is None
+               for c in cols):
+            return ArrowStringColumn(
+                dtype, _concat_strings([c._arrow for c in cols]), validity)
+        data = np.concatenate([c.data for c in cols])
         return HostColumn(dtype, data, validity)
 
     def __repr__(self):  # pragma: no cover
         return f"HostColumn({self.dtype}, rows={self.num_rows}, nulls={self.null_count})"
+
+
+# one lock for every column: the conversion holds the GIL from end to
+# end, so two of them never ran side by side anyway
+_MATERIALIZE_LOCK = threading.Lock()
+
+
+class ArrowStringColumn(HostColumn):
+    """A STRING column as the scan decoded it: Arrow's array (validity,
+    offsets, bytes), not python objects.  The upload builds its byte
+    matrix from the buffers (``host_to_device``); whoever reads ``data``
+    gets the object ndarray of ``str`` (``None`` in null slots) every
+    other STRING column holds, made on the first read and kept.  What
+    runs between a scan and an upload (``num_rows``, ``slice``,
+    ``concat``, ``take``, ``estimate_bytes``) stays on the Arrow side;
+    once the objects exist, ``slice``, ``concat`` and ``take`` cut them
+    and give a plain ``HostColumn``, so no string is converted twice.
+    A pickle carries the objects and loads as a plain ``HostColumn``.
+
+    ``arr``: a ``pa.StringArray`` or ``LargeStringArray``, one chunk,
+    dictionary already decoded; ``validity`` says what its bitmap says."""
+
+    __slots__ = ("_arrow", "_objects")
+
+    def __init__(self, dtype: DType, arr,
+                 validity: Optional[np.ndarray] = None):
+        self.dtype = dtype
+        self._arrow = arr
+        self._objects = None
+        self.validity = _bool_or_none(validity)
+
+    @property
+    def data(self) -> np.ndarray:
+        objs = self._objects
+        if objs is None:
+            with _MATERIALIZE_LOCK:
+                objs = self._objects
+                if objs is None:
+                    with trace_range("HostStrings.materialize"):
+                        objs = np.asarray(self._arrow.to_pylist(),
+                                          dtype=object)
+                    self._objects = objs
+        return objs
+
+    def arrow_strings(self):
+        return self._arrow
+
+    @property
+    def num_rows(self) -> int:
+        return len(self._arrow)
+
+    def string_bytes(self) -> int:
+        """Exact, from the offsets: what the sample approximates."""
+        offsets, _ = dstrings.arrow_buffers(self._arrow)
+        if self.validity is None:
+            return int(offsets[-1]) - int(offsets[0])
+        return int(np.diff(offsets)[self.validity].sum())
+
+    def take(self, indices: np.ndarray) -> "HostColumn":
+        idx = np.asarray(indices)
+        if self._objects is not None or idx.ndim != 1 \
+                or idx.dtype.kind not in "iub":
+            return super().take(indices)
+        validity = None if self.validity is None else self.validity[idx]
+        pa = dstrings._pa()
+        if idx.dtype.kind == "b":
+            arr = self._arrow.filter(pa.array(idx))
+        else:  # numpy's negative indices count from the end
+            arr = self._arrow.take(pa.array(
+                np.where(idx < 0, idx + len(self._arrow), idx)))
+        return ArrowStringColumn(self.dtype, arr, validity)
+
+    def slice(self, start: int, stop: int) -> "HostColumn":
+        if self._objects is not None:
+            return super().slice(start, stop)
+        start, stop, _ = slice(start, stop).indices(len(self._arrow))
+        v = None if self.validity is None else self.validity[start:stop]
+        return ArrowStringColumn(
+            self.dtype, self._arrow.slice(start, max(stop - start, 0)), v)
+
+    def __reduce__(self):
+        return HostColumn, (self.dtype, self.data, self.validity)
+
+    def __repr__(self):  # pragma: no cover
+        return (f"ArrowStringColumn(rows={self.num_rows}, "
+                f"nulls={self.null_count})")
+
+
+def _concat_strings(arrs):
+    """One Arrow string array of several; 64-bit offsets when the parts'
+    types differ or their bytes would not fit 32-bit ones."""
+    pa = dstrings._pa()
+    total = 0
+    for a in arrs:
+        offsets, _ = dstrings.arrow_buffers(a)
+        total += int(offsets[-1]) - int(offsets[0])
+    if total >= (1 << 31) - 1 or any(a.type != arrs[0].type for a in arrs):
+        arrs = [a.cast(pa.large_string()) for a in arrs]
+    return pa.concat_arrays(arrs)
 
 
 class HostBatch:
@@ -201,21 +330,13 @@ class HostBatch:
 
     def estimate_bytes(self) -> int:
         """Reference analogue: GpuBatchUtils row/byte estimation.
-        String bytes are SAMPLED (~1k strided rows extrapolated) — an
-        estimate is all the callers need, and the exact per-row encode
-        was a measurable slice of every upload path.  Strided, not
-        prefix, sampling: sorted/clustered columns would bias a prefix
-        sample by orders of magnitude."""
+        A string column counts its logical bytes (``string_bytes``:
+        sampled for python objects, exact for Arrow's offsets) and a
+        4-byte offset a row."""
         total = 0
         for c in self.columns:
             if c.dtype.id is TypeId.STRING:
-                n = c.num_rows
-                if n:
-                    sample = c.data[:: max(1, n // 1024)]
-                    sampled = sum(
-                        len(s.encode("utf-8")) if isinstance(s, str)
-                        else 0 for s in sample)
-                    total += int(sampled * (n / len(sample))) + 4 * n
+                total += c.string_bytes() + 4 * c.num_rows
             else:
                 total += c.data.nbytes
             total += (c.num_rows + 7) // 8  # validity bitmap estimate
@@ -376,8 +497,15 @@ def host_to_device(batch: HostBatch, min_bucket_rows: int = 128,
         validity[:n] = valid_np
         if c.dtype.id is TypeId.STRING:
             width = (string_widths or {}).get(ci)
+            arr = c.arrow_strings()
             with trace_range("HostToDevice.strings"):
-                bm, ln = dstrings.encode(c.data, c.validity, max_len=width)
+                if arr is not None:  # from a scan: no python object made
+                    bm, ln = dstrings.encode_buffers(
+                        *dstrings.arrow_buffers(arr), c.validity,
+                        max_len=width)
+                else:
+                    bm, ln = dstrings.encode(c.data, c.validity,
+                                             max_len=width)
             if string_guard_bytes > 0 \
                     and padded * bm.shape[1] > string_guard_bytes:
                 raise RuntimeError(

@@ -11,7 +11,15 @@ This supports vectorized upper/lower/substring/length/contains/starts/ends/
 concat/compare on the VPU.  Regex-class ops fall back to the host engine,
 mirroring the reference's regex bail-outs (GpuOverrides.scala:326-371).
 
-Host-side strings are ``object`` ndarrays of python ``str``.
+Host side, a string column is one of two things, and the column knows
+which (``data/column.py``): an ``object`` ndarray of python ``str``
+(``None`` in null slots), as ``decode``, ``from_pylist`` and the host
+operators make it, or the Arrow array a scan decoded
+(``ArrowStringColumn``: validity, offsets, bytes), which becomes python
+objects only when someone reads its ``data``.  Both upload through one
+function, :func:`encode_buffers`: ``encode`` asks Arrow for an object
+array's offsets and bytes first, a scanned column has them in hand
+(:func:`arrow_buffers`).  The device arrays are the same to the byte.
 """
 from __future__ import annotations
 
@@ -94,24 +102,63 @@ def encode(values: np.ndarray, validity: Optional[np.ndarray],
             vals = np.where(np.asarray(validity, dtype=bool), vals, None)
         with _PA_LOCK:
             arr = pa.array(vals, type=pa.string())
-            bufs = arr.buffers()
-            offsets = np.array(
-                np.frombuffer(bufs[1], dtype=np.int32, count=n + 1))
-            nbytes = int(offsets[-1])
-            data = (np.array(np.frombuffer(bufs[2], dtype=np.uint8,
-                                           count=nbytes))
-                    if bufs[2] is not None and nbytes else
-                    np.empty(0, dtype=np.uint8))
         # null rows have equal offsets, so their lengths are already 0
-        lengths = np.diff(offsets).astype(np.int32)
+        offsets, data = arrow_buffers(arr)
     except Exception:  # noqa: BLE001 — any arrow failure: exact slow path
         return _encode_slow(values, validity, max_len)
-    ml = int(lengths.max()) if n else 0
+    return encode_buffers(offsets, data, None, max_len)
+
+
+def arrow_buffers(arr) -> Tuple[np.ndarray, np.ndarray]:
+    """``(offsets[n + 1], bytes)`` of a ``pa.StringArray`` or
+    ``LargeStringArray`` as numpy views of Arrow's buffers (no copy).
+    A sliced array's offsets start where its ``offset`` says, so they
+    need not start at 0; ``bytes`` is the whole data buffer."""
+    n = len(arr)
+    bufs = arr.buffers()
+    odt = np.int64 if arr.type == _pa().large_string() else np.int32
+    if n == 0 or bufs[1] is None:  # an empty array may hold no offset
+        offsets = np.zeros(n + 1, dtype=odt)
+    else:
+        offsets = np.frombuffer(bufs[1], dtype=odt)[
+            arr.offset:arr.offset + n + 1]
+    data = (np.frombuffer(bufs[2], dtype=np.uint8) if bufs[2] is not None
+            else np.empty(0, dtype=np.uint8))
+    return offsets, data
+
+
+def encode_buffers(offsets: np.ndarray, data: np.ndarray,
+                   validity: Optional[np.ndarray],
+                   max_len: Optional[int] = None
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+    """Arrow's string layout into (bytes[rows,max_len], lengths), to
+    ``encode``'s contract: ``max_len`` or the greatest length (at least
+    1), ``ValueError`` when a string is longer, zero padding, length 0
+    and zero bytes in a null row (Arrow allows bytes under one).
+    ``offsets`` has rows + 1 entries of any integer type and may start
+    anywhere in ``data`` (a sliced array)."""
+    n = len(offsets) - 1
+    if n == 0:
+        return (np.zeros((0, 1 if max_len is None else max_len),
+                         dtype=np.uint8), np.zeros(0, dtype=np.int32))
+    lengths = np.diff(offsets).astype(np.int32)
+    data = data[int(offsets[0]):int(offsets[-1])]
+    if validity is not None:
+        valid = np.asarray(validity, dtype=bool)
+        if lengths[~valid].any():
+            data = data[np.repeat(valid, lengths)]
+        lengths = np.where(valid, lengths, np.int32(0))
+    ml = int(lengths.max())
     if max_len is None:
         max_len = max(1, ml)
     elif ml > max_len:
         raise ValueError(f"string of {ml} bytes exceeds max_len {max_len}")
     out = np.zeros((n, max_len), dtype=np.uint8)
+    if data.size == n * ml:
+        # every row as long as the longest (flags, codes, keys): the
+        # bytes are the matrix's first ml columns already
+        out[:, :ml] = data.reshape(n, ml)
+        return out, lengths
     # row-major boolean scatter: the True cells enumerate in exactly
     # concatenated-row order, which is the arrow data buffer's layout
     mask = np.arange(max_len, dtype=np.int32) < lengths[:, None]

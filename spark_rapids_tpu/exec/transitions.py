@@ -185,6 +185,12 @@ class HostToDeviceExec(TpuExec):
         waits = ctx.metrics.metric(f"{self.name}.prefetchWaits")
         # rows x width of the string columns' byte matrices as uploaded
         matrix_bytes = ctx.metrics.metric(f"{self.name}.stringMatrixBytes")
+        # string columns uploaded from Arrow's buffers (a scan's), and
+        # from python objects (every other producer's)
+        from_arrow = ctx.metrics.metric(
+            f"{self.name}.stringColumnsFromArrow")
+        from_objects = ctx.metrics.metric(
+            f"{self.name}.stringColumnsFromObjects")
 
         def upload(hb):
             import time as _time
@@ -207,6 +213,11 @@ class HostToDeviceExec(TpuExec):
             self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
             matrix_bytes.add(sum(c.data.size for c in db.columns
                                  if c.lengths is not None))
+            n_arrow = sum(c.arrow_strings() is not None
+                          for c in hb.columns)
+            from_arrow.add(n_arrow)
+            from_objects.add(sum(c.lengths is not None
+                                 for c in db.columns) - n_arrow)
             return db
 
         def upload_retry(hb):

@@ -11,10 +11,9 @@ from typing import Optional
 
 import numpy as np
 import pyarrow as pa
-import pyarrow.compute as pc
 
 from .. import types as T
-from ..data.column import HostBatch, HostColumn
+from ..data.column import ArrowStringColumn, HostBatch, HostColumn
 from ..utils.tracing import trace_range
 
 _ARROW_TO_DTYPE = {
@@ -62,6 +61,21 @@ def schema_to_arrow(s: T.Schema) -> pa.Schema:
                                f.nullable) for f in s])
 
 
+def _one_array(col, dtype: T.DType):
+    """A table's column as one array, dictionary decoded, and its
+    validity as a bool ndarray (None when nothing is null)."""
+    arr = col.combine_chunks()
+    if isinstance(arr, pa.ChunkedArray):
+        arr = arr.chunk(0) if arr.num_chunks else pa.array(
+            [], type=dtype_to_arrow(dtype))
+    if pa.types.is_dictionary(arr.type):
+        arr = arr.dictionary_decode()
+    validity = None
+    if arr.null_count:
+        validity = np.asarray(arr.is_valid())
+    return arr, validity
+
+
 def arrow_to_host_batch(tbl, schema: Optional[T.Schema] = None,
                         string_bytes=None) -> HostBatch:
     """``string_bytes``: an optional metric that is given the string
@@ -72,22 +86,18 @@ def arrow_to_host_batch(tbl, schema: Optional[T.Schema] = None,
         schema = arrow_schema_to_schema(tbl.schema)
     cols = []
     for f in schema:
-        arr = tbl.column(f.name).combine_chunks()
-        if isinstance(arr, pa.ChunkedArray):
-            arr = arr.chunk(0) if arr.num_chunks else pa.array(
-                [], type=dtype_to_arrow(f.dtype))
-        if pa.types.is_dictionary(arr.type):
-            arr = arr.dictionary_decode()
-        validity = None
-        if arr.null_count:
-            validity = np.asarray(arr.is_valid())
         if f.dtype.id is T.TypeId.STRING:
-            # every caller is the scan's decode: a child of ScanDecode
+            # every caller is the scan's decode: a child of ScanDecode.
+            # The column keeps Arrow's array; no python object is made
             with trace_range("ScanDecode.strings"):
-                data = np.asarray(arr.to_pylist(), dtype=object)
-            if string_bytes is not None:
-                string_bytes.add(pc.sum(pc.binary_length(arr)).as_py() or 0)
-        elif f.dtype.id is T.TypeId.TIMESTAMP:
+                arr, validity = _one_array(tbl.column(f.name), f.dtype)
+                col = ArrowStringColumn(f.dtype, arr, validity)
+                if string_bytes is not None:
+                    string_bytes.add(col.string_bytes())
+            cols.append(col)
+            continue
+        arr, validity = _one_array(tbl.column(f.name), f.dtype)
+        if f.dtype.id is T.TypeId.TIMESTAMP:
             data = arr.cast(pa.timestamp("us")).to_numpy(
                 zero_copy_only=False).astype("datetime64[us]").astype(
                 np.int64)

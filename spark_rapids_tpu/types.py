@@ -71,7 +71,9 @@ class DType:
     def np_dtype(self) -> np.dtype:
         """numpy dtype of the physical host representation.
 
-        STRING host columns are ``object`` ndarrays of python str; the
+        A STRING host column's ``data`` is an ``object`` ndarray of
+        python str (a scanned one makes it on first read, from the
+        Arrow array it keeps: ``data/column.py:ArrowStringColumn``); the
         physical dtype here refers to the non-string payload.
         """
         return _NP[self.id]

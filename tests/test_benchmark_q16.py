@@ -256,12 +256,15 @@ def test_string_counters_give_logical_and_padded_bytes(tables_dir, frames):
     assert m["HostToDeviceExec.stringMatrixBytes"] == \
         n_files * bucket * (8 + 25)
     assert m["HostToDeviceExec.stringMatrixBytes"] > logical
+    assert m["HostToDeviceExec.stringColumnsFromArrow"] == 2 * n_files
+    assert m["HostToDeviceExec.stringColumnsFromObjects"] == 0
     # numbers alone: the counters are there and read nothing
     sess.read_parquet(os.path.join(tables_dir, "partsupp")) \
         .select("ps_suppkey").collect()
     m = sess.last_metrics
     assert m["FileScanExec.decodedStringBytes"] == 0
     assert m["HostToDeviceExec.stringMatrixBytes"] == 0
+    assert m["HostToDeviceExec.stringColumnsFromArrow"] == 0
 
 
 # -- what the cell's entry point forced --------------------------------
@@ -289,6 +292,10 @@ def test_a_request_that_arrives_as_a_new_plan_reads_and_compiles(tables_dir):
         assert new_plan.faults(m, CONFIG) == []
         assert m["FileScanExec.decodedRows"] == every_table
         assert m["FileScanExec.decodedStringBytes"] > 0
+        # every string column went up from Arrow's buffers (ISSUE 33):
+        # p_brand and p_type a part batch, s_comment a supplier batch
+        assert m["HostToDeviceExec.stringColumnsFromObjects"] == 0
+        assert m["HostToDeviceExec.stringColumnsFromArrow"] >= 3
         assert m["kernelCache.misses"] == 0
         assert watch.since(mark)["xla_compiles"] == 0
     assert same_plan.run(sess, df, CONFIG) == first
