@@ -13,6 +13,10 @@ When no exchange remains, the final plan executes normally.
 Build sides of shuffled joins materialize first — that is what gives
 the broadcast-conversion rewrite its window: the build side's real
 bytes are known while the stream-side exchange can still be skipped.
+A build side that is itself stages away (an aggregate under it, as in
+``IN (select ... group by ... having ...)``) keeps that window open:
+the join's stream-side exchange waits until the build side has no
+exchange left to run.
 
 The original ``phys`` tree is never mutated (``with_new_children``
 copies every ancestor on a replacement path), so the session's
@@ -30,6 +34,7 @@ from ..exec.coalesce import TpuCoalesceBatchesExec
 from ..exec.exchange import TpuShuffleExchangeExec
 from ..exec.joins import TpuShuffledHashJoinExec
 from ..telemetry.events import emit_event
+from ..utils.tracing import trace_range
 
 log = logging.getLogger(__name__)
 
@@ -146,13 +151,23 @@ def _contains_exchange(node) -> bool:
     return any(_contains_exchange(c) for c in node.children)
 
 
-def _pick_ready(plan) -> List[TpuShuffleExchangeExec]:
-    """Exchanges whose whole input is executable now (no exchange
-    below them), build sides of shuffled joins first — materializing
-    the build side before its stream side is what lets the broadcast
-    rewrite skip the stream exchange entirely."""
+def _pick_ready(plan, metrics=None) -> List[TpuShuffleExchangeExec]:
+    """The exchanges whose whole input is executable now (no exchange
+    below them), in the order to run them.
+
+    Build sides of shuffled joins come first — materializing the build
+    side before its stream side is what lets the broadcast rewrite
+    skip the stream exchange entirely.  For the same reason the
+    stream-side exchange of a join the rewrite may convert comes last
+    while that join's build side still has an exchange to run: run
+    now, it would be read "already executed" by the time the build
+    side's size is known.  Whenever one waits, an exchange under its
+    join's build side is ready, so a waiting exchange is picked only
+    once everything else has run; each pick that passes one over
+    counts in ``aqe.streamExchangesDeferred``."""
     ready: List[TpuShuffleExchangeExec] = []
     seen = set()
+    build_ids, deferred = set(), set()
 
     def visit(node):
         if isinstance(node, TpuShuffleExchangeExec) \
@@ -161,21 +176,26 @@ def _pick_ready(plan) -> List[TpuShuffleExchangeExec]:
                             for c in node.children):
             seen.add(id(node))
             ready.append(node)
+        if isinstance(node, TpuShuffledHashJoinExec):
+            stream, build = map(_strip_coalesce, node.children)
+            build_ids.add(id(build))
+            if node.how in TpuShuffledHashJoinExec._STREAM_SPLITTABLE \
+                    and isinstance(build, TpuShuffleExchangeExec) \
+                    and any(_contains_exchange(c)
+                            for c in build.children):
+                deferred.add(id(stream))
         for c in node.children:
             visit(c)
 
     visit(plan)
-    build_ids = set()
 
-    def mark(node):
-        if isinstance(node, TpuShuffledHashJoinExec):
-            build_ids.add(id(_strip_coalesce(node.children[1])))
-        for c in node.children:
-            mark(c)
+    def rank(e):
+        return 2 if id(e) in deferred else 0 if id(e) in build_ids else 1
 
-    mark(plan)
-    return sorted(ready,
-                  key=lambda e: 0 if id(e) in build_ids else 1)
+    ready.sort(key=rank)
+    if metrics is not None and ready and rank(ready[-1]) == 2:
+        metrics["aqe.streamExchangesDeferred"].add(1)
+    return ready
 
 
 # ==========================================================================
@@ -239,12 +259,35 @@ def _has_nondeterministic(plan) -> bool:
 # ==========================================================================
 # Stage materialization (+ the per-stage retry protocol)
 # ==========================================================================
-def _materialize_stage(exch: TpuShuffleExchangeExec,
-                       ctx) -> MaterializedStageExec:
+def _materialize_stage(exch: TpuShuffleExchangeExec, ctx,
+                       ordinal: int) -> MaterializedStageExec:
     """Run one exchange's write drain to completion on the driver
-    thread, with the SAME retry discipline a reader task applies
-    (plan/physical.py:drain_with_retry): bounded retries with seeded
-    backoff, never for KeyboardInterrupt/SystemExit/AssertionError,
+    thread (span ``AqeStage``: the exchange's id is known once the
+    lazy ``execute_columnar`` has allocated it), and record what it
+    wrote."""
+    data = exch.execute_columnar(ctx)
+    with trace_range("AqeStage", exchange=data.aqe_exchange_id,
+                     stage=ordinal):
+        _drain_stage(data, ctx)
+    obs = ctx.stage_stats.get(data.aqe_exchange_id)
+    if obs is not None:
+        fields = {"exchange": obs.exchange_id,
+                  "partitions": obs.n_out,
+                  "rows": obs.total_rows,
+                  "bytes": obs.total_bytes,
+                  "device_path": obs.device_path}
+        h = obs.histogram()
+        if h is not None:
+            fields.update(rows_min=h["min"], rows_p50=h["p50"],
+                          rows_max=h["max"], skew_pct=h["skewPct"])
+        emit_event("aqe_stage_stats", **fields)
+    return MaterializedStageExec(exch, data, obs)
+
+
+def _drain_stage(data: DevicePartitionedData, ctx) -> None:
+    """The drain, with the SAME retry discipline a reader task
+    applies (plan/physical.py:drain_with_retry): bounded retries with
+    seeded backoff, never for KeyboardInterrupt/SystemExit/AssertionError,
     cancellation terminates; the drain re-arms its writer election on
     failure so a retry re-executes the stage lineage — and re-records
     FRESH stage stats (``StageStats.record_exchange`` overwrites)."""
@@ -253,7 +296,6 @@ def _materialize_stage(exch: TpuShuffleExchangeExec,
     from ..memory.retry import backoff_delay_s
     from ..scheduler.cancel import TpuQueryCancelled
 
-    data = exch.execute_columnar(ctx)
     retries = max(0, ctx.conf.get(TASK_RETRIES))
     sem = None
     if ctx.session is not None and ctx.session.device_manager:
@@ -294,19 +336,6 @@ def _materialize_stage(exch: TpuShuffleExchangeExec,
         # device hold per stage, mirroring the inline collect path
         if sem is not None:
             sem.release_task()
-    obs = ctx.stage_stats.get(data.aqe_exchange_id)
-    if obs is not None:
-        fields = {"exchange": obs.exchange_id,
-                  "partitions": obs.n_out,
-                  "rows": obs.total_rows,
-                  "bytes": obs.total_bytes,
-                  "device_path": obs.device_path}
-        h = obs.histogram()
-        if h is not None:
-            fields.update(rows_min=h["min"], rows_p50=h["p50"],
-                          rows_max=h["max"], skew_pct=h["skewPct"])
-        emit_event("aqe_stage_stats", **fields)
-    return MaterializedStageExec(exch, data, obs)
 
 
 def _rebase_reservation(ctx) -> None:
@@ -356,13 +385,14 @@ def maybe_execute_adaptive(phys, ctx):
     n_stages = 0
     while True:
         check_cancel("aqe.stage_loop")
-        ready = _pick_ready(plan)
+        ready = _pick_ready(plan, ctx.metrics)
         if not ready:
             break
-        stage = _materialize_stage(ready[0], ctx)
+        stage = _materialize_stage(ready[0], ctx, n_stages)
         n_stages += 1
         plan = replace_node(plan, ready[0], stage)
-        plan = AdaptivePlanner(ctx).rewrite(plan)
+        with trace_range("AqeReplan"):
+            plan = AdaptivePlanner(ctx).rewrite(plan)
         _rebase_reservation(ctx)
     ctx.aqe_final_phys = plan
     ctx.metrics["aqe.numStages"].add(n_stages)

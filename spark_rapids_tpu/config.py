@@ -312,7 +312,11 @@ The `adaptive.*` confs (table above) configure the AQE subsystem
 * **Dynamic broadcast conversion** — a planned shuffled-hash join
   whose MATERIALIZED build side lands under
   `adaptive.autoBroadcastJoinThreshold` is demoted to a broadcast
-  join, skipping the stream-side exchange entirely.
+  join, skipping the stream-side exchange entirely.  Stages run build
+  sides first, and a shuffled join's stream-side exchange waits for a
+  build side that is still being computed (an aggregate under it, as
+  in `IN (select ... group by ... having ...)`), so the conversion's
+  window stays open until the build side's size is known.
 * Every decision emits a structured `aqe_*` telemetry event, the
   final plan renders AdaptiveSparkPlan-style in EXPLAIN ANALYZE, and
   the scheduler's per-query HBM reservation is re-based from observed

@@ -135,8 +135,7 @@ class Planner:
         partial = P.HashAggregateExec(child, "partial", node.keys, specs)
         if node.keys:
             part = HashPartitioning(
-                [F.col(n).expr for n in
-                 partial.schema.names[: len(node.keys)]],
+                self._exchange_keys(partial.schema, len(node.keys)),
                 min(self.shuffle_partitions,
                     max(self._n_partitions(child), 1)))
         else:
@@ -168,6 +167,23 @@ class Planner:
             right, HashPartitioning(node.right_keys, n).bind(right.schema))
         return P.HashJoinExec(lex, rex, node.left_keys, node.right_keys,
                               node.how, node.condition, broadcast=False)
+
+    @staticmethod
+    def _exchange_keys(schema, n_keys: int) -> List[Expression]:
+        """The grouping keys a group-by's exchange hash-partitions on.
+        Equal groups meet in one partition when they agree on ANY
+        subset of their keys, so a key the device cannot hash the way
+        Spark does (``utils/hashing.py:device_hash_gap``: FLOAT64 on a
+        TPU) stays a grouping key and is left out of the hash, and the
+        exchange stays on the device (TPC-H q18 groups by
+        ``o_totalprice`` beside four keys that hash).  Where no key
+        hashes, all stay: the exchange's rule tags it for the host."""
+        from ..utils import hashing
+
+        fields = schema.fields[:n_keys]
+        hashable = [f for f in fields
+                    if hashing.device_hash_gap(f.dtype) is None]
+        return [F.col(f.name).expr for f in hashable or fields]
 
     # ------------------------------------------------------------------
     @staticmethod

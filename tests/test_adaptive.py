@@ -167,6 +167,97 @@ def test_broadcast_conversion_no_trigger_when_threshold_zero():
     assert "aqe_broadcast_join" not in _events(sess)
 
 
+def _join_against_filtered_agg(sess, how, min_qty):
+    """``orders`` joined with the orders whose lines sum past
+    ``min_qty``: the build side is an aggregate two exchanges up, which
+    the static planner cannot size, so the join is planned shuffled."""
+    rng = np.random.RandomState(5)
+    n_ord = 3000
+    lines = {"l_orderkey": np.sort(rng.randint(0, n_ord, 12000)).tolist(),
+             "l_quantity": rng.randint(1, 51, 12000).astype(float).tolist()}
+    orders = {"o_orderkey": list(range(n_ord)),
+              "o_total": [round(float(v), 2)
+                          for v in rng.rand(n_ord) * 1000]}
+    li = sess.create_dataframe(lines, n_partitions=4)
+    o = sess.create_dataframe(orders, n_partitions=4)
+    big = (li.group_by(F.col("l_orderkey").alias("ok"))
+           .agg(F.sum("l_quantity").alias("qty"))
+           .filter(F.col("qty") > F.lit(min_qty)))
+    if how == "semi":
+        big = big.select("ok")
+    return o.join(big, on=(["o_orderkey"], ["ok"]), how=how)
+
+
+def _exchanged_rows(metrics):
+    return sorted(v for k, v in metrics.items()
+                  if k.startswith("shuffle.exchange")
+                  and k.endswith(".rowsTotal"))
+
+
+@pytest.mark.parametrize("how", ["semi", "inner"])
+def test_stream_side_waits_for_a_build_side_still_being_computed(how):
+    """The stream side's exchange used to run while the aggregate under
+    the build side was still stages away, so the conversion always came
+    too late (ISSUE 34)."""
+    off = _join_against_filtered_agg(
+        _sess(adaptive=False), how, 250.0).collect()
+    host = _join_against_filtered_agg(
+        srt.Session(tpu_enabled=False), how, 250.0).collect()
+    sess = _sess(TELE)
+    got = _join_against_filtered_agg(sess, how, 250.0).collect()
+    assert 0 < len(got) < 3000
+    assert _norm(got) == _norm(off) == _norm(host)
+    m = sess.last_metrics
+    assert m["aqe.numJoinsConverted"] == 1
+    assert m["aqe.streamExchangesDeferred"] == 1
+    assert "aqe_broadcast_join" in _events(sess)
+    # the aggregate's exchange and the survivors', never the 3,000
+    # stream rows
+    assert m["aqe.numStages"] == 2
+    assert _exchanged_rows(m)[0] == len(got) and \
+        3000 not in _exchanged_rows(m)
+
+
+def test_deferred_stream_side_runs_when_the_build_side_lands_too_big():
+    # nothing can be converted under this threshold: the stream side
+    # waits, then runs all the same, and the shuffled join answers
+    conf = {"spark.rapids.tpu.sql.adaptive.autoBroadcastJoinThreshold": 64}
+    off = _join_against_filtered_agg(
+        _sess(adaptive=False), "semi", 100.0).collect()
+    sess = _sess(conf)
+    got = _join_against_filtered_agg(sess, "semi", 100.0).collect()
+    assert len(got) > 500 and _norm(got) == _norm(off)
+    m = sess.last_metrics
+    assert "aqe.numJoinsConverted" not in m
+    assert m["aqe.streamExchangesDeferred"] == 1
+    assert m["aqe.numStages"] == 3
+    assert 3000 in _exchanged_rows(m)
+
+
+def test_a_join_of_two_leaf_scans_keeps_its_stage_order():
+    from spark_rapids_tpu.adaptive.executor import _pick_ready
+    from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
+
+    sess = _sess(SHUFFLED)
+    df = _join_agg_df(sess)
+    phys = sess.physical_plan(df.plan)
+    join, = [n for n in _walk(phys)
+             if isinstance(n, TpuShuffledHashJoinExec)]
+    ready = _pick_ready(phys)
+    # both sides are ready at once: the build side first, nothing waits
+    assert len(ready) == 2
+    assert any(ready[0] is n for n in _walk(join.children[1]))
+    assert any(ready[1] is n for n in _walk(join.children[0]))
+    df.collect()
+    assert "aqe.streamExchangesDeferred" not in sess.last_metrics
+
+
+def _walk(node):
+    yield node
+    for c in node.children:
+        yield from _walk(c)
+
+
 def test_coalesce_trigger_and_no_trigger_boundary():
     # default 64MB target: the tiny partitions all merge
     sess = _sess(TELE)

@@ -156,6 +156,50 @@ def test_float64_hash_key_is_tagged_at_plan_time(as_tpu):
     assert "* ShuffleExchangeExec" in keyed_on_int, keyed_on_int
 
 
+def test_group_by_exchange_hashes_only_the_keys_the_chip_can_hash(
+        monkeypatch):
+    """Equal groups agree on every subset of their keys, so a group-by
+    over a double and keys that hash partitions on the latter alone
+    (``plan/planner.py:_exchange_keys``) and its exchange stays on the
+    device, the double a grouping key all the same.  Run here with the
+    gap declared on this backend too: a device hash of the double would
+    raise inside the trace."""
+    import spark_rapids_tpu as srt
+    from spark_rapids_tpu import f
+    from spark_rapids_tpu.exec.exchange import TpuShuffleExchangeExec
+    from spark_rapids_tpu.utils import hashing
+
+    monkeypatch.setattr(
+        hashing, "device_hash_gap",
+        lambda dtype: "no IEEE bits" if dtype == T.FLOAT64 else None)
+    rng = np.random.default_rng(34)
+    data = {"k": rng.integers(0, 40, 600).tolist(),
+            "price": (rng.integers(0, 3, 600) + 0.25).tolist(),
+            "v": rng.integers(0, 9, 600).tolist()}
+
+    def grouped(sess):
+        return (sess.create_dataframe(data, n_partitions=4)
+                .group_by("price", "k").agg(f.sum("v").alias("s")))
+
+    sess = srt.Session({"spark.rapids.tpu.sql.test.enabled": True})
+    df = grouped(sess)
+    assert "! ShuffleExchangeExec" not in df.explain()
+
+    def walk(node):
+        yield node
+        for c in node.children:
+            yield from walk(c)
+
+    exchange, = [n for n in walk(sess.physical_plan(df.plan))
+                 if isinstance(n, TpuShuffleExchangeExec)]
+    assert [k.sql() for k in exchange.partitioning.keys] == ["k"]
+    got = df.collect()
+    assert len(got) == len({(p, k) for p, k in
+                            zip(data["price"], data["k"])})
+    assert sorted(got) == sorted(
+        grouped(srt.Session(tpu_enabled=False)).collect())
+
+
 def test_mesh_runner_names_the_float64_hash_gap(as_tpu):
     """The mesh runner adds hash exchanges of its own (join-colocation
     repair, complete-mode aggregates, window partition-by) on keys no
@@ -217,6 +261,49 @@ def test_q1_pipeline_compiles_for_v5e(one_chip, as_tpu):
         lambda a: _shape(one_chip, a.shape, a.dtype), example)
     mem = _compile(fn, shapes).memory_analysis()
     assert mem.temp_size_in_bytes <= 64 * mem.argument_size_in_bytes, mem
+
+
+@pytest.mark.parametrize("mode", ["partial", "final"])
+def test_group_by_with_a_float64_key_compiles_for_v5e(one_chip, as_tpu,
+                                                      mode):
+    """The shape of TPC-H q18's second group-by, planned as the chip
+    plans it: an 18-byte string, two integers and a double as keys, and
+    a sum.  The
+    double is ordered and compared as its pair of float32 words and is
+    hashed nowhere: the exchange between the two aggregates partitions
+    on the others (``plan/planner.py:_exchange_keys``)."""
+    import spark_rapids_tpu as srt
+    from spark_rapids_tpu import f
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.exchange import TpuShuffleExchangeExec
+
+    sess = srt.Session({"spark.rapids.tpu.sql.test.enabled": True})
+    df = (sess.create_dataframe(
+        {"c_name": ["Customer#000000001", "Customer#000000002"],
+         "c_custkey": [1, 2], "o_orderkey": [1, 2],
+         "o_totalprice": [10.5, 20.25], "l_quantity": [3.0, 4.0]},
+        n_partitions=2)
+        .group_by("c_name", "c_custkey", "o_orderkey", "o_totalprice")
+        .agg(f.sum("l_quantity").alias("sum_quantity")))
+
+    def walk(node):
+        yield node
+        for c in node.children:
+            yield from walk(c)
+
+    nodes = list(walk(sess.physical_plan(df.plan)))   # strict: no raise
+    exchange, = [n for n in nodes if isinstance(n, TpuShuffleExchangeExec)]
+    assert [k.sql() for k in exchange.partitioning.keys] == \
+        ["c_name", "c_custkey", "o_orderkey"]
+    agg, = [n for n in nodes if isinstance(n, TpuHashAggregateExec)
+            and n.mode == mode]
+    schema = agg.children[0].schema
+    batch = DeviceBatch(schema, [_column(one_chip, fld.dtype, 18)
+                                 for fld in schema],
+                        _shape(one_chip, (), np.int32))
+    out = _compile(agg.kernel_twin().compute_batch, batch).out_info
+    assert [c.dtype for c in out.columns] == \
+        [fld.dtype for fld in agg.schema]
 
 
 def test_exchange_trim_and_build_compile_for_v5e(one_chip, as_tpu):

@@ -183,6 +183,32 @@ def test_a_string_column_opens_the_two_children_and_nothing_else(
         and s[0] not in CHILDREN.get(s[2], ())] == []
 
 
+def test_the_stage_loop_opens_a_span_a_stage_and_one_a_replan(recorder):
+    # ISSUE 34: the adaptive driver's two steps, children of the request
+    sess = srt.Session(dict(TRACED))
+    df = sess.create_dataframe({"k": [1, 2, 1, 2] * 64,
+                                "v": list(range(256))}, n_partitions=2)
+    rows = df.group_by("k").agg(F.sum("v").alias("s")).sort("k").collect()
+    assert [r[0] for r in rows] == [1, 2]
+    stages = sess.last_metrics["aqe.numStages"]
+    assert stages >= 2      # the aggregate's exchange and the sort's
+    assert recorder.parents("AqeStage") == {"Query"}
+    assert recorder.parents("AqeReplan") == {"Query"}
+    # one of each a stage, in turn, the stage carrying its ordinal and
+    # its exchange's id as metadata, not in its name
+    loop = [s for s in recorder.spans if s[0].startswith("Aqe")]
+    assert [s[0] for s in loop] == ["AqeStage", "AqeReplan"] * stages
+    assert [s[3]["stage"] for s in loop[::2]] == list(range(stages))
+    ids = [s[3]["exchange"] for s in loop[::2]]
+    assert len(set(ids)) == stages and all(
+        f"shuffle.exchange{i}.rowsTotal" in sess.last_metrics for i in ids)
+    assert all(s[3] == {} for s in loop[1::2])
+    # the exchange's write is the stage's work; the re-plan pulls nothing
+    assert recorder.parents("TpuShuffleWrite") == {"AqeStage"}
+    assert [s for s in recorder.spans if s[2] == "AqeReplan"] == []
+    assert recorder.misnested == [] and recorder.still_open() == {}
+
+
 def test_a_repeat_plans_nothing_but_still_has_its_plan_span(
         tmp_path, recorder):
     sess = srt.Session(dict(TRACED))
