@@ -483,7 +483,11 @@ docs/perf_tuning.md):
   through the segment and compacting once at segment exit — results
   stay bit-identical to the unfused plan.  Fusion stops at exchanges,
   aggregates, sorts, joins, transitions and nondeterministic
-  expressions; `fusion.maxSegmentExecs` bounds segment size.
+  expressions; `fusion.maxSegmentExecs` bounds segment size.  One
+  consumer takes the chain in: a `partial`/`complete` aggregate directly
+  over a Filter (or a Filter/Project segment) absorbs it as a prologue
+  of its own kernels and reads the keep mask, so nothing compacts
+  (`fusion.filtersAbsorbed` in `Session.last_metrics`).
 * **Shared kernel cache** — every device exec routes jit compilation
   through the process-wide `KernelCache`, keyed by kernel fingerprint
   and schema signature (the row-bucket dimension rides the underlying
@@ -1052,7 +1056,11 @@ FUSION_ENABLED = conf("spark.rapids.tpu.sql.fusion.enabled").doc(
     "Filter, Expand, Generate) into one fused segment whose single "
     "jitted kernel composes the member compute bodies — one XLA "
     "dispatch per batch per segment, no intermediate HBM "
-    "materialization; results are bit-identical to the unfused plan"
+    "materialization; results are bit-identical to the unfused plan. "
+    "Also lets an update-phase aggregate absorb the Filter/Project "
+    "chain directly under it and read the filters' keep mask instead "
+    "of compacted rows (a keyless float SUM is then equal to the "
+    "unfused plan's to rounding)"
 ).boolean_conf(True)
 FUSION_MAX_SEGMENT_EXECS = conf(
     "spark.rapids.tpu.sql.fusion.maxSegmentExecs").doc(

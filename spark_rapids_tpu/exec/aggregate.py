@@ -13,6 +13,14 @@ The whole aggregate — key eval, sort, segment ids, every buffer reduction,
 and the finalize expressions — traces into ONE jitted XLA program per
 (schema, row-bucket), so XLA fuses the elementwise work into the sort and
 reduction loops.
+
+An update-phase aggregate (``partial`` / ``complete``) may have ABSORBED
+the row-local Filter/Project chain that stood directly under it
+(plan/fusion.py): the chain's node is gone from the plan, its members run
+as a prologue of every kernel that evaluates raw input, and the filters'
+keep mask joins the row mask.  Nothing compacts — the aggregate treats a
+masked-out row as it always treated a padding row (invalid key, invalid
+in every update, sorted last), so dense rows were never needed.
 """
 from __future__ import annotations
 
@@ -26,6 +34,7 @@ from ..ops.kernels import segment as seg
 from ..utils import metrics as M
 from ..utils.tracing import trace_range
 from .base import DevicePartitionedData, TpuExec
+from .fused import _member_fingerprint, run_members
 
 
 class TpuHashAggregateExec(TpuExec):
@@ -39,20 +48,33 @@ class TpuHashAggregateExec(TpuExec):
     registered with the spill catalog between merges so memory pressure
     can evict it."""
 
-    def __init__(self, child, plan):
+    def __init__(self, child, plan, absorbed=()):
         super().__init__([child])
         self.plan = plan  # physical.HashAggregateExec (exprs already bound)
         self.mode = plan.mode
         self.keys = plan.keys
         self.specs = plan.specs
         self._schema = plan.schema
+        #: the row-local members (TpuFilterExec / TpuProjectExec, closest
+        #: to the source first) that stood between ``child`` and this
+        #: aggregate until the fusion pass folded them in; ``keys`` and
+        #: the specs are bound to the last member's schema
+        self.absorbed = list(absorbed)
+        assert not self.absorbed or self.mode != "final", \
+            "a final aggregate reads buffers, not raw rows"
         from .kernel_cache import (expr_signature, jit_kernel,
                                    schema_signature)
 
+        #: what the prologue adds to the kernel keys (and to the mesh's
+        #: stage signature): () where nothing was absorbed
+        self.absorbed_signature = tuple(
+            _member_fingerprint(m) for m in self.absorbed)
         sig = ("agg", self.mode, schema_signature(child.schema),
                expr_signature(self.keys),
                tuple(sp.func.sql() for sp in self.specs),
                schema_signature(plan.schema))
+        if self.absorbed:
+            sig += (self.absorbed_signature,)
         twin = self.kernel_twin()
         self._kernel = jit_kernel(twin.compute_batch,
                                   key=sig + ("batch",))
@@ -68,6 +90,19 @@ class TpuHashAggregateExec(TpuExec):
         self._merge_final_kernel = jit_kernel(
             lambda b: twin._compute(b, "merge", "final"),
             key=sig + ("merge_final",))
+
+    def kernel_twin(self):
+        # the absorbed members still link to the chain they stood in: a
+        # cached kernel must not pin that subtree (as exec/fused.py)
+        twin = super().kernel_twin()
+        twin.absorbed = [m.kernel_twin() for m in self.absorbed]
+        return twin
+
+    def prologue(self, batch: DeviceBatch):
+        """Raw input through the absorbed members: (the rows as the
+        aggregate's expressions read them, the filters' keep mask)."""
+        (out,) = run_members(self.absorbed, batch)  # no Expand: one stream
+        return out
 
     def compute_batch(self, batch: DeviceBatch) -> DeviceBatch:
         """The mode's full aggregation over one batch (trace-safe; also
@@ -117,6 +152,12 @@ class TpuHashAggregateExec(TpuExec):
         nkeys = len(self.keys)
         padded = batch.padded_rows
         rm = batch.row_mask()
+        held = None
+        if phase == "update" and self.absorbed:
+            # a row the absorbed filters drop is absent as a padding
+            # row is: invalid in every key and input, sorted last
+            batch, keep = self.prologue(batch)
+            held, rm = rm, rm & keep
 
         # ----- keys ----------------------------------------------------
         if phase == "merge":
@@ -139,7 +180,10 @@ class TpuHashAggregateExec(TpuExec):
             n_real = (change & pad_sorted).sum().astype(jnp.int32)
         else:
             order = None
-            change = ~pad_sorted        # row 0 starts the one real segment
+            # row 0 starts the one real segment; under an absorbed filter
+            # no sort brings the kept rows to the front, so it spans
+            # every row the batch holds
+            change = ~(pad_sorted if held is None else held)
             n_real = jnp.asarray(1, dtype=jnp.int32)
         out_valid_seg = idx < n_real
 
@@ -151,8 +195,10 @@ class TpuHashAggregateExec(TpuExec):
         else:
             specs += self._merge_specs(batch, rm, nkeys)
         out_cols = []
+        # a keyless aggregate has its one real segment to read
         for (data, valid, lengths), (_, _, dtype) in zip(
-                seg.reduce_sorted(change, order, [sp[:2] for sp in specs]),
+                seg.reduce_sorted(change, order, [sp[:2] for sp in specs],
+                                  segments=None if nkeys else 1),
                 specs):
             if lengths is None and data.dtype != dtype.jnp_dtype:
                 data = data.astype(dtype.jnp_dtype)
@@ -371,8 +417,11 @@ class TpuHashAggregateExec(TpuExec):
             [make(i) for i in range(child.n_partitions)])
 
     def describe(self):
+        absorbed = ", absorbed: " + " -> ".join(
+            m.describe() for m in self.absorbed) if self.absorbed else ""
         return (f"TpuHashAggregate[{self.mode}, keys={len(self.keys)}, "
-                f"aggs={[sp.func.sql() for sp in self.specs]}]")
+                f"aggs={[sp.func.sql() for sp in self.specs]}"
+                f"{absorbed}]")
 
 
 # ==========================================================================

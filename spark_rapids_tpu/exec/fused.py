@@ -17,6 +17,13 @@ jitted kernel composes the member compute bodies:
   compaction, so results are bit-identical to the unfused plan — same
   rows, same order, same padded bucket.
 
+A segment ends at the first consumer that is not row-local.  One such
+consumer does not need the compaction at the exit: an update-phase
+aggregate directly over a Filter/Project chain ABSORBS the members
+(plan/fusion.py, exec/aggregate.py) and runs them through the same
+composition, ``run_members``, reading the keep mask and compacting
+nothing; no segment node is built for that chain.
+
 The kernel is compiled through the shared KernelCache; when the fusion
 pass proved the input batch single-consumer (fresh file-scan uploads),
 the input's buffers are donated to the kernel on backends that honor
@@ -48,6 +55,39 @@ def _member_fingerprint(m) -> tuple:
         return ("g", expr_signature(m.elements), bool(m.position),
                 str(m._out_dtype), schema_signature(m.schema))
     raise TypeError(f"{type(m).__name__} is not fusable")
+
+
+def _apply_member(m, streams):
+    """Advance every (batch, keep-mask) stream through member ``m``
+    (trace-time composition; mask=None means 'nothing filtered')."""
+    import jax.numpy as jnp
+
+    out = []
+    for b, keep in streams:
+        if isinstance(m, TpuFilterExec):
+            k = m._keep(b)
+            out.append((b, k if keep is None else keep & k))
+        elif isinstance(m, TpuExpandExec):
+            out.extend((fn(b), keep) for fn in m._kernel_fns)
+        elif isinstance(m, TpuGenerateExec):
+            nb = m._compute(b)
+            out.append((nb, None if keep is None
+                        else jnp.repeat(keep, len(m.elements))))
+        else:  # TpuProjectExec
+            out.append((m._compute(b), keep))
+    return out
+
+
+def run_members(members, batch: DeviceBatch):
+    """``batch`` through a bottom-up chain of row-local members with
+    every filter's compaction deferred: the surviving (batch, keep-mask)
+    streams.  The fused segment compacts each at its exit; an aggregate
+    that absorbed the chain (exec/aggregate.py) reads the mask and
+    compacts nothing."""
+    streams = [(batch, None)]
+    for m in members:
+        streams = _apply_member(m, streams)
+    return streams
 
 
 class TpuFusedSegmentExec(TpuExec):
@@ -90,34 +130,11 @@ class TpuFusedSegmentExec(TpuExec):
         return self.members[0].children_coalesce_goal
 
     # ---------------- the fused kernel body ----------------------------
-    def _apply_member(self, m, streams):
-        """Advance every (batch, keep-mask) stream through member ``m``
-        (trace-time composition; mask=None means 'nothing filtered')."""
-        import jax.numpy as jnp
-
-        out = []
-        for b, keep in streams:
-            if isinstance(m, TpuFilterExec):
-                k = m._keep(b)
-                out.append((b, k if keep is None else keep & k))
-            elif isinstance(m, TpuExpandExec):
-                out.extend((fn(b), keep) for fn in m._kernel_fns)
-            elif isinstance(m, TpuGenerateExec):
-                nb = m._compute(b)
-                out.append((nb, None if keep is None
-                            else jnp.repeat(keep, len(m.elements))))
-            else:  # TpuProjectExec
-                out.append((m._compute(b), keep))
-        return out
-
     def _compute(self, batch: DeviceBatch):
-        streams = [(batch, None)]
-        for m in self.members:
-            streams = self._apply_member(m, streams)
         # ONE compaction per surviving stream at segment exit — the
         # deferred form of each member filter's compact()
         return tuple(b if keep is None else compact(b, keep)
-                     for b, keep in streams)
+                     for b, keep in run_members(self.members, batch))
 
     # ---------------- execution ----------------------------------------
     def execute_columnar(self, ctx):

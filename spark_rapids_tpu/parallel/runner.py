@@ -211,9 +211,11 @@ def _operator_signature(op) -> Tuple:
     if isinstance(op, TpuSortExec):
         return head + (_sort_signature(op.keys),)
     if isinstance(op, TpuHashAggregateExec):
+        # an absorbed chain is part of the operator's raw body
         return head + (op.mode, expr_signature(op.keys),
                        tuple(sp.func.sql() for sp in op.specs),
-                       schema_signature(op.schema))
+                       schema_signature(op.schema)) + (
+            (op.absorbed_signature,) if op.absorbed else ())
     if isinstance(op, TpuGenerateExec):
         return head + (expr_signature(op.elements), bool(op.position),
                        str(op._out_dtype), schema_signature(op.schema))
@@ -1035,16 +1037,25 @@ class DistributedRunner:
                 return op._compute(child)
             if isinstance(op, TpuHashAggregateExec):
                 child = self._lower(kids[0], env, aux, caps, used_caps)
-                if op.mode == "complete":
+                if op.mode == "complete" and not self._is_single(
+                        part := self._source_partitioning(kids[0])):
                     # single-phase agg: groups must be colocated first
-                    part = self._source_partitioning(kids[0])
-                    if op.keys:
-                        if not self._hash_keys_match(part, op.keys) and \
-                                not self._is_single(part):
-                            child = self._exchange_by_exprs(
-                                child, op.keys, op.children[0].schema)
-                    elif not self._is_single(part):
+                    if not op.keys:
                         child = self._gather_single(child)
+                    elif op.absorbed:
+                        # the keys are read off the absorbed chain's
+                        # rows, so the source's partitioning says
+                        # nothing of them; a dropped row goes nowhere
+                        # and the raw rows that travel pass again
+                        rows, keep = op.prologue(child)
+                        pids = self._hash_pids_by_exprs(
+                            rows, op.keys, rows.schema)
+                        child = self.transport.exchange(
+                            child, jnp.where(keep, pids, self.n), self.n)
+                    elif not self._hash_keys_match(part, op.keys):
+                        child = self._exchange_by_exprs(
+                            child, op.keys, op.children[0].schema)
+                # compute_batch carries an absorbed chain as its prologue
                 return op.compute_batch(child)
             if isinstance(op, (B.TpuProjectExec, B.TpuFilterExec,
                                TpuGenerateExec)):
@@ -1619,7 +1630,11 @@ def _run_request(session, df, mesh, n_devices, recovery) -> HostBatch:
             getattr(session, "last_metrics", None) or {})
         session.last_metrics.update(_fault_stats.snapshot())
         session.last_metrics.update(runner.metrics())
+        from ..plan.fusion import count_absorbed
         from ..shuffle.device_shuffle import GLOBAL as _shuffle_stats
+
+        session.last_metrics["fusion.filtersAbsorbed"] = \
+            count_absorbed(phys)
 
         session.last_metrics.update(_shuffle_stats.metrics_since(
             getattr(ctx, "shuffle_stats_mark", None)))

@@ -15,16 +15,33 @@ coalesce placement around the segment matches the unfused plan).
 Segment boundaries — fusion stops at:
   * anything not row-local: exchanges, aggregates, sorts, joins,
     limits, unions, coalesces and transitions (they are simply not in
-    the fusable set);
+    the fusable set) — with ONE consumer that takes the chain in: an
+    update-phase aggregate (see below);
   * nondeterministic expressions (rand(), partition-id/row-position
     dependent values change meaning when compaction is deferred);
   * ``fusion.maxSegmentExecs`` — a longer chain becomes several
     segments.
+
+One consumer further — the aggregate absorbs the chain under it.  A
+``partial`` / ``complete`` ``TpuHashAggregateExec`` whose child is a
+``TpuFilterExec``, or a segment of Filter and Project members holding a
+Filter, takes those members as a prologue of its own kernels
+(exec/aggregate.py) and the chain's node leaves the plan: the aggregate
+reads the filters' keep mask where it read the row mask, so the
+compaction at the segment's exit, whose dense rows it never needed, is
+not run at all.  Decided from the plan alone: every expression
+deterministic, and no update that reads "the segment's first (last)
+row" over a keyless aggregate, whose one segment is not sorted
+(``first`` / ``last`` without ignore-nulls: the chain stays a node).
+``final`` never absorbs (its input is buffer form).  ``describe()``
+names what was absorbed; ``count_absorbed`` is the plan's
+``fusion.filtersAbsorbed`` in ``Session.last_metrics``.
 """
 from __future__ import annotations
 
 from ..config import (FUSION_ENABLED, FUSION_MAX_SEGMENT_EXECS,
                       KERNEL_CACHE_DONATION, TpuConf)
+from ..exec.aggregate import TpuHashAggregateExec
 from ..exec.basic import TpuExpandExec, TpuFilterExec, TpuProjectExec
 from ..exec.fused import TpuFusedSegmentExec
 from ..exec.generate import TpuGenerateExec
@@ -49,6 +66,12 @@ def _member_exprs(node):
     return []
 
 
+def count_absorbed(plan: P.PhysicalPlan) -> int:
+    """How many aggregates of ``plan`` run an absorbed chain."""
+    own = isinstance(plan, TpuHashAggregateExec) and bool(plan.absorbed)
+    return int(own) + sum(count_absorbed(c) for c in plan.children)
+
+
 class TpuFusionPass:
     def __init__(self, conf: TpuConf):
         self.enabled = bool(conf.get(FUSION_ENABLED))
@@ -66,21 +89,52 @@ class TpuFusionPass:
             and len(node.children) == 1 \
             and all(e.deterministic for e in _member_exprs(node))
 
+    def _chain(self, top) -> list:
+        """The maximal fusable chain that starts at ``top``, top first
+        (at most ``maxSegmentExecs`` long)."""
+        chain = []
+        while len(chain) < self.max_members and self._fusable(top):
+            chain.append(top)
+            top = top.children[0]
+        return chain
+
     def _rewrite(self, plan: P.PhysicalPlan) -> P.PhysicalPlan:
-        if self._fusable(plan):
-            chain = [plan]  # top-of-segment first
-            while len(chain) < self.max_members and \
-                    self._fusable(chain[-1].children[0]):
-                chain.append(chain[-1].children[0])
-            if len(chain) >= 2:
-                child = self._rewrite(chain[-1].children[0])
-                return TpuFusedSegmentExec(
-                    list(reversed(chain)), child,
-                    donate=self.donation and self._single_consumer(child))
+        chain = self._chain(plan)
+        if len(chain) >= 2:
+            child = self._rewrite(chain[-1].children[0])
+            return TpuFusedSegmentExec(
+                list(reversed(chain)), child,
+                donate=self.donation and self._single_consumer(child))
+        if isinstance(plan, TpuHashAggregateExec):
+            members = list(reversed(self._chain(plan.children[0])))
+            if self._absorbable(plan, members):
+                return TpuHashAggregateExec(
+                    self._rewrite(members[0].children[0]), plan.plan,
+                    absorbed=members)
         children = [self._rewrite(c) for c in plan.children]
         if children != list(plan.children):
             plan = plan.with_new_children(children)
         return plan
+
+    @staticmethod
+    def _absorbable(agg: TpuHashAggregateExec, members) -> bool:
+        """Whether ``agg`` may run the chain directly under it
+        (``members``, execution order) as its prologue: the rule in the
+        module docstring."""
+        if agg.mode == "final" or not all(
+                isinstance(m, (TpuFilterExec, TpuProjectExec))
+                for m in members) or not any(
+                isinstance(m, TpuFilterExec) for m in members):
+            return False
+        funcs = [sp.func for sp in agg.specs]
+        reads_rows = list(agg.keys) + \
+            [f.child for f in funcs if f.child is not None]
+        if not all(e.deterministic for e in reads_rows):
+            return False
+        # a keyless segment is not sorted: its first (last) ROW may be
+        # one the filters dropped
+        return bool(agg.keys) or not any(
+            op.endswith("_any") for f in funcs for op, _ in f.updates)
 
     # ------------------------------------------------------------------
     @staticmethod

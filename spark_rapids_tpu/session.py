@@ -229,6 +229,11 @@ class Session:
         # the registry is made inside the range, so it is told after
         ctx.metrics.metric("Session.planTime", "ns").add(
             time.perf_counter_ns() - t0)
+        from .plan.fusion import count_absorbed
+
+        # how often the fusion pass folded a filter into its aggregate
+        ctx.metrics.metric("fusion.filtersAbsorbed").add(
+            count_absorbed(phys))
         return phys, ctx
 
     def _prepare_execution(self, plan, *, scheduled, cancel_token,

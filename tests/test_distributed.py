@@ -568,6 +568,111 @@ def test_a_request_differing_in_one_literal_misses_the_stage_cache():
     _assert_rows_equal(got, _filter_agg(cpu, data, 11.0).collect())
 
 
+def _absorbing(plan):
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+
+    nodes, todo = [], [plan]
+    while todo:
+        nodes.append(todo.pop())
+        todo.extend(nodes[-1].children)
+    return [n for n in nodes
+            if isinstance(n, TpuHashAggregateExec) and n.absorbed]
+
+
+@pytest.mark.parametrize("keyed", [True, False], ids=["keyed", "keyless"])
+def test_filter_under_group_by_runs_absorbed_on_the_mesh(keyed):
+    """The fusion pass folds the filter into the partial aggregate
+    (``fusion.filtersAbsorbed``); the stage lowers the aggregate through
+    ``compute_batch``, which carries the prologue, and is signed with
+    the absorbed members: the mesh's answer is the one chip's, and two
+    stages differing only in the absorbed predicate are two programs."""
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.parallel.runner import (_operator_signature,
+                                                  run_distributed)
+    from spark_rapids_tpu.plan import functions as F
+
+    rng = np.random.RandomState(8)
+    data = {"k": rng.randint(0, 20, 300), "v": rng.rand(300) * 100}
+
+    def q(sess, bound):
+        if keyed:
+            return _filter_agg(sess, data, bound)
+        df = sess.create_dataframe(dict(data))
+        return df.filter(df["v"] > bound).agg(
+            F.sum("v").alias("s"), F.count("*").alias("c"))
+
+    sess, one_chip = Session(), Session()
+    got = run_distributed(sess, q(sess, 10.0), mesh=_mesh(4)).to_rows()
+    assert sess.last_metrics["fusion.filtersAbsorbed"] == 1
+    off = Session({"spark.rapids.tpu.sql.fusion.enabled": False})
+    unabsorbed = run_distributed(off, q(off, 10.0),
+                                 mesh=_mesh(4)).to_rows()
+    assert off.last_metrics["fusion.filtersAbsorbed"] == 0
+    if not keyed:
+        # the mesh answers a keyless aggregate with a row a shard, the
+        # shards that hold nothing with NULLs (as it did before
+        # aggregates absorbed): the one row that counts is the chip's
+        assert len(got) == len(unabsorbed) == 4
+        got, unabsorbed = ([r for r in rows if r != (None, None)]
+                           for rows in (got, unabsorbed))
+    _assert_rows_equal(got, unabsorbed)
+    _assert_rows_equal(got, q(one_chip, 10.0).collect())
+    _assert_rows_equal(got, q(Session(tpu_enabled=False), 10.0).collect())
+
+    def signed(bound):
+        (agg,) = _absorbing(sess.physical_plan(q(sess, bound).plan))
+        return _operator_signature(agg)
+
+    assert signed(10.0) == signed(10.0) != signed(11.0)
+
+
+def test_complete_mode_aggregate_absorbs_on_the_mesh():
+    """A ``complete`` aggregate colocates its groups itself: with an
+    absorbed chain the keys are read off the chain's rows, the dropped
+    rows travel nowhere, and the raw rows that do pass again."""
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.parallel.collective import make_transport
+    from spark_rapids_tpu.parallel.runner import DistributedRunner
+    from spark_rapids_tpu.plan import functions as F
+    from spark_rapids_tpu.plan import physical as P
+    from spark_rapids_tpu.plan.optimizer import optimize
+    from spark_rapids_tpu.plan.overrides import TpuOverrides
+    from spark_rapids_tpu.plan.planner import Planner
+    from spark_rapids_tpu.plan.transitions import TpuTransitionOverrides
+
+    rng = np.random.RandomState(9)
+    data = {"k": rng.randint(0, 20, 300), "v": rng.rand(300) * 100}
+
+    def complete(sess):
+        df = sess.create_dataframe(dict(data), n_partitions=2)
+        q = (df.with_column("g", F.col("k") % 5)
+             .filter(F.col("v") > 25.0).group_by("g")
+             .agg(F.sum("v").alias("s"), F.count("*").alias("c")))
+        final = Planner(sess.conf).plan(optimize(q.plan))
+        partial = final.children[0].children[0]
+        return P.HashAggregateExec(
+            partial.children[0], "complete", q.plan.keys, partial.specs,
+            ["s", "c"])
+
+    cpu = Session(tpu_enabled=False)
+    df = cpu.create_dataframe(dict(data))
+    want = (df.with_column("g", F.col("k") % 5)
+            .filter(F.col("v") > 25.0).group_by("g")
+            .agg(F.sum("v").alias("s"), F.count("*").alias("c"))).collect()
+    assert len(want) == 5
+
+    sess = Session()
+    phys = TpuTransitionOverrides(sess.conf).apply(
+        TpuOverrides(sess.conf).apply(complete(sess)))
+    (agg,) = _absorbing(phys)
+    assert agg.mode == "complete" and len(agg.absorbed) == 2
+    mesh = _mesh(4)
+    runner = DistributedRunner(
+        mesh, transport=make_transport(sess.conf, mesh.axis_names[0]))
+    got = runner.run(phys, P.ExecContext(sess.conf, sess)).to_rows()
+    _assert_rows_equal(got, want)
+
+
 def test_another_schema_misses_the_stage_cache():
     from spark_rapids_tpu import Session
     from spark_rapids_tpu.parallel.runner import run_distributed

@@ -193,6 +193,41 @@ def test_specs_of_one_kind_share_a_scan_and_each_keeps_its_answer():
         check(np.asarray(data)[:n_seg][ok], want[:n_seg][ok])
 
 
+@pytest.mark.parametrize("segments", [1, 3])
+def test_leading_segments_alone_read_as_all_of_them_would(segments):
+    """``segments``: the caller reads only that many leading segments (a
+    keyless aggregate: its one), so only their ends are gathered; those
+    rows answer to the bit as without the hint, the rest are zeros."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(43)
+    n, change = _change("four_blocks", rng)
+    valid = jnp.asarray(_valid("random", n, rng))
+    strings = np.frombuffer(rng.bytes(n * 3), np.uint8).reshape(n, 3) % 3
+    specs = [(DeviceColumn(T.from_numpy(v.dtype), jnp.asarray(v), valid),
+              op) for v, op in [
+        (_values(np.float64, n, rng), "sum"),
+        (_values(np.int32, n, rng), "count"),
+        (_values(np.int32, n, rng), "min"),
+        (_values(np.int64, n, rng), "first"),
+        (_values(np.float64, n, rng), "last_any")]]
+    specs.append((DeviceColumn(
+        T.STRING, jnp.asarray(strings + ord("a")), valid,
+        jnp.full((n,), 3, jnp.int32)), "max"))
+    whole = seg.reduce_sorted(jnp.asarray(change), None, specs)
+    some = seg.reduce_sorted(jnp.asarray(change), None, specs,
+                             segments=segments)
+    for want, got in zip(whole, some):
+        for w, g in zip(want, got):
+            if w is None:
+                assert g is None
+                continue
+            assert g.shape == w.shape and g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g)[:segments],
+                                          np.asarray(w)[:segments])
+            assert not np.asarray(g)[segments:].any()
+
+
 def test_a_block_sums_in_row_order_and_blocks_to_rounding():
     """Inside a block the scan adds a row at a time, as the oracle does
     (a sum that cancels catastrophically still comes out equal); a

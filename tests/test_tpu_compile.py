@@ -80,6 +80,12 @@ def _column(one_chip, dtype, width=None):
                         valid)
 
 
+def _walk(node):
+    yield node
+    for c in node.children:
+        yield from _walk(c)
+
+
 def _compile(fn, *args):
     import jax
 
@@ -185,12 +191,7 @@ def test_group_by_exchange_hashes_only_the_keys_the_chip_can_hash(
     df = grouped(sess)
     assert "! ShuffleExchangeExec" not in df.explain()
 
-    def walk(node):
-        yield node
-        for c in node.children:
-            yield from walk(c)
-
-    exchange, = [n for n in walk(sess.physical_plan(df.plan))
+    exchange, = [n for n in _walk(sess.physical_plan(df.plan))
                  if isinstance(n, TpuShuffleExchangeExec)]
     assert [k.sql() for k in exchange.partitioning.keys] == ["k"]
     got = df.collect()
@@ -286,12 +287,7 @@ def test_group_by_with_a_float64_key_compiles_for_v5e(one_chip, as_tpu,
         .group_by("c_name", "c_custkey", "o_orderkey", "o_totalprice")
         .agg(f.sum("l_quantity").alias("sum_quantity")))
 
-    def walk(node):
-        yield node
-        for c in node.children:
-            yield from walk(c)
-
-    nodes = list(walk(sess.physical_plan(df.plan)))   # strict: no raise
+    nodes = list(_walk(sess.physical_plan(df.plan)))   # strict: no raise
     exchange, = [n for n in nodes if isinstance(n, TpuShuffleExchangeExec)]
     assert [k.sql() for k in exchange.partitioning.keys] == \
         ["c_name", "c_custkey", "o_orderkey"]
@@ -304,6 +300,67 @@ def test_group_by_with_a_float64_key_compiles_for_v5e(one_chip, as_tpu,
     out = _compile(agg.kernel_twin().compute_batch, batch).out_info
     assert [c.dtype for c in out.columns] == \
         [fld.dtype for fld in agg.schema]
+
+
+@pytest.mark.parametrize("query", ["q6", "q1"])
+def test_absorbed_filter_aggregate_compiles_for_v5e_with_no_scatter(
+        one_chip, as_tpu, query):
+    """q6's and q1's partial aggregate with the filter under it run as
+    its prologue (plan/fusion.py): one program for the chip, and none of
+    compaction's scatter in it: the keep mask is all the filter hands
+    on."""
+    import datetime
+
+    import spark_rapids_tpu as srt
+    from spark_rapids_tpu import f
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.ops.kernels.gather import compact
+
+    sess = srt.Session({"spark.rapids.tpu.sql.test.enabled": True})
+    schema = T.Schema(
+        [T.Field("l_shipdate", T.DATE32), T.Field("l_returnflag", T.STRING),
+         T.Field("l_linestatus", T.STRING)]
+        + [T.Field(n, T.FLOAT64) for n in (
+            "l_quantity", "l_extendedprice", "l_discount", "l_tax")])
+    df = sess.create_dataframe(
+        {"l_shipdate": [8825, 8826],    # days: March 1994
+         "l_returnflag": ["A", "N"], "l_linestatus": ["F", "O"],
+         "l_quantity": [3.0, 30.0], "l_extendedprice": [10.5, 20.25],
+         "l_discount": [0.06, 0.01], "l_tax": [0.02, 0.04]},
+        schema=schema)
+    if query == "q6":
+        q = df.filter(
+            (f.col("l_shipdate") >= f.lit(datetime.date(1994, 1, 1)))
+            & (f.col("l_discount") >= f.lit(0.05))
+            & (f.col("l_quantity") < f.lit(24.0))).agg(
+            f.sum(f.col("l_extendedprice") * f.col("l_discount"))
+            .alias("revenue"))
+    else:
+        q = (df.filter(f.col("l_shipdate")
+                       <= f.lit(datetime.date(1998, 9, 2)))
+             .group_by("l_returnflag", "l_linestatus")
+             .agg(f.sum("l_quantity").alias("sum_qty"),
+                  f.avg("l_discount").alias("avg_disc"),
+                  f.count("l_quantity").alias("count_order")))
+
+    agg, = [n for n in _walk(sess.physical_plan(q.plan))
+            if isinstance(n, TpuHashAggregateExec) and n.absorbed]
+    schema = agg.children[0].schema
+    batch = DeviceBatch(schema, [_column(one_chip, fld.dtype, 1)
+                                 for fld in schema],
+                        _shape(one_chip, (), np.int32))
+    text = _compile(agg.kernel_twin().compute_batch, batch).as_text()
+    assert " scatter(" not in text
+    if query == "q6":
+        # a keyless aggregate reads its one segment's end, not every
+        # row's: no gather of a batch's worth of indices is left
+        wide = [ln for ln in text.splitlines()
+                if " gather(" in ln and f"[{ROWS}]" in ln.split("=")[1]
+                .split("gather(")[0]]
+        assert wide == [], wide[:2]
+    # what the standalone filter ran: the scatter is compaction's
+    packed = _compile(compact, batch, _shape(one_chip, (ROWS,), np.bool_))
+    assert " scatter(" in packed.as_text()
 
 
 def test_exchange_trim_and_build_compile_for_v5e(one_chip, as_tpu):
