@@ -1,0 +1,13 @@
+"""Device seconds a query in the phase ``join.expandSearch``
+(``ops/kernels/join.py:expand_pairs``: the ``searchsorted`` that turns
+an output slot into its left row), in any program.  Leaf seconds on the
+busiest device, read from the ops' metadata by the program's own
+``telemetry/device_trace.py`` (``harness/phases.py``).  0.0 where the
+program names no such scope or says nothing of its trace."""
+from benchmark.harness import phases
+
+UNIT, LAYER, MOVES = "s/query", "kernels", "query_s_p50"
+
+
+def reduce(trace, notes):
+    return phases.seconds(trace, "phase", "join.expandSearch")

@@ -127,17 +127,9 @@ class ProfilerGuardRule(Rule):
         out.extend(self.health(
             len(dispatches) >= 1, rel,
             "no record_dispatch site in _CachedKernel.__call__"))
-        # the h2d ceiling is recorded at the upload boundary
-        trans = ctx.resolver.module(common.PKG + "exec/transitions.py")
-        h2d = trans is not None and any(
-            "record_h2d" in fi2.own_call_names
-            for fi2 in trans.functions)
-        out.extend(self.health(
-            h2d, common.PKG + "exec/transitions.py",
-            "no record_h2d site in exec/transitions.py"))
         prof = ctx.resolver.module(common.PKG + "telemetry/profiler.py")
         have = set(prof.by_name) if prof is not None else set()
-        need = {"record_dispatch", "record_h2d", "mark", "since"}
+        need = {"record_dispatch", "mark", "since"}
         out.extend(self.health(
             need <= have, common.PKG + "telemetry/profiler.py",
             f"KernelProfiler API incomplete: missing {sorted(need - have)}"))

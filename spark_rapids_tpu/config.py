@@ -531,10 +531,12 @@ docs/observability.md):
   `# TYPE histogram` families (`Session.metrics_text()`).
 * **Per-kernel profiler** — `telemetry.profiler.enabled` attributes
   every jitted-kernel dispatch to a stable kernel fingerprint
-  (dispatches, wall, rows/bytes, padding waste) and renders a roofline
-  table against the measured host->device ceiling in
-  `Session.profile_report()`; the disabled cost is one attribute read
-  per dispatch (docs/profiling.md).
+  (dispatches, enqueue wall, rows/bytes, padding waste) and renders
+  the `-- Kernel dispatches --` table in `Session.profile_report()`;
+  the disabled cost is one attribute read per dispatch.  A kernel's
+  device seconds and GB/s by phase come from a profiler trace:
+  `Session.profile_report(device_trace=<xplane>)`
+  (docs/profiling.md).
 * **Trace timelines** — `telemetry.trace.dir` exports one
   Chrome-trace/Perfetto JSON per query (span tree as duration tracks,
   HBM watermark as a counter track, ring events as instants), written
@@ -1210,10 +1212,10 @@ TELEMETRY_MAX_EVENTS = conf("spark.rapids.tpu.telemetry.maxEvents").doc(
     "and unbounded").int_conf(4096)
 TELEMETRY_PROFILER_ENABLED = conf(
     "spark.rapids.tpu.telemetry.profiler.enabled").doc(
-    "Per-kernel dispatch profiler: accumulates dispatch count, wall "
-    "time, rows/bytes and shape-bucketing padding waste per kernel "
-    "fingerprint (telemetry/profiler.py), rendered as a roofline table "
-    "in Session.profile_report().  "
+    "Per-kernel dispatch profiler: accumulates dispatch count, enqueue "
+    "wall, rows/bytes and shape-bucketing padding waste per kernel "
+    "fingerprint (telemetry/profiler.py), rendered as the Kernel "
+    "dispatches table in Session.profile_report().  "
     "Independent of telemetry.enabled; the disabled hot-path cost is "
     "one attribute read per dispatch").boolean_conf(False)
 TELEMETRY_TRACE_DIR = conf("spark.rapids.tpu.telemetry.trace.dir").doc(

@@ -32,7 +32,7 @@ from ..ops.expression import BoundReference, as_device_column
 from ..ops.kernels import gather as G
 from ..ops.kernels import segment as seg
 from ..utils import metrics as M
-from ..utils.tracing import trace_range
+from ..utils.tracing import device_phase, trace_range
 from .base import DevicePartitionedData, TpuExec
 from .fused import _member_fingerprint, run_members
 
@@ -47,6 +47,8 @@ class TpuHashAggregateExec(TpuExec):
     runs per batch (aggregate.scala:240-335).  The running result is
     registered with the spill catalog between merges so memory pressure
     can evict it."""
+
+    SPAN = "TpuHashAggregate"
 
     def __init__(self, child, plan, absorbed=()):
         super().__init__([child])
@@ -101,7 +103,9 @@ class TpuHashAggregateExec(TpuExec):
     def prologue(self, batch: DeviceBatch):
         """Raw input through the absorbed members: (the rows as the
         aggregate's expressions read them, the filters' keep mask)."""
-        (out,) = run_members(self.absorbed, batch)  # no Expand: one stream
+        with device_phase("agg.prologue"):
+            # no Expand among them: one stream
+            (out,) = run_members(self.absorbed, batch)
         return out
 
     def compute_batch(self, batch: DeviceBatch) -> DeviceBatch:
@@ -147,10 +151,6 @@ class TpuHashAggregateExec(TpuExec):
         expressions over raw input rows; "merge" treats the batch as
         buffer-form (keys + buffers).  ``emit``: "buffers" outputs the
         grouped buffer form; "final" applies the finalize expressions."""
-        import jax.numpy as jnp
-
-        nkeys = len(self.keys)
-        padded = batch.padded_rows
         rm = batch.row_mask()
         held = None
         if phase == "update" and self.absorbed:
@@ -158,6 +158,19 @@ class TpuHashAggregateExec(TpuExec):
             # row is: invalid in every key and input, sorted last
             batch, keep = self.prologue(batch)
             held, rm = rm, rm & keep
+        # the aggregate's own ops under its name, beside the absorbed
+        # members' (and, in a mesh stage, the other operators')
+        with device_phase(self.SPAN):
+            return self._reduce(batch, phase, emit, rm, held)
+
+    def _reduce(self, batch: DeviceBatch, phase: str, emit: str, rm,
+                held) -> DeviceBatch:
+        """``_compute`` past the prologue: ``rm`` the rows that count,
+        ``held`` the batch's own row mask where a filter was absorbed."""
+        import jax.numpy as jnp
+
+        nkeys = len(self.keys)
+        padded = batch.padded_rows
 
         # ----- keys ----------------------------------------------------
         if phase == "merge":
@@ -385,7 +398,7 @@ class TpuHashAggregateExec(TpuExec):
                     R.maybe_inject_oom("TpuHashAggregate")
                     return self._kernel(b)
 
-                with trace_range("TpuHashAggregate",
+                with trace_range(self.SPAN,
                                  self.metrics[M.TOTAL_TIME]):
                     if second is None:
                         try:

@@ -103,9 +103,18 @@ class _SchemaStub:
 class TpuExec(PhysicalPlan):
     """Base of all device operators."""
 
+    #: the operator's name on both clocks: its host span
+    #: (``trace_range``) and its scope inside a program composed of
+    #: several operators' bodies (``device_phase``); None: the class's
+    SPAN: Optional[str] = None
+
     def __init__(self, children: Sequence[PhysicalPlan] = ()):  # noqa
         super().__init__(children)
         self.metrics = {}
+
+    @property
+    def span_name(self) -> str:
+        return self.SPAN or type(self).__name__
 
     # standard metric names (reference: GpuMetricNames)
     def _init_metrics(self, ctx: ExecContext):

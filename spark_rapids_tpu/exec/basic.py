@@ -20,6 +20,8 @@ from .kernel_cache import expr_signature, jit_kernel, schema_signature
 
 
 class TpuProjectExec(TpuExec):
+    SPAN = "TpuProject"
+
     def __init__(self, child, exprs: List[Expression],
                  schema: T.Schema = None):
         super().__init__([child])
@@ -54,7 +56,7 @@ class TpuProjectExec(TpuExec):
         def make(pid):
             def it():
                 for db in child.iterator(pid):
-                    with trace_range("TpuProject",
+                    with trace_range(self.SPAN,
                                      self.metrics[M.TOTAL_TIME]):
                         out = self._kernel(db, metrics=self.metrics)
                     self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
@@ -70,6 +72,8 @@ class TpuProjectExec(TpuExec):
 
 
 class TpuFilterExec(TpuExec):
+    SPAN = "TpuFilter"
+
     def __init__(self, child, condition: Expression):
         super().__init__([child])
         self.condition = bind_references(condition, child.schema)
@@ -104,7 +108,7 @@ class TpuFilterExec(TpuExec):
         def make(pid):
             def it():
                 for db in child.iterator(pid):
-                    with trace_range("TpuFilter",
+                    with trace_range(self.SPAN,
                                      self.metrics[M.TOTAL_TIME]):
                         out = self._kernel(db, metrics=self.metrics)
                     self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
@@ -192,6 +196,8 @@ class TpuExpandExec(TpuExec):
     """Reference analogue: GpuExpandExec — one projected batch per
     projection list per input batch."""
 
+    SPAN = "TpuExpand"
+
     def __init__(self, child, projections: List[List[Expression]],
                  output_names: List[str]):
         super().__init__([child])
@@ -244,7 +250,7 @@ class TpuExpandExec(TpuExec):
             def it():
                 for db in child.iterator(pid):
                     for k in self._kernels:
-                        with trace_range("TpuExpand",
+                        with trace_range(self.SPAN,
                                          self.metrics[M.TOTAL_TIME]):
                             out = k(db, metrics=self.metrics)
                         yield out

@@ -43,7 +43,7 @@ from ..shuffle.partitioning import (HashPartitioning, RangePartitioning,
                                     SinglePartitioning)
 from ..utils import hashing
 from ..utils import metrics as M
-from ..utils.tracing import trace_range
+from ..utils.tracing import device_phase, trace_range
 from .base import DevicePartitionedData, TpuExec
 
 #: string keys are truncated to this byte prefix for range PLACEMENT
@@ -142,6 +142,7 @@ def _free_shuffle_buffers(fw, store, spill_listener=None,
             pass
 
 
+@device_phase("shuffle.hashPids")
 def _hash_pids(bound, n_out, batch: DeviceBatch):
     import jax.numpy as jnp
 
@@ -169,6 +170,8 @@ def _sample(passes, nr):
 
 
 class TpuShuffleExchangeExec(TpuExec):
+    SPAN = "TpuShuffleWrite"
+
     def __init__(self, child, plan):
         super().__init__([child])
         self.plan = plan  # physical.ShuffleExchangeExec
@@ -479,7 +482,7 @@ class TpuShuffleExchangeExec(TpuExec):
 
             added = []  # every buffer this ATTEMPT registered
             try:
-                with trace_range("TpuShuffleWrite",
+                with trace_range(self.SPAN,
                                  self.metrics[M.TOTAL_TIME]):
                     for pid in range(child.n_partitions):
                         for b in child.iterator(pid):

@@ -36,7 +36,7 @@ from typing import List
 from ..data.column import DeviceBatch
 from ..ops.kernels.gather import compact
 from ..utils import metrics as M
-from ..utils.tracing import trace_range
+from ..utils.tracing import device_phase, trace_range
 from .base import DevicePartitionedData, TpuExec
 from .basic import TpuExpandExec, TpuFilterExec, TpuProjectExec
 from .generate import TpuGenerateExec
@@ -83,10 +83,12 @@ def run_members(members, batch: DeviceBatch):
     every filter's compaction deferred: the surviving (batch, keep-mask)
     streams.  The fused segment compacts each at its exit; an aggregate
     that absorbed the chain (exec/aggregate.py) reads the mask and
-    compacts nothing."""
+    compacts nothing.  Each member's ops stand under its own name, as
+    its host span has it when it runs alone."""
     streams = [(batch, None)]
     for m in members:
-        streams = _apply_member(m, streams)
+        with device_phase(m.span_name):
+            streams = _apply_member(m, streams)
     return streams
 
 
@@ -95,6 +97,8 @@ class TpuFusedSegmentExec(TpuExec):
 
     ``members`` is in execution order (closest-to-source first);
     ``child`` is the segment input (the bottom member's child)."""
+
+    SPAN = "TpuFusedSegment"
 
     def __init__(self, members: List[TpuExec], child, donate: bool = False):
         super().__init__([child])
@@ -133,8 +137,10 @@ class TpuFusedSegmentExec(TpuExec):
     def _compute(self, batch: DeviceBatch):
         # ONE compaction per surviving stream at segment exit — the
         # deferred form of each member filter's compact()
-        return tuple(b if keep is None else compact(b, keep)
-                     for b, keep in run_members(self.members, batch))
+        streams = run_members(self.members, batch)
+        with device_phase(self.SPAN):
+            return tuple(b if keep is None else compact(b, keep)
+                         for b, keep in streams)
 
     # ---------------- execution ----------------------------------------
     def execute_columnar(self, ctx):
@@ -144,7 +150,7 @@ class TpuFusedSegmentExec(TpuExec):
         def make(pid):
             def it():
                 for db in child.iterator(pid):
-                    with trace_range("TpuFusedSegment",
+                    with trace_range(self.SPAN,
                                      self.metrics[M.TOTAL_TIME]):
                         outs = self._kernel(db, metrics=self.metrics)
                     for out in outs:

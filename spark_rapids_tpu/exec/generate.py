@@ -23,6 +23,8 @@ from .kernel_cache import expr_signature, jit_kernel, schema_signature
 
 
 class TpuGenerateExec(TpuExec):
+    SPAN = "TpuGenerate"
+
     def __init__(self, child, plan):
         super().__init__([child])
         self.elements: List[Expression] = [
@@ -94,7 +96,7 @@ class TpuGenerateExec(TpuExec):
         def make(pid):
             def it():
                 for db in child.iterator(pid):
-                    with trace_range("TpuGenerate",
+                    with trace_range(self.SPAN,
                                      self.metrics[M.TOTAL_TIME]):
                         out = self._kernel(db, metrics=self.metrics)
                     self.metrics[M.NUM_OUTPUT_ROWS].add(int(out.num_rows))

@@ -117,7 +117,8 @@ class _CachedKernel:
     attributed to the dispatching exec's ``compileTime`` metric.
     """
 
-    __slots__ = ("_cache", "fn", "_jfn", "donated", "fingerprint", "name")
+    __slots__ = ("_cache", "fn", "_jfn", "donated", "fingerprint", "name",
+                 "static_argnums")
 
     def __init__(self, cache: "KernelCache", fn: Callable,
                  static_argnums: Tuple[int, ...],
@@ -128,6 +129,7 @@ class _CachedKernel:
 
         self._cache = cache
         self.fn = fn  # the raw traceable body (runner/fusion reuse it)
+        self.static_argnums = tuple(static_argnums or ())
         self.fingerprint = fingerprint or kernel_fingerprint(None, fn)
         self.donated = bool(donate_argnums) and cache.donation_active()
         kwargs = {}

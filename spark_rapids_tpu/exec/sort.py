@@ -47,6 +47,8 @@ class _Tile:
 
 
 class TpuSortExec(TpuExec):
+    SPAN = "TpuSort"
+
     def __init__(self, child, keys):
         super().__init__([child])
         self.keys = keys  # List[functions.SortKey], exprs already bound
@@ -256,11 +258,11 @@ class TpuSortExec(TpuExec):
                     # the out-of-core merge streams, so it runs after
                     # the range below has closed: each of its steps
                     # gets a range of its own, closed at the hand-over
-                    return trace_steps("TpuSort",
+                    return trace_steps(self.SPAN,
                                        self._sort_chunked(runs, rctx),
                                        self.metrics[M.TOTAL_TIME])
 
-                with trace_range("TpuSort",
+                with trace_range(self.SPAN,
                                  self.metrics[M.TOTAL_TIME]):
                     if second is None:
                         try:

@@ -15,7 +15,6 @@ from ..config import (BUCKET_MIN_ROWS, FAULT_QUEUE_PUT_TIMEOUT_MS,
 from ..fault.errors import TpuPayloadCorruption, TpuStageTimeout
 from ..memory import retry as R
 from ..plan.physical import PartitionedData
-from ..telemetry.profiler import PROFILER as _PROFILER
 from ..utils import metrics as M
 from ..utils.tracing import trace_range
 from .base import DevicePartitionedData, TpuExec
@@ -207,8 +206,6 @@ class HostToDeviceExec(TpuExec):
             sync = self.metrics.get(M.DEVICE_SYNC_TIME)
             if sync is not None:  # registered only under telemetry
                 sync.add(dt)
-            if _PROFILER.enabled:  # h2d ceiling for the kernel roofline
-                _PROFILER.record_h2d(hb.estimate_bytes(), dt)
             self.metrics[M.NUM_OUTPUT_ROWS].add(hb.num_rows)
             self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
             matrix_bytes.add(sum(c.data.size for c in db.columns

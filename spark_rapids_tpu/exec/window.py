@@ -77,6 +77,8 @@ def _seg_scan(comb_val, vals, seg_ids, reverse=False):
 
 
 class TpuWindowExec(TpuExec):
+    SPAN = "TpuWindow"
+
     def __init__(self, child, plan):
         super().__init__([child])
         self.plan = plan  # window_cpu.WindowExec (exprs already bound)
@@ -315,7 +317,7 @@ class TpuWindowExec(TpuExec):
 
                 batch = concat_device_batches(batches) \
                     if len(batches) > 1 else batches[0]
-                with trace_range("TpuWindow",
+                with trace_range(self.SPAN,
                                  self.metrics[M.TOTAL_TIME]):
                     out = self._kernel(batch)
                 self.metrics[M.NUM_OUTPUT_BATCHES].add(1)

@@ -11,16 +11,18 @@ from __future__ import annotations
 from typing import Optional
 
 from ...data.column import DeviceBatch, DeviceColumn
+from ...utils.tracing import device_phase
 
 
 def gather_column(col: DeviceColumn, order, valid_mask=None) -> DeviceColumn:
     """Permute one column by ``order`` (int32[n]); optionally AND the
     permuted validity with ``valid_mask`` (already in output order)."""
-    data = col.data[order]
-    validity = col.validity[order]
-    if valid_mask is not None:
-        validity = validity & valid_mask
-    lengths = col.lengths[order] if col.lengths is not None else None
+    with device_phase("reorder"):
+        data = col.data[order]
+        validity = col.validity[order]
+        if valid_mask is not None:
+            validity = validity & valid_mask
+        lengths = col.lengths[order] if col.lengths is not None else None
     return DeviceColumn(col.dtype, data, validity, lengths)
 
 
@@ -53,6 +55,7 @@ def prefix_sum(x):
     return (inner + (prefix_sum(totals) - totals)[..., None]).reshape(x.shape)
 
 
+@device_phase("gather.partitionOrder")
 def partition_order(first):
     """int32 permutation moving the rows where ``first`` (bool[n]) is
     True to the front, both groups in their original order — what a

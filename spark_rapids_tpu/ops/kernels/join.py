@@ -29,6 +29,7 @@ from __future__ import annotations
 from typing import List, NamedTuple
 
 from ...data.column import DeviceColumn
+from ...utils.tracing import device_phase
 from . import segment as seg
 from .gather import partition_order, prefix_sum
 
@@ -63,6 +64,7 @@ class Probe(NamedTuple):
     has_r: object    # bool[Nr] right row has a left match
 
 
+@device_phase("join.probe")
 def probe(l_keys: List[DeviceColumn], r_keys: List[DeviceColumn],
           l_ok, r_ok) -> Probe:
     """Every left row's run of matches, read off ONE sort of both sides'
@@ -104,7 +106,8 @@ def probe(l_keys: List[DeviceColumn], r_keys: List[DeviceColumn],
     # theirs); a string's length rides along, as its bytes are padded
     rows = words + [c.lengths.astype(jnp.uint32) for c in combined
                     if c.lengths is not None]
-    keys_s = jnp.stack(rows)[:, order]
+    with device_phase("reorder"):
+        keys_s = jnp.stack(rows)[:, order]
     pos = jnp.arange(n, dtype=jnp.int32)
     ok_s = pos < ok.sum(dtype=jnp.int32)
     change = ~ok_s | jnp.concatenate(
@@ -131,6 +134,7 @@ def probe(l_keys: List[DeviceColumn], r_keys: List[DeviceColumn],
                  mine[nl:] > 0)
 
 
+@device_phase("join.emitCounts")
 def emit_counts(p: Probe, how: str, l_rm, r_rm):
     """Per-left-row emit counts + unmatched-right mask + total rows.
 
@@ -152,6 +156,7 @@ def emit_counts(p: Probe, how: str, l_rm, r_rm):
     return emit, r_extra, total
 
 
+@device_phase("join.expandGather")
 def expand_pairs(p: Probe, emit, r_extra, c_out: int):
     """Turn slot t in [0, c_out) into its (lidx, ridx) pair; -1 marks
     the null-extended side.  Returns (lidx, ridx, slot_valid)."""
@@ -162,7 +167,8 @@ def expand_pairs(p: Probe, emit, r_extra, c_out: int):
     offs = prefix_sum(emit)                      # inclusive
     m_left = offs[-1]
     t = jnp.arange(c_out, dtype=jnp.int64)
-    li = jnp.searchsorted(offs, t, side="right").astype(jnp.int32)
+    with device_phase("join.expandSearch"):
+        li = jnp.searchsorted(offs, t, side="right").astype(jnp.int32)
     li_safe = jnp.clip(li, 0, nl - 1)
     prev = offs[li_safe] - emit[li_safe]         # exclusive prefix
     k = (t - prev).astype(jnp.int32)
@@ -184,6 +190,7 @@ def expand_pairs(p: Probe, emit, r_extra, c_out: int):
     return lidx, ridx, slot_valid
 
 
+@device_phase("reorder")
 def gather_side(columns: List[DeviceColumn], idx, slot_valid
                 ) -> List[DeviceColumn]:
     """Gather one side's columns by row index; idx -1 → null."""

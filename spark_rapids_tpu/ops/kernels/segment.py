@@ -22,6 +22,7 @@ import numpy as np
 
 from ... import types as T
 from ...data.column import DeviceColumn, HostColumn
+from ...utils.tracing import device_phase
 from .gather import _SCAN_BLOCK, prefix_sum
 
 # ---------------------------------------------------------------------------
@@ -404,18 +405,20 @@ def sort_permutation(words, n: int):
         return lax.sort((key, perm), dimension=0, is_stable=True,
                         num_keys=1)[1]
 
-    # the least significant word sorts outside the loop: under
-    # shard_map the carry must start as shard-varying as it ends
-    order = sort_by(words[-1], iota)
-    if len(words) == 1:
-        return order
-    stacked = jnp.stack(words[:-1])
-    last = len(words) - 2
-    return lax.fori_loop(
-        0, len(words) - 1,
-        lambda i, perm: sort_by(stacked[last - i][perm], perm), order)
+    with device_phase("lexsort"):
+        # the least significant word sorts outside the loop: under
+        # shard_map the carry must start as shard-varying as it ends
+        order = sort_by(words[-1], iota)
+        if len(words) == 1:
+            return order
+        stacked = jnp.stack(words[:-1])
+        last = len(words) - 2
+        return lax.fori_loop(
+            0, len(words) - 1,
+            lambda i, perm: sort_by(stacked[last - i][perm], perm), order)
 
 
+@device_phase("segments")
 def segment_change_device(sorted_keys: List[DeviceColumn], pad_valid=None):
     """Given key columns already in sorted order, the bool flags of the
     rows that start a segment: row 0, every key change, and every
@@ -465,9 +468,11 @@ def segment_ids_device(sorted_keys: List[DeviceColumn], pad_valid=None):
     import jax.numpy as jnp
 
     change = segment_change_device(sorted_keys, pad_valid)
-    return prefix_sum(change.astype(jnp.int32)) - 1
+    with device_phase("segments"):
+        return prefix_sum(change.astype(jnp.int32)) - 1
 
 
+@device_phase("segments")
 def segmented_scan(x, change, op):
     """Inclusive scan of ``x`` ([k, n]) along its rows by the
     associative ``op``, restarting at every row ``change`` (bool[n])
@@ -519,6 +524,7 @@ _SCAN_OPS = {"sum": "add", "min": "minimum", "max": "maximum",
              "first": "minimum", "last": "maximum"}
 
 
+@device_phase("segments")
 def reduce_sorted(change, order, specs, segments=None):
     """Per-segment reductions of a batch whose rows ``order`` (an int32
     permutation from a STABLE sort; None: as they stand) brings into
@@ -560,7 +566,10 @@ def reduce_sorted(change, order, specs, segments=None):
     m = ends.shape[0]
 
     def in_order(stack):
-        return stack if order is None else stack[:, order]
+        if order is None:
+            return stack
+        with device_phase("reorder"):
+            return stack[:, order]
 
     # ----- what each spec has the scans and the counts do --------------
     stacks, flags, slots = {}, [], []

@@ -27,8 +27,10 @@ from typing import List, Tuple
 from ..data.column import DeviceBatch, DeviceColumn
 from ..ops.kernels.gather import partition_order
 from ..utils import hashing
+from ..utils.tracing import device_phase
 
 
+@device_phase("shuffle.hashPids")
 def device_partition_ids(batch: DeviceBatch, key_indices, num_parts: int):
     """Spark-compatible murmur3 pmod partition ids on device; rows past
     ``num_rows`` get id ``num_parts`` (a sentinel the bucketer drops).
@@ -45,6 +47,7 @@ def device_partition_ids(batch: DeviceBatch, key_indices, num_parts: int):
     return jnp.where(batch.row_mask(), pid, num_parts)
 
 
+@device_phase("shuffle.packedBuild")
 def bucket_rows(pids, num_parts: int, capacity: int):
     """Pack row indices into per-destination tiles.
 
@@ -72,6 +75,7 @@ def bucket_rows(pids, num_parts: int, capacity: int):
     return rows, valid
 
 
+@device_phase("shuffle.packedBuild")
 def _gather_tiles(batch: DeviceBatch, rows, valid) -> List[DeviceColumn]:
     """Gather every column into [P, C, ...] tiles; validity AND'd with
     the lane mask."""
@@ -109,11 +113,12 @@ def _compact(batch_cols: List[DeviceColumn], present, schema) -> DeviceBatch:
     order = partition_order(present)
     num_rows = present.sum().astype(jnp.int32)
     out = []
-    for c in batch_cols:
-        data = c.data[order]
-        validity = c.validity[order] & present[order]
-        lengths = c.lengths[order] if c.lengths is not None else None
-        out.append(DeviceColumn(c.dtype, data, validity, lengths))
+    with device_phase("reorder"):
+        for c in batch_cols:
+            data = c.data[order]
+            validity = c.validity[order] & present[order]
+            lengths = c.lengths[order] if c.lengths is not None else None
+            out.append(DeviceColumn(c.dtype, data, validity, lengths))
     return DeviceBatch(schema, out, num_rows)
 
 

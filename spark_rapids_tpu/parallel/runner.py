@@ -52,7 +52,7 @@ from ..memory.semaphore import DeviceSemaphoreTimeout
 from ..telemetry import spans as tspans
 from ..telemetry.events import emit_event
 from ..utils import hashing
-from ..utils.tracing import trace_range
+from ..utils.tracing import device_phase, trace_range
 from . import exchange as X
 from .mesh import DATA_AXIS
 
@@ -707,6 +707,7 @@ class DistributedRunner:
         pids = jnp.where(batch.row_mask(), 0, self.n)
         return self.transport.exchange(batch, pids, self.n)
 
+    @device_phase("shuffle.hashPids")
     def _hash_pids_by_exprs(self, batch: DeviceBatch, exprs, schema):
         """Hash partition ids on expression keys — the one place the
         runner hashes, for the planned exchanges and for the ones it
@@ -914,6 +915,34 @@ class DistributedRunner:
         (``_detach``): returns the (traced) output batch of ``node``
         given the program's inputs in ``env``, by slot.  Capacities are
         named by their operator's place in the tree."""
+        from ..exec.aggregate import TpuHashAggregateExec
+        from ..exec.coalesce import TpuCoalesceBatchesExec
+        from ..exec.fused import TpuFusedSegmentExec
+
+        if isinstance(node, _InputRef):
+            return env[node.slot]
+        if isinstance(node, tuple):
+            op, *kids = node
+            # children first and outside the operator's scope: an op's
+            # outermost ``Tpu...`` scope names the operator it is part of
+            inputs = [self._lower(k, env, aux, caps, used_caps)
+                      for k in kids]
+            if isinstance(op, (TpuHashAggregateExec, TpuFusedSegmentExec,
+                               TpuCoalesceBatchesExec)):
+                # these name their own bodies (and their members'); a
+                # coalesce has none
+                return self._lower_op(op, kids, inputs, aux, caps,
+                                      used_caps)
+            with device_phase(op.span_name):
+                return self._lower_op(op, kids, inputs, aux, caps,
+                                      used_caps)
+        raise DistributedUnsupported(f"cannot lower {node!r}")
+
+    def _lower_op(self, op, kids, inputs, aux: Dict, caps: Dict,
+                  used_caps: Dict) -> DeviceBatch:
+        """One operator of a stage tree over its lowered ``inputs``
+        (``kids``: the subtrees they came from, for what they say of
+        partitioning)."""
         import jax.numpy as jnp
 
         from ..exec import basic as B
@@ -927,119 +956,116 @@ class DistributedRunner:
         from ..exec.sort import TpuSortExec
         from ..exec.window import TpuWindowExec
 
-        if isinstance(node, _InputRef):
-            return env[node.slot]
-        if isinstance(node, tuple):
-            op, *kids = node
-            if isinstance(op, TpuShuffleExchangeExec):
-                from ..shuffle.partitioning import SinglePartitioning
+        if isinstance(op, TpuShuffleExchangeExec):
+            from ..shuffle.partitioning import SinglePartitioning
 
-                body = self._lower(kids[0], env, aux, caps, used_caps)
-                pids = self._exchange_pids(op, body)
-                if isinstance(op.partitioning, SinglePartitioning):
-                    # gather-to-one genuinely needs P x capacity
-                    return self.transport.exchange(body, pids, self.n)
-                # cap the per-destination tile so exchange output stops
-                # inflating padded size P-fold (Weak #3): start at ~2x
-                # the even share, detect overflow, retry bigger
-                return self._capped_exchange(body, pids, f"exch{op.place}",
-                                             aux, caps, used_caps)
-            if isinstance(op, (TpuCoalesceBatchesExec,)):
-                return self._lower(kids[0], env, aux, caps, used_caps)
-            if isinstance(op, TpuHashJoinExec):
-                lb = self._lower(kids[0], env, aux, caps, used_caps)
-                # a broadcast join's build side is an input: the
-                # replicated batch _prepare_broadcasts gathered
-                rb = self._lower(kids[1], env, aux, caps, used_caps)
-                if not isinstance(op, TpuBroadcastHashJoinExec):
-                    # colocation is a correctness invariant, not a
-                    # planner courtesy: verify both sides arrive
-                    # hash-partitioned on the join keys (or single)
-                    verdict = self._join_colocation(op, kids[0], kids[1])
-                    if verdict == "repair":
-                        # hash re-exchange both sides on the join keys
-                        # (capped, so padded size doesn't inflate
-                        # P-fold)
-                        lb = self._capped_exchange(
-                            lb, self._hash_pids_by_exprs(
-                                lb, op.plan.left_keys,
-                                op.children[0].schema),
-                            f"jexl{op.place}", aux, caps, used_caps)
-                        rb = self._capped_exchange(
-                            rb, self._hash_pids_by_exprs(
-                                rb, op.plan.right_keys,
-                                op.children[1].schema),
-                            f"jexr{op.place}", aux, caps, used_caps)
-                    elif verdict == "unsupported":
-                        raise DistributedUnsupported(
-                            "shuffled join children are not colocated "
-                            "on the join keys — plan shape would "
-                            "produce wrong rows")
-                key = f"join{op.place}"
-                cap = caps.get(key)
-                if cap is None:
-                    cap = bucket_rows(
-                        lb.padded_rows + rb.padded_rows, self.min_bucket)
-                used_caps[key] = cap
-                out, total = op.join_static(lb, rb, cap)
-                aux[key] = total
-                return out
-            if isinstance(op, (B.TpuExpandExec,)):
-                child = self._lower(kids[0], env, aux, caps, used_caps)
-                # raw bodies: the enclosing shard_map trace must not
-                # nest the locally-jitted (and cache-counted) kernels
-                pieces = [fn(child) for fn in op._kernel_fns]
-                return self._concat_compact(pieces, op.schema)
-            if isinstance(op, B.TpuUnionExec):
-                pieces = [self._lower(k, env, aux, caps, used_caps)
-                          for k in kids]
-                return self._concat_compact(pieces, op.schema)
-            if isinstance(op, B.TpuLocalLimitExec):
-                child = self._lower(kids[0], env, aux, caps, used_caps)
-                if isinstance(op, B.TpuGlobalLimitExec) and \
-                        not self._is_single(
-                            self._source_partitioning(kids[0])):
-                    child = self._gather_single(child)
-                keep = jnp.minimum(child.num_rows,
-                                   jnp.asarray(op.n, dtype=jnp.int32))
-                mask = jnp.arange(child.padded_rows,
-                                  dtype=jnp.int32) < keep
-                cols = [DeviceColumn(c.dtype, c.data, c.validity & mask,
-                                     c.lengths) for c in child.columns]
-                return DeviceBatch(child.schema, cols, keep)
-            if isinstance(op, TpuSortExec):
-                # distributed sort: range-exchange rows by sampled key
-                # bounds so shard i's rows all order before shard i+1's,
-                # then sort each shard locally — no gather-to-one-shard
-                # bottleneck (reference: GpuRangePartitioning + per-task
-                # sort under Spark's range exchange)
-                child = self._lower(kids[0], env, aux, caps, used_caps)
-                if not self._sort_presorted(kids[0], op):
-                    pids = self._range_pids(child, op.keys)
-                    child = self._capped_exchange(
-                        child, pids, f"rexch{op.place}", aux, caps,
-                        used_caps)
-                return op._compute(child)
-            if isinstance(op, TpuWindowExec):
-                child = self._lower(kids[0], env, aux, caps, used_caps)
-                specs = [w.spec for w in op.window_exprs]
-                keys = specs[0].partition_by if specs else []
-                same = all([k.sql() for k in s.partition_by]
-                           == [k.sql() for k in keys] for s in specs)
-                part = self._source_partitioning(kids[0])
-                if keys and same:
-                    if not self._hash_keys_match(part, keys) and \
-                            not self._is_single(part):
-                        child = self._exchange_by_exprs(
-                            child, keys, op.children[0].schema)
-                elif not self._is_single(part):
-                    child = self._gather_single(child)
-                return op._compute(child)
-            if isinstance(op, TpuHashAggregateExec):
-                child = self._lower(kids[0], env, aux, caps, used_caps)
-                if op.mode == "complete" and not self._is_single(
-                        part := self._source_partitioning(kids[0])):
-                    # single-phase agg: groups must be colocated first
+            body = inputs[0]
+            pids = self._exchange_pids(op, body)
+            if isinstance(op.partitioning, SinglePartitioning):
+                # gather-to-one genuinely needs P x capacity
+                return self.transport.exchange(body, pids, self.n)
+            # cap the per-destination tile so exchange output stops
+            # inflating padded size P-fold (Weak #3): start at ~2x
+            # the even share, detect overflow, retry bigger
+            return self._capped_exchange(body, pids, f"exch{op.place}",
+                                         aux, caps, used_caps)
+        if isinstance(op, (TpuCoalesceBatchesExec,)):
+            return inputs[0]
+        if isinstance(op, TpuHashJoinExec):
+            lb = inputs[0]
+            # a broadcast join's build side is an input: the
+            # replicated batch _prepare_broadcasts gathered
+            rb = inputs[1]
+            if not isinstance(op, TpuBroadcastHashJoinExec):
+                # colocation is a correctness invariant, not a
+                # planner courtesy: verify both sides arrive
+                # hash-partitioned on the join keys (or single)
+                verdict = self._join_colocation(op, kids[0], kids[1])
+                if verdict == "repair":
+                    # hash re-exchange both sides on the join keys
+                    # (capped, so padded size doesn't inflate
+                    # P-fold)
+                    lb = self._capped_exchange(
+                        lb, self._hash_pids_by_exprs(
+                            lb, op.plan.left_keys,
+                            op.children[0].schema),
+                        f"jexl{op.place}", aux, caps, used_caps)
+                    rb = self._capped_exchange(
+                        rb, self._hash_pids_by_exprs(
+                            rb, op.plan.right_keys,
+                            op.children[1].schema),
+                        f"jexr{op.place}", aux, caps, used_caps)
+                elif verdict == "unsupported":
+                    raise DistributedUnsupported(
+                        "shuffled join children are not colocated "
+                        "on the join keys — plan shape would "
+                        "produce wrong rows")
+            key = f"join{op.place}"
+            cap = caps.get(key)
+            if cap is None:
+                cap = bucket_rows(
+                    lb.padded_rows + rb.padded_rows, self.min_bucket)
+            used_caps[key] = cap
+            out, total = op.join_static(lb, rb, cap)
+            aux[key] = total
+            return out
+        if isinstance(op, (B.TpuExpandExec,)):
+            child = inputs[0]
+            # raw bodies: the enclosing shard_map trace must not
+            # nest the locally-jitted (and cache-counted) kernels
+            pieces = [fn(child) for fn in op._kernel_fns]
+            return self._concat_compact(pieces, op.schema)
+        if isinstance(op, B.TpuUnionExec):
+            pieces = inputs
+            return self._concat_compact(pieces, op.schema)
+        if isinstance(op, B.TpuLocalLimitExec):
+            child = inputs[0]
+            if isinstance(op, B.TpuGlobalLimitExec) and \
+                    not self._is_single(
+                        self._source_partitioning(kids[0])):
+                child = self._gather_single(child)
+            keep = jnp.minimum(child.num_rows,
+                               jnp.asarray(op.n, dtype=jnp.int32))
+            mask = jnp.arange(child.padded_rows,
+                              dtype=jnp.int32) < keep
+            cols = [DeviceColumn(c.dtype, c.data, c.validity & mask,
+                                 c.lengths) for c in child.columns]
+            return DeviceBatch(child.schema, cols, keep)
+        if isinstance(op, TpuSortExec):
+            # distributed sort: range-exchange rows by sampled key
+            # bounds so shard i's rows all order before shard i+1's,
+            # then sort each shard locally — no gather-to-one-shard
+            # bottleneck (reference: GpuRangePartitioning + per-task
+            # sort under Spark's range exchange)
+            child = inputs[0]
+            if not self._sort_presorted(kids[0], op):
+                pids = self._range_pids(child, op.keys)
+                child = self._capped_exchange(
+                    child, pids, f"rexch{op.place}", aux, caps,
+                    used_caps)
+            return op._compute(child)
+        if isinstance(op, TpuWindowExec):
+            child = inputs[0]
+            specs = [w.spec for w in op.window_exprs]
+            keys = specs[0].partition_by if specs else []
+            same = all([k.sql() for k in s.partition_by]
+                       == [k.sql() for k in keys] for s in specs)
+            part = self._source_partitioning(kids[0])
+            if keys and same:
+                if not self._hash_keys_match(part, keys) and \
+                        not self._is_single(part):
+                    child = self._exchange_by_exprs(
+                        child, keys, op.children[0].schema)
+            elif not self._is_single(part):
+                child = self._gather_single(child)
+            return op._compute(child)
+        if isinstance(op, TpuHashAggregateExec):
+            child = inputs[0]
+            if op.mode == "complete" and not self._is_single(
+                    part := self._source_partitioning(kids[0])):
+                # single-phase agg: groups must be colocated first (an
+                # exchange the plan has no node for)
+                with device_phase(TpuShuffleExchangeExec.SPAN):
                     if not op.keys:
                         child = self._gather_single(child)
                     elif op.absorbed:
@@ -1055,21 +1081,21 @@ class DistributedRunner:
                     elif not self._hash_keys_match(part, op.keys):
                         child = self._exchange_by_exprs(
                             child, op.keys, op.children[0].schema)
-                # compute_batch carries an absorbed chain as its prologue
-                return op.compute_batch(child)
-            if isinstance(op, (B.TpuProjectExec, B.TpuFilterExec,
-                               TpuGenerateExec)):
-                child = self._lower(kids[0], env, aux, caps, used_caps)
-                return op._compute(child)
-            if isinstance(op, TpuFusedSegmentExec):
-                child = self._lower(kids[0], env, aux, caps, used_caps)
-                # same composed body the local jitted segment runs;
-                # expand members fan out into multiple streams
-                pieces = list(op._compute(child))
-                if len(pieces) == 1:
-                    return pieces[0]
-                return self._concat_compact(pieces, op.schema)
-        raise DistributedUnsupported(f"cannot lower {node!r}")
+            # compute_batch carries an absorbed chain as its prologue
+            return op.compute_batch(child)
+        if isinstance(op, (B.TpuProjectExec, B.TpuFilterExec,
+                           TpuGenerateExec)):
+            child = inputs[0]
+            return op._compute(child)
+        if isinstance(op, TpuFusedSegmentExec):
+            child = inputs[0]
+            # same composed body the local jitted segment runs;
+            # expand members fan out into multiple streams
+            pieces = list(op._compute(child))
+            if len(pieces) == 1:
+                return pieces[0]
+            return self._concat_compact(pieces, op.schema)
+        raise DistributedUnsupported(f"cannot lower {op!r}")
 
     @staticmethod
     def _env_key(ref) -> str:

@@ -63,9 +63,8 @@ class Session:
         self.last_profile = None
         #: per-kernel profiler delta of the most recent execution
         #: ({fingerprint -> telemetry.profiler.KernelStat}; None unless
-        #: telemetry.profiler.enabled) + the observed h2d ceiling
+        #: telemetry.profiler.enabled)
         self.last_kernel_profile = None
-        self.last_h2d_ceiling_bps = 0.0
         from collections import deque as _deque
 
         from .config import TELEMETRY_MAX_QUERY_PROFILES
@@ -429,13 +428,12 @@ class Session:
             from .telemetry.profiler import PROFILER as _profiler
 
             if _profiler.enabled:
-                # the per-kernel roofline delta of THIS query; the
+                # the per-kernel dispatch counts of THIS query; the
                 # handle/profile read it because last_kernel_profile is
                 # last-writer-wins shared state (like last_metrics)
                 ctx.kernel_profile = _profiler.since(
                     getattr(ctx, "kernel_profiler_mark", None))
                 self.last_kernel_profile = ctx.kernel_profile
-                self.last_h2d_ceiling_bps = _profiler.h2d_ceiling_bps()
             fsum = fault_summary(merged)
             if fsum:
                 log.warning(
@@ -463,9 +461,8 @@ class Session:
         if ctx.profile is not None:
             kstats = getattr(ctx, "kernel_profile", None)
             if kstats:
-                # the profile renders its own roofline section
+                # the profile renders its own dispatches section
                 ctx.profile.kernel_stats = kstats
-                ctx.profile.h2d_ceiling_bps = self.last_h2d_ceiling_bps
             from .config import TELEMETRY_TRACE_DIR
 
             trace_dir = self.conf.get(TELEMETRY_TRACE_DIR)
@@ -855,14 +852,21 @@ class Session:
         ``telemetry.maxQueryProfiles``)."""
         return list(self._profiles)
 
-    def profile_report(self, top_n: int = 5) -> str:
+    def profile_report(self, top_n: int = 5,
+                       device_trace: Optional[str] = None) -> str:
         """EXPLAIN-ANALYZE report of the most recent execution: the
         physical plan annotated with per-exec metrics, the span tree, a
         top-N hot-operator summary and the event digest.  Empty string
-        unless ``telemetry.enabled`` was on for the query."""
+        unless ``telemetry.enabled`` was on for the query.
+
+        ``device_trace``: an ``.xplane.pb[.gz]`` a ``jax.profiler``
+        session around the query wrote; the report then ends in a
+        ``-- Device phases --`` section, the device's seconds and GB/s
+        by program and phase (``telemetry/device_trace.py``)."""
         if self.last_profile is None:
             return ""
-        return self.last_profile.render(top_n=top_n)
+        return self.last_profile.render(top_n=top_n,
+                                        device_trace=device_trace)
 
     def export_metrics(self) -> Dict:
         """One combined metrics dict for the exporters: the last

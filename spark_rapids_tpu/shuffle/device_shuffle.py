@@ -39,6 +39,7 @@ from typing import Dict
 
 from ..data.column import DeviceBatch, DeviceColumn
 from ..ops.kernels.gather import gather_batch, gather_column
+from ..utils.tracing import device_phase
 
 
 # ==========================================================================
@@ -102,6 +103,7 @@ def collective_timer():
 # packed partition block: build + slice kernel bodies (module level so
 # the kernel-cache key — not a per-exec closure — owns the compilation)
 # ==========================================================================
+@device_phase("shuffle.packedBuild")
 def packed_build(batch: DeviceBatch, pids, n_out: int):
     """Group rows by destination partition inside ONE flat device block.
 
@@ -124,6 +126,7 @@ def packed_build(batch: DeviceBatch, pids, n_out: int):
     return gather_batch(batch, order, batch.num_rows), counts, starts
 
 
+@device_phase("shuffle.packedSlice")
 def packed_slice(block: DeviceBatch, start, count) -> DeviceBatch:
     """Slice one partition's contiguous row range out of a packed
     block: a clipped-index gather to the front plus a lane mask.
@@ -140,6 +143,7 @@ def packed_slice(block: DeviceBatch, start, count) -> DeviceBatch:
                        jnp.asarray(count, dtype=jnp.int32))
 
 
+@device_phase("shuffle.trim")
 def trim(batch: DeviceBatch, out_rows: int) -> DeviceBatch:
     """The leading ``out_rows`` rows of every column: data, validity
     and lengths.  A batch's live rows are at the front

@@ -93,10 +93,9 @@ class QueryProfile:
                           if plan is not None else None)
         self.hbm_timeline = list(tele.hbm_timeline)
         #: per-query kernel-profiler deltas ({fingerprint ->
-        #: profiler.KernelStat}) + the observed h2d ceiling — back-filled
-        #: by Session._finalize_metrics when the profiler conf is on
+        #: profiler.KernelStat}) — back-filled by
+        #: Session._finalize_metrics when the profiler conf is on
         self.kernel_stats = None
-        self.h2d_ceiling_bps = 0.0
 
     # ------------------------------------------------------------------
     @property
@@ -138,8 +137,11 @@ class QueryProfile:
         for c in sp["children"]:
             self._render_span(c, indent + 1, lines)
 
-    def render(self, top_n: int = 5) -> str:
-        """The full EXPLAIN-ANALYZE report."""
+    def render(self, top_n: int = 5,
+               device_trace: Optional[str] = None) -> str:
+        """The full EXPLAIN-ANALYZE report; with ``device_trace`` (the
+        path of a profiler trace's xplane) it ends in the device's
+        seconds by program and phase."""
         lines = [f"== Query profile {self.query_id} "
                  f"(wall={_fmt_ms(self.wall_ns)}) =="]
         if self.plan_text is not None:
@@ -174,12 +176,11 @@ class QueryProfile:
                 lines.append(f"  {k}: "
                              + (_fmt_ms(v) if k.endswith("Ns") else str(v)))
         if self.kernel_stats:
-            from .profiler import render_roofline
+            from .profiler import render_dispatches
 
             lines.append("")
-            lines.extend(render_roofline(self.kernel_stats,
-                                         self.h2d_ceiling_bps,
-                                         top_n=max(top_n, 10)))
+            lines.extend(render_dispatches(self.kernel_stats,
+                                           top_n=max(top_n, 10)))
         aqe = {k.split(".", 1)[1]: v for k, v in self.metrics.items()
                if k.startswith("aqe.")}
         if aqe:
@@ -249,6 +250,12 @@ class QueryProfile:
             lines.append("")
             lines.append(f"-- HBM watermark ({len(self.hbm_timeline)} "
                          f"samples, peak={peak}B) --")
+        if device_trace is not None:
+            from . import device_trace as _device_trace
+
+            lines.append("")
+            lines.extend(_device_trace.render(
+                _device_trace.load(device_trace), top_n=max(top_n, 10)))
         return "\n".join(lines)
 
     def __repr__(self):  # pragma: no cover

@@ -1,13 +1,13 @@
-"""Per-kernel dispatch profiler with roofline attribution.
+"""Per-kernel dispatch counts.
 
 Every ``jit_kernel`` dispatch in ``exec/kernel_cache.py`` reports to the
 process-global :data:`PROFILER` (mirroring the KernelCache GLOBAL): per
-kernel *fingerprint* it accumulates dispatch count, dispatch wall, input
+kernel *fingerprint* it accumulates dispatch count, enqueue wall, input
 and output rows/bytes, and the padding waste from power-of-two shape
-bucketing.  ``HostToDeviceExec`` reports each upload so the observed
-h2d ceiling (peak bytes/s) anchors the roofline: a kernel far below the
-ceiling on bytes/s is compute-bound, not transfer-bound — which is the
-question ROADMAP item 2 needs answered per kernel, not per query.
+bucketing: counts that follow from shapes and are exact on any backend.
+What a kernel's *device* seconds and bytes a second are is not here (an
+enqueue's wall on an asynchronous device is neither): that is
+``telemetry/device_trace.py``, from a profiler trace.
 
 Hot-path discipline (enforced by the ``profiler-guard`` and
 ``host-sync`` analysis rules):
@@ -20,18 +20,14 @@ Hot-path discipline (enforced by the ``profiler-guard`` and
   batch's logical ``num_rows`` is counted only when it is a plain
   Python int (kernel *outputs* can carry traced/device scalars there).
 
-Wall times are dispatch wall: on asynchronous backends this measures
-enqueue + any blocking the dispatch itself does (first-shape dispatches
-include compile), which is exactly what the per-query ``compute_s``
-wall is made of.
+``wall_ns`` is the host's wall around the enqueue (first-shape
+dispatches include compile): a host cost, never divided into a rate.
 """
 from __future__ import annotations
 
 import hashlib
 import threading
 from typing import Callable, Dict, List, Optional, Tuple
-
-_H2D_MIN_BYTES = 1 << 16   # ignore tiny transfers when taking the peak
 
 
 def kernel_fingerprint(key, fn: Callable) -> str:
@@ -128,9 +124,6 @@ class KernelProfiler:
         self._lock = threading.Lock()
         self._stats: Dict[str, KernelStat] = {}
         self.enabled = False
-        self._h2d_bytes = 0
-        self._h2d_ns = 0
-        self._h2d_peak_bps = 0.0
 
     # ---------------- configuration / lifecycle -----------------------
     def configure(self, conf) -> None:
@@ -142,9 +135,6 @@ class KernelProfiler:
         with self._lock:
             self._stats.clear()
             self.enabled = False
-            self._h2d_bytes = 0
-            self._h2d_ns = 0
-            self._h2d_peak_bps = 0.0
 
     # ---------------- hot-path recorders ------------------------------
     def record_dispatch(self, fingerprint: str, wall_ns: int,
@@ -168,19 +158,6 @@ class KernelProfiler:
                 st.out_padded += out_padded
                 st.out_bytes += out_bytes
         except Exception:  # noqa: BLE001 - profiling must never fail a query
-            pass
-
-    def record_h2d(self, nbytes: int, elapsed_ns: int) -> None:
-        """Account one host->device upload (the roofline ceiling)."""
-        try:
-            with self._lock:
-                self._h2d_bytes += int(nbytes)
-                self._h2d_ns += int(elapsed_ns)
-                if nbytes >= _H2D_MIN_BYTES and elapsed_ns > 0:
-                    bps = nbytes / (elapsed_ns / 1e9)
-                    if bps > self._h2d_peak_bps:
-                        self._h2d_peak_bps = bps
-        except Exception:  # noqa: BLE001
             pass
 
     # ---------------- snapshots / per-query deltas ---------------------
@@ -208,78 +185,50 @@ class KernelProfiler:
     def snapshot(self) -> Dict[str, KernelStat]:
         return self.since(None)
 
-    def h2d_ceiling_bps(self) -> float:
-        """Observed h2d ceiling, bytes/s: peak single-transfer rate,
-        falling back to the aggregate rate when no transfer cleared the
-        size floor."""
-        with self._lock:
-            if self._h2d_peak_bps > 0:
-                return self._h2d_peak_bps
-            if self._h2d_ns > 0:
-                return self._h2d_bytes / (self._h2d_ns / 1e9)
-            return 0.0
 
-
-def roofline_rows(stats: Dict[str, KernelStat],
-                  h2d_ceiling_bps: float = 0.0,
+def dispatch_rows(stats: Dict[str, KernelStat],
                   top_n: Optional[int] = None) -> List[dict]:
-    """Derive the roofline table from a stats snapshot: one dict per
-    kernel, sorted by wall descending — the rows
+    """One dict per kernel, most bytes first — the rows
     ``Session.profile_report()`` renders."""
-    rows = []
-    for fp, st in sorted(stats.items(), key=lambda kv: -kv[1].wall_ns):
-        wall_s = st.wall_ns / 1e9
-        nbytes = st.in_bytes + st.out_bytes
-        row = {
-            "kernel": fp,
-            "dispatches": st.dispatches,
-            "wall_s": round(wall_s, 6),
-            "rows": st.in_rows,
-            "padded_rows": st.in_padded,
-            "bytes": nbytes,
-            "padding_waste": round(st.padding_waste, 4),
-            "bytes_per_s": round(nbytes / wall_s, 1) if wall_s > 0 else 0.0,
-            "rows_per_s": round(st.in_padded / wall_s, 1)
-            if wall_s > 0 else 0.0,
-        }
-        if h2d_ceiling_bps > 0 and wall_s > 0:
-            row["pct_of_h2d_ceiling"] = round(
-                100.0 * row["bytes_per_s"] / h2d_ceiling_bps, 2)
-        rows.append(row)
+    rows = [{
+        "kernel": fp,
+        "dispatches": st.dispatches,
+        "enqueue_s": round(st.wall_ns / 1e9, 6),
+        "rows": st.in_rows,
+        "padded_rows": st.in_padded,
+        "bytes": st.in_bytes + st.out_bytes,
+        "padding_waste": round(st.padding_waste, 4),
+    } for fp, st in stats.items()]
+    rows.sort(key=lambda r: (-r["bytes"], r["kernel"]))
     return rows[:top_n] if top_n else rows
 
 
-def _fmt_rate(v: float) -> str:
+def _fmt_count(v: float) -> str:
     if v >= 1e9:
         return f"{v / 1e9:.2f}G"
     if v >= 1e6:
         return f"{v / 1e6:.2f}M"
     if v >= 1e3:
         return f"{v / 1e3:.2f}K"
-    return f"{v:.1f}"
+    return f"{v:.0f}"
 
 
-def render_roofline(stats: Dict[str, KernelStat],
-                    h2d_ceiling_bps: float = 0.0,
-                    top_n: int = 10) -> List[str]:
-    """Text roofline table for Session.profile_report()."""
-    rows = roofline_rows(stats, h2d_ceiling_bps, top_n=top_n)
-    ceiling = (f"{_fmt_rate(h2d_ceiling_bps)}B/s"
-               if h2d_ceiling_bps > 0 else "unmeasured")
-    lines = [f"-- Kernel roofline (h2d ceiling={ceiling}) --"]
+def render_dispatches(stats: Dict[str, KernelStat],
+                      top_n: int = 10) -> List[str]:
+    """Text table for Session.profile_report()."""
+    lines = ["-- Kernel dispatches --"]
+    rows = dispatch_rows(stats, top_n=top_n)
     if not rows:
         lines.append("  (no kernel dispatches recorded)")
         return lines
-    hdr = (f"  {'kernel':<34} {'disp':>5} {'wall':>9} {'rows/s':>9} "
-           f"{'bytes/s':>9} {'%ceil':>6} {'waste':>6}")
-    lines.append(hdr)
+    lines.append(f"  {'kernel':<34} {'disp':>5} {'enqueue':>9} {'rows':>9} "
+                 f"{'padded':>9} {'bytes':>9} {'waste':>6}")
     for r in rows:
-        pct = r.get("pct_of_h2d_ceiling")
         lines.append(
             f"  {r['kernel'][:34]:<34} {r['dispatches']:>5} "
-            f"{r['wall_s'] * 1e3:>7.1f}ms {_fmt_rate(r['rows_per_s']):>9} "
-            f"{_fmt_rate(r['bytes_per_s']):>8}B "
-            f"{(f'{pct:.0f}%' if pct is not None else '-'):>6} "
+            f"{r['enqueue_s'] * 1e3:>7.1f}ms {_fmt_count(r['rows']):>9} "
+            f"{_fmt_count(r['padded_rows']):>9} "
+            f"{_fmt_count(r['bytes']):>8}B "
             f"{r['padding_waste'] * 100:>5.1f}%")
     return lines
 

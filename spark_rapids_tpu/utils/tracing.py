@@ -9,7 +9,12 @@ metrics too.
 ``trace_range`` is ONE exception-safe path: the optional profiler
 annotation, the optional metric coupling, and the telemetry span-stack
 push/pop (re-entrant, thread-local — a re-entered range name never
-double counts) all ride the same try/finally, enabled or not."""
+double counts) all ride the same try/finally, enabled or not.
+
+The host's ranges stop at a program's dispatch.  Inside a program the
+names are ``device_phase`` scopes: they land in every op's metadata
+(``jit(agg_batch)/lexsort/while/body/gather``), where
+``telemetry/device_trace.py`` reads them out of a profiler trace."""
 from __future__ import annotations
 
 import time
@@ -19,10 +24,66 @@ _ENABLED = False
 
 _spans = None  # telemetry.spans module, bound at first use
 
+#: the phases a program names inside itself (docs/observability.md has
+#: what each covers); an operator's scope is its host span's name
+DEVICE_PHASES = (
+    "lexsort", "reorder", "segments", "gather.partitionOrder",
+    "agg.prologue",
+    "join.probe", "join.emitCounts", "join.expandSearch",
+    "join.expandGather",
+    "shuffle.hashPids", "shuffle.packedBuild", "shuffle.packedSlice",
+    "shuffle.trim",
+)
+
+#: the span a request opens first (session.py, parallel/runner.py)
+QUERY_SPAN = "Query"
+
+#: the directory of the last profiler session that was open when a
+#: ``Query`` span opened under ``sql.trace.enabled`` (None: none ever)
+_profile_dir = None
+
 
 def enable(flag: bool = True) -> None:
     global _ENABLED
     _ENABLED = flag
+
+
+@contextmanager
+def device_phase(name: str):
+    """``jax.named_scope`` for the ops traced inside it (a ``with``, or
+    a decorator of a kernel body): one of ``DEVICE_PHASES``, or an
+    operator's host-span name where a program is composed of several
+    operators' bodies.  Runs at trace time only (nothing at dispatch),
+    and JAX strips debug info from the persistent cache's key, so no
+    program's key changes."""
+    import jax
+
+    with jax.named_scope(name):
+        yield
+
+
+def note_profile_dir() -> None:
+    """``trace_range`` calls it where a ``Query`` span opens, under
+    ``_ENABLED`` alone (off, the request path reads nothing more):
+    remember where the open profiler session, if any, will write.  A
+    query outside any session leaves the last one's directory noted: a
+    benchmark's window goes on after its profiler stops."""
+    global _profile_dir
+    import jax._src.profiler as _jax_profiler
+
+    # private, as kernel_cache's ``_jfn._cache_size()`` is:
+    # tests/test_device_phases.py fails loudly when JAX moves it
+    state = getattr(_jax_profiler, "_profile_state", None)
+    log_dir = getattr(state, "log_dir", None)
+    if log_dir is not None:
+        _profile_dir = str(log_dir)
+
+
+def last_profile_dir():
+    """Where the last profiler session that was open around a traced
+    query writes its ``plugins/profile/<run>/*.xplane.pb``; None with
+    tracing off, or where no query ran inside a session."""
+    return _profile_dir if _ENABLED else None
 
 
 def _telemetry_spans():
@@ -52,6 +113,8 @@ def trace_range(name: str, metric=None, **annotation):
     if _ENABLED:
         import jax.profiler
 
+        if name == QUERY_SPAN:
+            note_profile_dir()
         profiled = jax.profiler.TraceAnnotation(name, **annotation)
         profiled.__enter__()
     token = spans.push_range(name)

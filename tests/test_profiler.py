@@ -2,12 +2,14 @@
 
 Contract under test (ISSUE 13): with ``telemetry.profiler.enabled``
 every jitted-kernel dispatch is attributed to a deterministic kernel
-fingerprint — dispatch count, wall, input rows/bytes, padding waste —
-and a TPC-H q1 run reconciles with its scan input within padding
-tolerance; the roofline report ranks kernels against the measured h2d
-ceiling; per-query deltas come from mark()/since(); disabled mode
-records nothing and changes no results, and enabling the profiler
-keeps fused vs unfused plans bit-identical.
+fingerprint — dispatch count, enqueue wall, input rows/bytes, padding
+waste — and a TPC-H q1 run reconciles with its scan input within padding
+tolerance; the report's ``-- Kernel dispatches --`` table ranks kernels
+by the bytes their shapes say and derives no rate from the enqueue's
+wall (PR 36: the device's seconds and GB/s are
+``telemetry/device_trace.py``'s); per-query deltas come from
+mark()/since(); disabled mode records nothing and changes no results,
+and enabling the profiler keeps fused vs unfused plans bit-identical.
 """
 import numpy as np
 import pytest
@@ -16,8 +18,8 @@ import spark_rapids_tpu as srt
 from spark_rapids_tpu.benchmarks import tpch, tpch_datagen
 from spark_rapids_tpu.plan import functions as F
 from spark_rapids_tpu.telemetry.profiler import (PROFILER, KernelStat,
-                                                 kernel_fingerprint,
-                                                 roofline_rows)
+                                                 dispatch_rows,
+                                                 kernel_fingerprint)
 
 SF = 0.0007
 SEED = 7
@@ -55,7 +57,7 @@ def test_fingerprint_deterministic_and_key_sensitive():
 def test_q1_attribution_reconciles_with_scan_input():
     raw = tpch_datagen.generate(SF, seed=SEED)
     n_li = len(raw["lineitem"][1]["l_quantity"])
-    # telemetry on as well: the roofline table rides profile_report()
+    # telemetry on as well: the dispatches table rides profile_report()
     sess = srt.Session(dict(
         PROF, **{"spark.rapids.tpu.telemetry.enabled": True}))
     tables = {name: sess.create_dataframe(cols, schema)
@@ -90,13 +92,22 @@ def test_q1_attribution_reconciles_with_scan_input():
     # from shapes and not from the clock
     read = sorted((s.in_bytes for s in per), reverse=True)
     assert sum(read[:3]) >= 0.5 * sum(read)
-    # roofline rows are ranked by wall and carry derived rates
-    rows = roofline_rows(stats, sess.last_h2d_ceiling_bps, top_n=10)
-    assert rows == sorted(rows, key=lambda r: -r["wall_s"])
+    # the table's rows are ranked by bytes and hold counts alone: no
+    # rate is derived from the enqueue's wall
+    rows = dispatch_rows(stats, top_n=10)
+    assert [r["bytes"] for r in rows] == \
+        sorted((r["bytes"] for r in rows), reverse=True)
+    assert rows[0]["bytes"] == max(s.in_bytes + s.out_bytes for s in per)
     for r in rows:
-        assert r["bytes_per_s"] >= 0 and r["rows_per_s"] >= 0
-    # the session report renders the roofline table
-    assert "Kernel roofline" in sess.profile_report()
+        assert set(r) == {"kernel", "dispatches", "enqueue_s", "rows",
+                          "padded_rows", "bytes", "padding_waste"}
+        assert r["dispatches"] >= 1 and r["enqueue_s"] >= 0
+    # the session report renders the table, and no roofline
+    report = sess.profile_report()
+    assert "-- Kernel dispatches --" in report
+    assert "roofline" not in report and "/s" not in report.split(
+        "-- Kernel dispatches --")[1].split("\n--")[0]
+    assert not hasattr(sess, "last_h2d_ceiling_bps")
 
 
 # ==========================================================================
@@ -139,7 +150,7 @@ def test_disabled_mode_records_nothing():
     assert PROFILER.enabled is False
     assert PROFILER.mark() == {}
     assert PROFILER.snapshot() == {}
-    assert "Kernel roofline" not in (sess.profile_report() or "")
+    assert "Kernel dispatches" not in (sess.profile_report() or "")
 
 
 # ==========================================================================
