@@ -208,10 +208,12 @@ class TpuHashAggregateExec(TpuExec):
         else:
             specs += self._merge_specs(batch, rm, nkeys)
         out_cols = []
-        # a keyless aggregate has its one real segment to read
+        # the real segments alone are read: a keyless aggregate has its
+        # one, a keyed one counts them on the device and the kernel picks
+        # its read's width there (the rows past them are masked below)
         for (data, valid, lengths), (_, _, dtype) in zip(
                 seg.reduce_sorted(change, order, [sp[:2] for sp in specs],
-                                  segments=None if nkeys else 1),
+                                  segments=n_real if nkeys else 1),
                 specs):
             if lengths is None and data.dtype != dtype.jnp_dtype:
                 data = data.astype(dtype.jnp_dtype)

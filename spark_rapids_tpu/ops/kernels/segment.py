@@ -22,7 +22,7 @@ import numpy as np
 
 from ... import types as T
 from ...data.column import DeviceColumn, HostColumn
-from ...utils.tracing import device_phase
+from ...utils.tracing import READ_TIER, device_phase
 from .gather import _SCAN_BLOCK, prefix_sum
 
 # ---------------------------------------------------------------------------
@@ -523,6 +523,26 @@ def segmented_scan(x, change, op):
 _SCAN_OPS = {"sum": "add", "min": "minimum", "max": "maximum",
              "first": "minimum", "last": "maximum"}
 
+#: below this many rows ``reduce_sorted`` reads every slot whatever the
+#: segment count: a gather of 2^13 indices is a quarter of a millisecond
+#: (30 ns an index from HBM) and the call its fixed latencies (q1's specs
+#: on a v5e, plain against tiered: 1.84 / 1.66 ms at 2^13 rows, 2.43 /
+#: 1.70 at 2^14, 6.84 / 2.01 at 2^16), so a branch has nothing to save
+#: there, and the small buckets (a trimmed exchange's 128 rows, a test's
+#: batch) keep one read to compile where the tiers are four
+_TIER_FLOOR = 1 << 14
+
+
+def read_tiers(n: int):
+    """The read widths a ``reduce_sorted`` over ``n`` rows chooses from
+    when its segment count is known on the device alone: ``n/64``,
+    ``n/16``, ``n/4`` and ``n`` (the plain read, the fallback that is
+    always right), a function of the static ``n``; ``(n,)`` below
+    ``_TIER_FLOOR``."""
+    if n < _TIER_FLOOR:
+        return (n,)
+    return (n // 64, n // 16, n // 4, n)
+
 
 @device_phase("segments")
 def reduce_sorted(change, order, specs, segments=None):
@@ -534,11 +554,21 @@ def reduce_sorted(change, order, specs, segments=None):
     the answer lists (data, valid, lengths) of ``n`` rows each, segment
     ``j``'s in row ``j`` (rows past the last segment hold nothing of
     meaning): the device analogue of ``segment_reduce_np`` /
-    ``segment_pick_np``.  ``segments`` (static) says how many leading
-    segments the caller reads where it knows (a keyless aggregate: 1):
-    only their ends are read, and the rows after them are zeros; a read
-    of all ``n`` ends is a gather of ``n`` indices a stack (2^22 rows on
-    a v5e: 30-36 ms each, three of them q6's whole aggregate).
+    ``segment_pick_np``.
+
+    ``segments`` says how many leading segments the caller reads; the
+    rows at and past it are the caller's to mask, and come back as zeros
+    wherever they were not read.  None: all ``n`` slots are read (a
+    gather of ``n`` indices a stack; 2^22 rows on a v5e: 30-124 ms
+    each).  A static int (a keyless aggregate: 1): only that many ends
+    are read.  A traced int32 scalar (a keyed aggregate's group count,
+    known on the device alone): the reads stand in a ``lax.switch`` over
+    ``read_tiers(n)`` and the smallest width that holds ``segments`` is
+    the one that runs, so q1's 4 groups in a 2^22-row bucket cost 2^16
+    indices a gather and not 2^22; the rows below ``segments`` are read
+    by the same indices from the same arrays as the plain read's, equal
+    to the bit.  The sort of the ends, the scans and the prefix sum are
+    outside the switch: every branch reads their whole arrays.
 
     Nothing scatters.  The segments' first and last rows come from one
     sort; sums, minima and maxima from a segmented scan read at the last
@@ -548,22 +578,30 @@ def reduce_sorted(change, order, specs, segments=None):
     stacked before they are sorted, scanned and read, because a gather
     of a stack costs a tenth of a gather a column (16 float32 of 2^22
     rows on a v5e: 138 ms against 1429)."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
     if not specs:
         return []
     n = change.shape[0]
+    # one width where the count is static or absent, or the batch small
+    tiers = (n,) if segments is None or isinstance(segments, int) \
+        else read_tiers(n)
     idx = jnp.arange(n, dtype=jnp.int32)
     last = jnp.concatenate([change[1:], jnp.ones((1,), jnp.bool_)])
     # the sorted rows that end a segment, in order, then n's: a one-key
     # sort runs in a quarter of a compaction's scatter
     ends = jnp.minimum(lax.sort(jnp.where(last, idx, n)), n - 1)
-    starts = jnp.minimum(jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), ends[:-1] + 1]), n - 1)
-    if segments is not None:
-        ends, starts = ends[:segments], starts[:segments]
-    m = ends.shape[0]
+
+    def starts_of(ends):    # of the leading segments whose ends these are
+        return jnp.minimum(jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32), ends[:-1] + 1]), n - 1)
+
+    if len(tiers) == 1:
+        starts = starts_of(ends)
+        if isinstance(segments, int):
+            ends, starts = ends[:segments], starts[:segments]
 
     def in_order(stack):
         if order is None:
@@ -610,42 +648,69 @@ def reduce_sorted(change, order, specs, segments=None):
             flags.append(col.validity)
         slots.append((slot, at, by_value))
 
-    # ----- one scan and one gather a stack ------------------------------
-    totals = {key: segmented_scan(in_order(jnp.stack(rows)), change,
-                                  getattr(jnp, key[0]))[:, ends]
-              for key, rows in stacks.items()}
-    if flags:
-        upto = prefix_sum(
-            in_order(jnp.stack(flags)).astype(jnp.int32))[:, ends]
-        counts = upto - jnp.concatenate(
-            [jnp.zeros_like(upto[:, :1]), upto[:, :-1]], axis=1)
+    # ----- one scan a stack, running to every row -----------------------
+    def scans(read):
+        totals = {key: read(segmented_scan(in_order(jnp.stack(rows)),
+                                           change, getattr(jnp, key[0])))
+                  for key, rows in stacks.items()}
+        upto = read(prefix_sum(in_order(jnp.stack(flags)).astype(
+            jnp.int32))) if flags else None
+        return totals, upto
 
-    # ----- each spec's rows ---------------------------------------------
-    out = []
-    for (col, op), (slot, at, by_value) in zip(specs, slots):
-        if op == "count":
-            out.append((counts[at].astype(jnp.int64),
-                        jnp.ones((m,), jnp.bool_), None))
-            continue
-        if op.endswith("_any"):     # every segment has a first row
-            row = starts if op == "first_any" else ends
-            row = row if order is None else order[row]
-            has = col.validity[row]
-        else:
-            has = counts[at] > 0
-            total = totals[slot[0]][slot[1]]
-            if by_value is None and op in ("sum", "min", "max"):
-                out.append((total, has, None))
+    # ----- one gather a stack, and each spec's rows ----------------------
+    def read_rows(ends, starts, totals, upto):
+        """The ``m = len(ends)`` leading segments' rows, then zeros up to
+        ``n``; ``totals`` and ``upto`` as read at ``ends``."""
+        m = ends.shape[0]
+        if upto is not None:
+            counts = upto - jnp.concatenate(
+                [jnp.zeros_like(upto[:, :1]), upto[:, :-1]], axis=1)
+        out = []
+        for (col, op), (slot, at, by_value) in zip(specs, slots):
+            if op == "count":
+                out.append((counts[at].astype(jnp.int64),
+                            jnp.ones((m,), jnp.bool_), None))
                 continue
-            row = jnp.clip(total, 0, n - 1)
-            row = row if by_value is None else by_value[row]
-        out.append((col.data[row], has,
-                    None if col.lengths is None else col.lengths[row]))
-    if m < n:
+            if op.endswith("_any"):     # every segment has a first row
+                row = starts if op == "first_any" else ends
+                row = row if order is None else order[row]
+                has = col.validity[row]
+            else:
+                has = counts[at] > 0
+                total = totals[slot[0]][slot[1]]
+                if by_value is None and op in ("sum", "min", "max"):
+                    out.append((total, has, None))
+                    continue
+                row = jnp.clip(total, 0, n - 1)
+                row = row if by_value is None else by_value[row]
+            out.append((col.data[row], has,
+                        None if col.lengths is None else col.lengths[row]))
+        if m < n:
 
-        def whole(x):
-            return None if x is None else jnp.pad(
-                x, [(0, n - m)] + [(0, 0)] * (x.ndim - 1))
+            def whole(x):
+                return None if x is None else jnp.pad(
+                    x, [(0, n - m)] + [(0, 0)] * (x.ndim - 1))
 
-        out = [tuple(whole(x) for x in triple) for triple in out]
-    return out
+            out = [tuple(whole(x) for x in triple) for triple in out]
+        return out
+
+    if len(tiers) == 1:
+        return read_rows(ends, starts, *scans(lambda x: x[:, ends]))
+
+    # ----- the segment count picks the read's width on the device --------
+    scanned, upto = scans(lambda x: x)
+
+    def tier(m):
+        def read():
+            with jax.named_scope(f"{READ_TIER}{m}"):
+                e = ends[:m]
+                return read_rows(
+                    e, starts_of(e), {k: x[:, e] for k, x in scanned.items()},
+                    None if upto is None else upto[:, e])
+
+        return read
+
+    segments = jnp.asarray(segments, jnp.int32)
+    # the smallest tier that holds them (the last holds any count)
+    width = sum((segments > m).astype(jnp.int32) for m in tiers[:-1])
+    return lax.switch(width, [tier(m) for m in tiers])

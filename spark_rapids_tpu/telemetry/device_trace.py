@@ -17,7 +17,9 @@ The reduction: for each device, program (``jit_agg_batch``: the module
 name without its fingerprint) and key — the op's **phase** (innermost
 ``device_phase`` scope of :data:`~spark_rapids_tpu.utils.tracing.DEVICE_PHASES`),
 **operator** (outermost ``Tpu...`` scope), JAX **primitive** (last
-component of ``tf_op``) or **source** line — seconds, op count,
+component of ``tf_op``), **source** line or **tier** (``segment.reduce_sorted``'s
+``readTier.<rows>`` scope: which read width a group-by's segment count
+chose on the device; ``--by tier --within segments``) — seconds, op count,
 ``bytes_accessed``, GB/s and the share of the plane's peak bandwidth.
 Seconds are leaf seconds: an event that wraps others (a ``while`` around
 its body) adds only the time in which none of them ran, so the keys of
@@ -29,8 +31,8 @@ Two surfaces: ``Session.profile_report(device_trace=<path>)`` renders
 :func:`render` as the report's ``-- Device phases --`` section, and
 
     python -m spark_rapids_tpu.telemetry.device_trace <xplane> \\
-        [--program jit_agg_batch] [--by phase|operator|primitive|source] \\
-        [--within segments]
+        [--program jit_agg_batch] \\
+        [--by phase|operator|primitive|source|tier] [--within segments]
 
 A persistent compile cache that holds executables compiled by a tree
 without the scopes hands them back with that tree's metadata: the scopes
@@ -45,7 +47,7 @@ import struct
 import sys
 from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
-from ..utils.tracing import DEVICE_PHASES
+from ..utils.tracing import DEVICE_PHASES, READ_TIER
 
 #: the key of ops that stand under no scope of the asked kind
 UNSCOPED = "(unscoped)"
@@ -54,7 +56,7 @@ UNSCOPED = "(unscoped)"
 NO_PATH = "(no op path)"
 #: the benchmark's span around one traced request (host plane)
 MARKER = "bench.query"
-BY = ("phase", "operator", "primitive", "source")
+BY = ("phase", "operator", "primitive", "source", "tier")
 
 _DEVICE = re.compile(r"/device:TPU:(\d+)")
 _FINGERPRINT = re.compile(r"\((\d+)\)$")
@@ -347,6 +349,13 @@ def operator_of(tf_op: str) -> str:
                 UNSCOPED if tf_op else NO_PATH)
 
 
+def tier_of(tf_op: str) -> str:
+    """``.../segments/cond/branch_0_fun/readTier.65536/gather:`` ->
+    ``readTier.65536``: the width of the read the op belongs to."""
+    return next((c for c in scopes(tf_op) if c.startswith(READ_TIER)),
+                UNSCOPED if tf_op else NO_PATH)
+
+
 def primitive_of(tf_op: str) -> str:
     return tf_op.rstrip(":").rsplit("/", 1)[-1] or NO_PATH
 
@@ -357,7 +366,8 @@ def _key_of(by: str, phases):
     if by == "source":
         return lambda op: op.source or NO_PATH
     of_path = {"phase": lambda path: phase_of(path, phases),
-               "operator": operator_of, "primitive": primitive_of}.get(by)
+               "operator": operator_of, "primitive": primitive_of,
+               "tier": tier_of}.get(by)
     if of_path is None:
         raise ValueError(f"by={by!r}: one of {', '.join(BY)}")
     of_path = functools.lru_cache(maxsize=None)(of_path)
@@ -533,7 +543,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m spark_rapids_tpu.telemetry.device_trace",
         description="Device seconds of an xplane by phase, operator, JAX "
-                    "primitive or source line, a table a program.")
+                    "primitive, source line or read tier, a table a "
+                    "program.")
     ap.add_argument("xplane", help="an .xplane.pb or .xplane.pb.gz")
     ap.add_argument("--program", help="one program, e.g. jit_agg_batch")
     ap.add_argument("--by", choices=BY, default="phase")

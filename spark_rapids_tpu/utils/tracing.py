@@ -35,6 +35,11 @@ DEVICE_PHASES = (
     "shuffle.trim",
 )
 
+#: ``segment.reduce_sorted``'s reads of one width stand under
+#: ``readTier.<rows>``, inside ``segments``.  No phase: it takes no second
+#: out of ``segments``; ``telemetry/device_trace.py --by tier`` counts by it
+READ_TIER = "readTier."
+
 #: the span a request opens first (session.py, parallel/runner.py)
 QUERY_SPAN = "Query"
 

@@ -139,6 +139,7 @@ def test_kernel_names_its_phases_in_the_lowered_text(dispatched, program):
     for scope in named:
         if "." in scope or scope.startswith("Tpu"):
             assert scope in tracing.DEVICE_PHASES or \
+                scope.startswith(tracing.READ_TIER) or \
                 device_trace.operator_of(f"jit(x)/{scope}/op:") == scope
 
 
@@ -254,6 +255,55 @@ def test_a_wrapper_adds_only_the_time_none_of_its_events_ran():
     assert "2 request(s)" in text and "jit_p:" in text
 
 
+def test_read_tiers_within_segments_by_seconds_and_events():
+    """``--by tier``: which width ``reduce_sorted``'s switch took, and
+    what it cost.  Two aggregates of a request: the first reads 2^16
+    rows (two gathers and a pad), the second all 2^22; the scans before
+    the switch, the ``conditional``'s own time and a join's ``segments``
+    are the phase's untiered rest, a ``reorder`` is outside the phase."""
+    def op(start, end, path):
+        return device_trace.Op(start, end, "jit_agg_batch", path, "", 8, "")
+
+    base = "jit(agg_batch)/TpuHashAggregate/segments"
+    small, full = (f"{base}/cond/branch_{i}_fun/readTier.{m}"
+                   for i, m in ((0, 1 << 16), (3, 1 << 22)))
+    trace = device_trace.DeviceTrace({0: device_trace.Device(100.0, [
+        op(0, 50, f"{base}/while/body/closed_call/add:"),
+        op(50, 60, "jit(agg_batch)/TpuHashAggregate/reorder/gather:"),
+        op(60, 100, f"{base}/cond:"),
+        op(62, 70, f"{small}/gather:"),
+        op(70, 76, f"{small}/gather:"),
+        op(76, 98, f"{small}/jit(_pad)/pad:"),
+        op(100, 400, f"{base}/cond:"),
+        op(100, 390, f"{full}/gather:"),
+        op(400, 420, "jit(agg_batch)/join.probe/segments/cumsum:"),
+    ], [(0, 420, "jit_agg_batch")])}, [(0, 420)])
+    ps = 1e-12
+    assert device_trace.tier_of(f"{small}/gather:") == "readTier.65536"
+    assert device_trace.tier_of(f"{base}/cond:") == device_trace.UNSCOPED
+    assert device_trace.phase_of(f"{small}/jit(_pad)/pad:") == "segments"
+    rows = device_trace.reduce(trace, 0, by="tier", within="segments",
+                               window=trace.window)["jit_agg_batch"]
+    assert set(rows) == {"readTier.65536", "readTier.4194304",
+                         device_trace.UNSCOPED}
+    assert rows["readTier.65536"].seconds == pytest.approx(36 * ps)
+    assert rows["readTier.65536"].ops == 3
+    assert rows["readTier.4194304"].seconds == pytest.approx(290 * ps)
+    assert rows["readTier.4194304"].ops == 1
+    # the scan, the two conditionals' own time (4 + 10), the join's
+    assert rows[device_trace.UNSCOPED].seconds == pytest.approx(
+        (50 + 14 + 20) * ps)
+    # the tiers take nothing out of the phase the metrics read
+    by_phase = device_trace.reduce(trace, 0, window=trace.window)[
+        "jit_agg_batch"]
+    assert by_phase["segments"].seconds == pytest.approx(
+        sum(r.seconds for r in rows.values()))
+    assert by_phase["reorder"].seconds == pytest.approx(10 * ps)
+    text = "\n".join(device_trace.render(trace, by="tier",
+                                         within="segments"))
+    assert "by tier within segments" in text and "readTier.65536" in text
+
+
 def test_recorded_phases_innermost_scope_while_and_the_unscoped_rest():
     trace = device_trace.load(PHASES)
     theirs = harness_trace.load(PHASES)
@@ -365,7 +415,8 @@ def test_last_profile_dir(tmp_path):
 READERS = {"gather_device_s": ("primitive", "gather"),
            "lexsort_device_s": ("phase", "lexsort"),
            "reorder_device_s": ("phase", "reorder"),
-           "expand_search_device_s": ("phase", "join.expandSearch")}
+           "expand_search_device_s": ("phase", "join.expandSearch"),
+           "segments_device_s": ("phase", "segments")}
 
 
 @pytest.fixture()
@@ -407,19 +458,22 @@ def test_reader_reads_the_programs_phases(name, traced_dir):
     trace = device_trace.load(PHASES)
     want = device_trace.seconds_where(trace, 0, by, key, trace.window, 2)
     assert value == pytest.approx(want, rel=1e-6)
-    assert (value > 0) == (name != "expand_search_device_s")
+    # the recorded program sorts and reorders; it searches and segments
+    # nothing
+    assert (value > 0) == (name in ("gather_device_s", "lexsort_device_s",
+                                    "reorder_device_s"))
 
 
-def test_the_four_metrics_are_listed_for_every_accepted_cell():
+def test_the_phase_metrics_are_listed_for_every_accepted_cell():
     import json
 
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
     listed = {m["name"]: m for m in bench["per_layer"]}
     cells = [w["name"] for w in bench["workloads"]]
-    assert [m["name"] for m in bench["per_layer"][-4:]] == [
+    assert [m["name"] for m in bench["per_layer"][-5:]] == [
         "gather_device_s", "lexsort_device_s", "reorder_device_s",
-        "expand_search_device_s"]
+        "expand_search_device_s", "segments_device_s"]
     for name in READERS:
         assert listed[name] == {
             "name": name, "unit": "s/query", "better": "lower",

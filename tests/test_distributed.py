@@ -626,6 +626,95 @@ def test_filter_under_group_by_runs_absorbed_on_the_mesh(keyed):
     assert signed(10.0) == signed(10.0) != signed(11.0)
 
 
+def test_shards_of_one_stage_read_their_groups_at_different_widths():
+    """``reduce_sorted`` picks its read's width from the segment count,
+    which under ``shard_map`` differs shard by shard: four shards of one
+    program take four different branches (a handful of groups, a
+    sixteenth of the rows and one more, every row its own, none at
+    all), each shard's rows equal to the plain read's below its tier
+    and zeros past it, as on one chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from spark_rapids_tpu.data.column import DeviceColumn
+    from spark_rapids_tpu.ops.kernels import segment as seg
+    from spark_rapids_tpu.parallel.mesh import DATA_AXIS
+
+    n = seg._TIER_FLOOR
+    tiers = seg.read_tiers(n)
+    counts = [5, tiers[1] + 1, n, 0]
+    rng = np.random.RandomState(37)
+    change = np.ones((4, n), np.bool_)       # padding rows: each its own
+    for shard, groups in enumerate(counts):
+        real = min(n, 3 * groups)
+        change[shard, :real] = np.arange(real) % 3 == 0
+    values = rng.randint(1, 99, (4, n)).astype(np.int64)
+    floats = rng.rand(4, n) + 1.0
+    valid = rng.rand(4, n) < 0.7
+    order = np.stack([rng.permutation(n) for _ in range(4)]).astype(np.int32)
+
+    def reduce(change, order, values, floats, valid, count):
+        specs = [(DeviceColumn(T.INT64, values, valid), "sum"),
+                 (DeviceColumn(T.FLOAT64, floats, valid), "max"),
+                 (DeviceColumn(T.INT64, values, valid), "count"),
+                 (DeviceColumn(T.INT64, values, valid), "first"),
+                 (DeviceColumn(T.FLOAT64, floats, valid), "last_any")]
+        return [(d, ok) for d, ok, _ in
+                seg.reduce_sorted(change, order, specs, segments=count)]
+
+    def per_shard(change, order, values, floats, valid, count):
+        out = reduce(change[0], order[0], values[0], floats[0], valid[0],
+                     count[0])
+        return jax.tree_util.tree_map(lambda x: x[None], out)
+
+    spec = P(DATA_AXIS)
+    args = [jnp.asarray(a) for a in (change, order, values, floats, valid)]
+    got = jax.jit(jax.shard_map(
+        per_shard, mesh=_mesh(4), in_specs=(spec,) * 6, out_specs=spec))(
+        *args, jnp.asarray(counts, jnp.int32))
+    for shard, groups in enumerate(counts):
+        m = next(m for m in tiers if m >= groups)
+        plain = reduce(*(a[shard] for a in args), None)
+        for (data, ok), (want, want_ok) in zip(got, plain):
+            for g, w in ((data[shard], want), (ok[shard], want_ok)):
+                g, w = np.asarray(g), np.asarray(w)
+                np.testing.assert_array_equal(g[:m], w[:m])
+                assert not g[m:].any() and (m == n or w[m:].any())
+
+
+def test_group_by_on_the_mesh_where_the_shards_hold_unlike_group_counts(
+        monkeypatch):
+    """The same through ``run_distributed``: the leaf's rows are split
+    row-wise, so the first shard of the partial aggregate's stage holds
+    three groups and the last every row its own, in buckets wide enough
+    for the tiers; the answer is the host engine's."""
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.ops.kernels import segment as seg
+    from spark_rapids_tpu.parallel.runner import run_distributed
+    from spark_rapids_tpu.plan import functions as F
+
+    n = seg._TIER_FLOOR
+    rng = np.random.RandomState(41)
+    k = np.concatenate([rng.randint(0, 3, 3 * n),
+                        10 + np.arange(n)]).astype(np.int64)
+    data = {"k": k, "v": rng.rand(4 * n) * 100}
+
+    def q(sess):
+        df = sess.create_dataframe(dict(data))
+        return (df.filter(df["v"] > 1).group_by("k")
+                .agg(F.sum("v").alias("s"), F.count("v").alias("c"),
+                     F.max("v").alias("hi")))
+
+    widths, read_tiers = [], seg.read_tiers
+    monkeypatch.setattr(seg, "read_tiers", lambda rows: (
+        widths.append(rows), read_tiers(rows))[1])
+    sess = Session()
+    got = run_distributed(sess, q(sess), mesh=_mesh(4)).to_rows()
+    assert max(widths) >= n         # the partial aggregate's shards
+    _assert_rows_equal(got, q(Session(tpu_enabled=False)).collect())
+
+
 def test_complete_mode_aggregate_absorbs_on_the_mesh():
     """A ``complete`` aggregate colocates its groups itself: with an
     absorbed chain the keys are read off the chain's rows, the dropped

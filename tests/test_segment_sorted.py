@@ -259,3 +259,258 @@ def test_a_block_sums_in_row_order_and_blocks_to_rounding():
     np.testing.assert_array_equal(got[:B], want[:B])
     np.testing.assert_allclose(got, want, rtol=1e-12)
     assert (got != want).any()
+
+
+# ==========================================================================
+# a traced ``segments``: the read's width is picked on the device
+# ==========================================================================
+N = seg._TIER_FLOOR          # the smallest batch whose reads are tiered
+TIERS = seg.read_tiers(N)
+
+
+def _tiered_case(seed, ordered=True):
+    """(change, order, specs, column data by spec) over ``N`` rows: 300
+    real segments of a few rows, then padding rows, each its own
+    segment, as an aggregate's batch has them.  No data is zero, so a
+    row that was read tells itself from one that was not."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    change = np.ones(N, np.bool_)
+    change[:1500] = rng.random(1500) < 0.2
+    change[0] = True
+    seg_ids = np.cumsum(change) - 1
+    valid = rng.random(N) < 0.6                 # nulls
+    other = rng.random(N) < 0.9
+
+    def values(dtype):
+        v = _values(dtype, N, rng)
+        return np.where(v == 0, dtype(7), v)
+
+    strings = np.frombuffer(rng.bytes(N * 3), np.uint8).reshape(N, 3) % 3 \
+        + ord("a")
+    lengths = np.full(N, 3, np.int32)
+    cols = [(values(np.float64), valid, "sum"),
+            (values(np.int64), valid, "sum"),
+            (values(np.int32), valid, "min"),
+            (values(np.float32), other, "max"),
+            (values(np.int32), valid, "count"),
+            (values(np.int64), other, "first"),
+            (values(np.int32), valid, "last"),
+            (values(np.float64), other, "first_any"),
+            (values(np.int64), valid, "last_any"),
+            (strings, valid, "min"),
+            (strings, other, "first_any")]
+    order = None
+    if ordered:
+        order, unsorted = _shuffled(
+            rng, seg_ids, lengths, *[c for v, ok, _ in cols for c in (v, ok)])
+        lengths, unsorted = unsorted[0], unsorted[1:]
+        cols = [(unsorted[2 * i], unsorted[2 * i + 1], op)
+                for i, (_, _, op) in enumerate(cols)]
+        order = jnp.asarray(order)
+    shared = {}
+
+    def column(v, ok):
+        ok = shared.setdefault(ok.tobytes(), jnp.asarray(ok))
+        if v.ndim == 2:
+            return DeviceColumn(T.STRING, jnp.asarray(v), ok,
+                                jnp.asarray(lengths))
+        return DeviceColumn(T.from_numpy(v.dtype), jnp.asarray(v), ok)
+
+    specs = [(column(v, ok), op) for v, ok, op in cols]
+    return jnp.asarray(change), order, specs, seg_ids, cols
+
+
+def _tiered(change, order, specs):
+    """jit of ``reduce_sorted`` with its segment count an argument."""
+    import jax
+
+    ops = [op for _, op in specs]
+
+    def run(change, order, columns, segments):
+        return seg.reduce_sorted(change, order, list(zip(columns, ops)),
+                                 segments=segments)
+
+    return jax.jit(run), (change, order, [c for c, _ in specs])
+
+
+EDGES = sorted({0, 1, 300} | {m + d for m in TIERS[:-1] for d in (0, 1)}
+               | {N})
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["ordered", "order_none"])
+def tiered(request):
+    change, order, specs, seg_ids, cols = _tiered_case(
+        47, ordered=request.param)
+    run, args = _tiered(change, order, specs)
+    plain = seg.reduce_sorted(change, order, specs)
+    return run, args, plain, seg_ids, cols
+
+
+@pytest.mark.parametrize("segments", EDGES)
+def test_traced_segment_count_reads_its_tier_and_zeros_past_it(tiered,
+                                                               segments):
+    """Every tier, and both edges of each (``segments == m``, ``m + 1``):
+    the rows below the tier's width equal the plain read's to the bit
+    (so every row below ``segments`` does), the rows past it are zeros:
+    what tells the tier that ran."""
+    import jax.numpy as jnp
+
+    run, args, plain, _, _ = tiered
+    m = next(m for m in TIERS if m >= segments)
+    for want, got in zip(plain, run(*args, jnp.int32(segments))):
+        for w, g in zip(want, got):
+            if w is None:
+                assert g is None
+                continue
+            w, g = np.asarray(w), np.asarray(g)
+            assert g.shape == w.shape and g.dtype == w.dtype
+            np.testing.assert_array_equal(g[:m], w[:m])
+            assert not g[m:].any()
+            if m < N:       # the plain read holds the padding rows' own
+                assert w[m:].any() or w.dtype == np.bool_
+
+
+@pytest.mark.parametrize("spec", range(11))
+def test_traced_segment_count_answers_as_the_oracle(tiered, spec):
+    """The 300 real segments of each spec (sum, min, max, count, first,
+    last, the ``_any`` picks, a string minimum and a string pick, with
+    nulls) against ``segment_reduce_np``, read through the first tier."""
+    import jax.numpy as jnp
+
+    run, args, _, seg_ids, cols = tiered
+    values, valid, op = cols[spec]
+    if args[1] is not None:     # the oracle takes the rows in sorted order
+        order = np.asarray(args[1])
+        values, valid = values[order], valid[order]
+    n_seg = int((np.asarray(args[0])[:1500]).sum())
+    data, ok, lengths = run(*args, jnp.int32(n_seg))[spec]
+    data, ok = np.asarray(data)[:n_seg], np.asarray(ok)[:n_seg]
+    if values.ndim == 2:
+        assert (np.asarray(lengths)[:n_seg][ok] == 3).all()
+        values = np.array([bytes(r).decode() for r in values], object)
+        data = np.array([bytes(r).decode() for r in data], object)
+    want, want_ok = seg.segment_reduce_np(values, valid, seg_ids, N, op)
+    np.testing.assert_array_equal(ok, want_ok[:n_seg])
+    if op == "sum" and values.dtype == np.float64:
+        np.testing.assert_allclose(data[ok], want[:n_seg][ok], rtol=1e-12)
+    else:
+        np.testing.assert_array_equal(data[ok], want[:n_seg][ok])
+
+
+def test_tiers_are_a_function_of_the_row_count_alone():
+    assert seg.read_tiers(N - 1) == (N - 1,)      # below the floor: plain
+    assert seg.read_tiers(128) == (128,)
+    assert TIERS == (N // 64, N // 16, N // 4, N)
+    assert seg.read_tiers(1 << 22) == (1 << 16, 1 << 18, 1 << 20, 1 << 22)
+
+
+def _lowered(n, segments, debug_info=False):
+    import jax
+    import jax.numpy as jnp
+
+    valid = jnp.ones((n,), jnp.bool_)
+    cols = [DeviceColumn(T.FLOAT64, jnp.ones((n,)), valid),
+            DeviceColumn(T.INT32, jnp.ones((n,), jnp.int32), valid)]
+
+    def run(change, columns, count):
+        return seg.reduce_sorted(
+            change, None, list(zip(columns, ["sum", "first_any"])),
+            segments=count if segments == "traced" else segments)
+
+    return jax.jit(run).lower(valid, cols, jnp.int32(3)).as_text(
+        debug_info=debug_info)
+
+
+def test_keyed_call_lowers_to_one_conditional_a_branch_a_tier():
+    import re
+
+    text = _lowered(N, "traced", debug_info=True)
+    assert text.count("stablehlo.case") == 1
+    named = set(re.findall(r"segments/cond/branch_(\d)_fun/(readTier\.\d+)/",
+                           text))
+    assert named == {(str(i), f"readTier.{m}") for i, m in enumerate(TIERS)}
+    # every gather of the program stands inside a branch: nothing is
+    # read a row a slot on the way to the switch
+    gathers = re.findall(r'loc\("([^"]+/gather)"', text)
+    assert gathers and all("/readTier." in g for g in gathers)
+    # a batch below the floor keeps the plain read, whatever the count
+    small = _lowered(N // 2, "traced")
+    assert "stablehlo.case" not in small
+    assert small == _lowered(N // 2, None)
+
+
+#: sha256 of the lowered text (no debug info) of ``_lowered(N, 1)`` and
+#: ``_lowered(N, None)`` on the commit before the tiers (ed88954, PR 36),
+#: under this JAX: the static paths' programs, and so their compile-cache
+#: keys, are the parent's
+PARENT_TEXT = {
+    "jax": "0.9.0",
+    1: "5cd5eadc1979e0d21df2f1d788574db9e44b8c6f26b482ed8a422e78f58172de",
+    None: "c2cc9c76698a8355f1b441f2cca8267a1ac252b0ab1248d247b7951686d34ce9"}
+
+
+@pytest.mark.parametrize("segments", [1, None], ids=["keyless", "plain"])
+def test_static_segment_count_lowers_to_the_parents_text(segments):
+    import hashlib
+
+    import jax
+
+    text = _lowered(N, segments)
+    assert "stablehlo.case" not in text
+    if jax.__version__ != PARENT_TEXT["jax"]:
+        pytest.skip("the parent's text was recorded under jax "
+                    + PARENT_TEXT["jax"])
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        PARENT_TEXT[segments]
+
+
+@pytest.mark.parametrize("groups", [5, N // 4 + 3, N],
+                         ids=["few", "a_quarter", "all_distinct"])
+def test_group_by_answers_the_same_whatever_tier_its_groups_take(
+        groups, monkeypatch):
+    """A keyed aggregate hands the kernel its group count
+    (``exec/aggregate.py:_reduce``): a filter, a group-by on two keys
+    (one a string, with nulls) and every kind of buffer over a
+    ``N``-row bucket, against the host engine and against the plan with
+    fusion off (its filter a program of its own)."""
+    import spark_rapids_tpu as srt
+    from spark_rapids_tpu import f
+
+    read_tiers = seg.read_tiers
+    rng = np.random.default_rng(53)
+    k = rng.permutation(N) % groups
+    data = {"k": k.tolist(),
+            "s": [None if i % 7 == 0 else f"g{i % 3}" for i in k.tolist()],
+            "v": [None if x < 0.1 else float(x) for x in rng.random(N)],
+            "w": rng.integers(-50, 50, N).tolist()}
+
+    widths = []
+    monkeypatch.setattr(seg, "read_tiers", lambda n: (
+        widths.append(n), read_tiers(n))[1])
+
+    def q(sess):
+        df = sess.create_dataframe(data, n_partitions=1)
+        return (df.filter(df["w"] > -45).group_by("k", "s").agg(
+            f.sum("v").alias("sv"), f.min("w").alias("lo"),
+            f.max("w").alias("hi"), f.count("v").alias("c"),
+            f.avg("w").alias("a"), f.min("s").alias("s_lo")))
+
+    def rows(sess):
+        return sorted(q(sess).collect(), key=lambda r: (r[0], r[1] or ""))
+
+    got = rows(srt.Session({"spark.rapids.tpu.sql.test.enabled": True}))
+    assert N in widths      # the update phase met the whole bucket
+    unfused = rows(srt.Session({
+        "spark.rapids.tpu.sql.fusion.enabled": False}))
+    want = rows(srt.Session(tpu_enabled=False))
+    assert len(got) == len(want) >= groups * 0.9
+    for g, u, w in zip(got, unfused, want):
+        assert g[:2] == u[:2] == w[:2]
+        assert g[3:6] == u[3:6] == w[3:6] and g[7] == u[7] == w[7]
+        for i in (2, 6):        # float sums: to rounding across engines
+            assert g[i] == u[i]
+            assert (g[i] is None) == (w[i] is None)
+            assert g[i] is None or g[i] == pytest.approx(w[i], rel=1e-12)

@@ -358,6 +358,16 @@ def test_absorbed_filter_aggregate_compiles_for_v5e_with_no_scatter(
                 if " gather(" in ln and f"[{ROWS}]" in ln.split("=")[1]
                 .split("gather(")[0]]
         assert wide == [], wide[:2]
+        assert " conditional(" not in text
+    else:
+        # a keyed aggregate counts its groups on the device, and its
+        # one-row-a-segment reads stand in ONE conditional, a branch a
+        # read width (``segment.read_tiers``), which the chip's compiler
+        # keeps a conditional
+        from spark_rapids_tpu.ops.kernels.segment import read_tiers
+
+        switch, = [ln for ln in text.splitlines() if " conditional(" in ln]
+        assert switch.count("%region") == len(read_tiers(ROWS)) == 4
     # what the standalone filter ran: the scatter is compaction's
     packed = _compile(compact, batch, _shape(one_chip, (ROWS,), np.bool_))
     assert " scatter(" in packed.as_text()
@@ -428,6 +438,51 @@ def test_mesh_exchange_compiles_for_four_chips(topo):
     assert "all-to-all(" in _compile(stage(jnp.int32), batch).as_text()
     with pytest.raises(Exception, match="Sum all reduce"):
         _compile(stage(jnp.int64), batch)
+
+
+def test_group_by_switch_compiles_for_four_chips(topo, as_tpu):
+    """A keyed aggregate's stage body under ``shard_map`` on a 2x2 mesh:
+    each shard counts its own groups, so the switch over read widths
+    (``segment.read_tiers``) has a shard-varying index, and every branch
+    gives back values as shard-varying as the others.  The chip's
+    compiler takes it with the conditional kept and no collective in
+    the program."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import spark_rapids_tpu as srt
+    from spark_rapids_tpu import f
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.parallel import exchange as X
+
+    sess = srt.Session({"spark.rapids.tpu.sql.test.enabled": True})
+    df = sess.create_dataframe({"k": [1, 2], "v": [3.0, 4.0]})
+    q = df.group_by("k").agg(f.sum("v").alias("s"), f.count("v").alias("c"))
+    agg, = [n for n in _walk(sess.physical_plan(q.plan))
+            if isinstance(n, TpuHashAggregateExec) and n.mode == "partial"]
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("dp",))
+    spread = NamedSharding(mesh, P("dp"))
+
+    def stacked(shape, dtype):
+        return jax.ShapeDtypeStruct((4,) + shape, np.dtype(dtype),
+                                    sharding=spread)
+
+    schema = agg.children[0].schema
+    batch = DeviceBatch(
+        schema, [DeviceColumn(fld.dtype,
+                              stacked((ROWS,), fld.dtype.np_dtype),
+                              stacked((ROWS,), np.bool_))
+                 for fld in schema], stacked((), np.int32))
+    twin = agg.kernel_twin()
+
+    def per_shard(b):
+        return X.unsqueeze_leading(twin.compute_batch(X.squeeze_leading(b)))
+
+    text = _compile(jax.shard_map(per_shard, mesh=mesh, in_specs=P("dp"),
+                                  out_specs=P("dp")), batch).as_text()
+    assert text.count(" conditional(") == 1
+    assert "all-reduce(" not in text and "all-to-all(" not in text
 
 
 def test_join_probe_and_expand_compile_for_four_chips(topo, as_tpu):
