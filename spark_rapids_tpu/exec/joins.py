@@ -6,8 +6,10 @@ into a single table, stream the other), GpuBroadcastHashJoinExec
 GpuHashJoin.scala:25-140, and GpuSortMergeJoinMeta (SMJ replaced by the
 shuffled join, GpuSortMergeJoinExec.scala:23).  Capability superset:
 the reference supports inner/left/semi/anti with conditions only on
-inner; this exec adds right/full outer (still condition-on-inner-only,
-matching GpuHashJoin.tagJoin's gate).
+inner; this exec adds right/full outer, and a condition on a semi or
+anti join (Spark's plan of a correlated ``EXISTS`` / ``NOT EXISTS``
+whose correlation is not only equalities: TPC-H q21), which the
+reference's GpuHashJoin.tagJoin leaves on the CPU.
 
 The kernel is the sort-merge pipeline in ops/kernels/join.py; both
 sides require a single batch per partition (the reference's
@@ -21,14 +23,14 @@ from typing import List, Optional
 import numpy as np
 
 from .. import types as T
-from ..data.column import DeviceBatch, bucket_rows
+from ..data.column import DeviceBatch, DeviceColumn, bucket_rows
 from ..memory import retry as R
 from ..ops.cast import Cast
 from ..ops.expression import Expression, as_device_column
 from ..ops.kernels import join as J
 from ..ops.kernels.gather import compact
 from ..utils import metrics as M
-from ..utils.tracing import trace_range
+from ..utils.tracing import device_phase, trace_range
 from .base import DevicePartitionedData, RequireSingleBatch, TpuExec
 from .coalesce import concat_device_batches
 
@@ -62,6 +64,19 @@ def _common_key_exprs(l_keys: List[Expression],
     return lo, ro
 
 
+def _ordinals(expr: Expression) -> List[int]:
+    """The input columns a bound expression reads, in order."""
+    from ..ops.expression import BoundReference
+
+    out, stack = set(), [expr]
+    while stack:
+        e = stack.pop()
+        if isinstance(e, BoundReference):
+            out.add(e.ordinal)
+        stack.extend(e.children)
+    return sorted(out)
+
+
 class TpuHashJoinExec(TpuExec):
     """Shared device join core (reference: GpuHashJoin trait)."""
 
@@ -73,6 +88,8 @@ class TpuHashJoinExec(TpuExec):
             plan.left_keys, plan.right_keys)
         self.condition = plan.condition
         self._schema = plan.schema
+        #: the rows a semi/anti join's condition reads: left + right
+        self._pair_schema = plan.pair_schema
         from .kernel_cache import (expr_signature, jit_kernel,
                                    schema_signature)
 
@@ -91,6 +108,17 @@ class TpuHashJoinExec(TpuExec):
                                          key=sig + ("expand",))
         self._semi_kernel = jit_kernel(twin._semi_anti,
                                        key=sig + ("semi",))
+        if self._pairs_needed:
+            # a program of its own: the unconditioned joins keep theirs
+            self._pairs_kernel = jit_kernel(
+                twin._semi_pairs, static_argnums=(0,),
+                key=sig + ("semiPairs",))
+
+    @property
+    def _pairs_needed(self) -> bool:
+        """A semi/anti join with a condition: it evaluates the condition
+        on its key-matched pairs (``_semi_pairs``)."""
+        return self.condition is not None and self.how in ("semi", "anti")
 
     @property
     def schema(self):
@@ -232,6 +260,10 @@ class TpuHashJoinExec(TpuExec):
             else:
                 lbp = pad_device_batch(lb, cap_l, l_widths)
                 rbp = pad_device_batch(rb, cap_r, r_widths)
+                if self._pairs_needed:
+                    # too many pairs for one layout split the stream side
+                    yield from self._join_stream_retry(lbp, rbp, rctx)
+                    continue
                 yield R.retry_call(
                     lambda lbp=lbp, rbp=rbp: self._metrics_wrap(
                         lambda: self._join(lbp, rbp)), rctx)
@@ -272,9 +304,84 @@ class TpuHashJoinExec(TpuExec):
         keep = has if self.how == "semi" else ~has
         return compact(lb, keep)
 
+    def _semi_pairs(self, c_out: int, lb: DeviceBatch, rb: DeviceBatch,
+                    pr: J.Probe, emit) -> DeviceBatch:
+        """A conditional semi/anti join: a left row is kept (semi) or
+        dropped (anti) where ANY right row of an equal, non-null key makes
+        the condition TRUE (NULL is no match), as the host engine's
+        ``HashJoinExec._join_partition``.  The ``emit`` pairs are laid
+        out in ``c_out`` slots by ``J.pair_rows``; the condition reads a
+        batch of the pair schema whose left columns rode along to the
+        pairs and whose right columns are read at them."""
+        import jax.numpy as jnp
+
+        n_left = len(lb.columns)
+        reads = _ordinals(self.condition)
+        flat = [i for i in reads if i < n_left
+                and lb.columns[i].lengths is None]
+        wide = [i for i in reads if i < n_left and i not in flat]
+        carried = [a for i in flat for a in (lb.columns[i].data,
+                                             lb.columns[i].validity)]
+        if wide:
+            carried.append(jnp.arange(lb.padded_rows, dtype=jnp.int32))
+        lay = J.pair_rows(emit, pr.lo, c_out, carried)
+        m = lay.valid.shape[0]
+        cols = [None] * (n_left + len(rb.columns))
+        for j, i in enumerate(flat):
+            c = lb.columns[i]
+            cols[i] = DeviceColumn(c.dtype, lay.carried[2 * j],
+                                   lay.carried[2 * j + 1], None)
+        with device_phase("join.condition"):
+            if wide:
+                taken = J.take_rows([lb.columns[i] for i in wide],
+                                    lay.carried[-1])
+                for i, c in zip(wide, taken):
+                    cols[i] = c
+            right = [i for i in reads if i >= n_left]
+            # the right columns in key order first (nr rows), then read
+            # at the pairs
+            in_order = J.take_rows([rb.columns[i - n_left] for i in right],
+                                   pr.order_r)
+            for i, c in zip(right, J.take_rows(in_order, lay.right_pos)):
+                cols[i] = c
+            for i, c in enumerate(cols):
+                if c is None:       # read by nothing: dead code
+                    like = (lb.columns + rb.columns)[i]
+                    cols[i] = DeviceColumn(
+                        like.dtype,
+                        jnp.zeros((m,) + like.data.shape[1:],
+                                  like.data.dtype),
+                        jnp.zeros((m,), jnp.bool_),
+                        None if like.lengths is None
+                        else jnp.zeros((m,), like.lengths.dtype))
+            pairs = DeviceBatch(self._pair_schema, cols, m)
+            c = as_device_column(self.condition.eval_tpu(pairs), m)
+            hit = c.data.astype(jnp.bool_) & c.validity & lay.valid
+        has = J.any_pair(hit, lay.first, emit)
+        return compact(lb, has if self.how == "semi" else ~has)
+
+    #: the most pair slots one call lays out (a conditional semi/anti
+    #: join); past it the stream side is split by rows.  ~80 bytes a
+    #: slot at the peak: 2^26 slots ~5 GB of a v5e's 16
+    _PAIR_SLOTS_MOST = 1 << 26
+
     def _join(self, lb: DeviceBatch, rb: DeviceBatch) -> DeviceBatch:
         # OOM-injection checkpoint: the join's working set is the pair
         R.maybe_inject_oom(type(self).__name__ + ".join")
+        if self._pairs_needed:
+            pr, emit, _, total = self._count_kernel(lb, rb)
+            pairs = int(total)              # host sync: the layout's size
+            c_out = bucket_rows(pairs)
+            if c_out > self._PAIR_SLOTS_MOST:
+                raise R.TpuSplitAndRetryOOM(
+                    f"{type(self).__name__}: {pairs} pairs a condition "
+                    f"reads, past {self._PAIR_SLOTS_MOST} slots")
+            for name, n in (("join.conditionPairs", pairs),
+                            ("join.conditionPairSlots", c_out),
+                            ("join.conditionJoins", 1)):
+                if name in self.metrics:
+                    self.metrics[name].add(n)
+            return self._pairs_kernel(c_out, lb, rb, pr, emit)
         if self.how in ("semi", "anti"):
             return self._semi_kernel(lb, rb)
         pr, emit, r_extra, total = self._count_kernel(lb, rb)
@@ -305,6 +412,11 @@ class TpuHashJoinExec(TpuExec):
         a larger ``c_out``."""
         import jax.numpy as jnp
 
+        if self._pairs_needed:
+            # the pairs are laid out at ``c_out`` slots: their count is
+            # the demand the runner retries a larger capacity on
+            pr, emit, _, total = self._count(lb, rb)
+            return self._semi_pairs(c_out, lb, rb, pr, emit), total
         if self.how in ("semi", "anti"):
             out = self._semi_anti(lb, rb)
             return out, jnp.asarray(0, dtype=jnp.int64)
@@ -321,6 +433,16 @@ class TpuHashJoinExec(TpuExec):
             out = fn()
         self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
         return out
+
+    def _init_metrics(self, ctx):
+        super()._init_metrics(ctx)
+        if self._pairs_needed:
+            # query-wide, summed over every conditional semi/anti join:
+            # the pairs evaluated, the slots they were laid out in, and
+            # the pair programs run (one a stream batch)
+            for name in ("join.conditionPairs", "join.conditionPairSlots",
+                         "join.conditionJoins"):
+                self.metrics[name] = ctx.metrics.metric(name)
 
 
 class TpuShuffledHashJoinExec(TpuHashJoinExec):
@@ -497,11 +619,14 @@ def register(register_exec):
 
     def tag(meta):
         plan = meta.plan
-        if plan.condition is not None and plan.how != "inner":
-            # reference: GpuHashJoin.tagJoin — conditions only on inner
+        if plan.condition is not None and \
+                plan.how not in ("inner", "semi", "anti"):
+            # reference: GpuHashJoin.tagJoin — conditions only on inner;
+            # here on semi and anti too (their pairs, _semi_pairs); the
+            # condition's own expressions are tagged through exprs_of
             meta.will_not_work_on_tpu(
                 f"join condition on {plan.how} join is not supported "
-                f"on TPU (inner only)")
+                f"on TPU (inner, semi and anti only)")
         # an oversized partition pair is re-bucketed by the device hash
         # of the join keys (_join_grace)
         from ..utils import hashing

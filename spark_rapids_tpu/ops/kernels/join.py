@@ -23,6 +23,13 @@ replacement is reversed).  Three stages, all static shapes:
 The only host sync is reading the match count to pick the output's
 power-of-two bucket (the same sync point the reference has when cudf
 returns the join output size).
+
+A semi or anti join with a condition (Spark's LeftSemi/LeftAnti with a
+residual, what ``EXISTS`` / ``NOT EXISTS`` with a non-equi correlation
+plan to) needs its pairs but never their order: ``pair_rows`` lays them
+out by one sort and scans, with no search (section 5 of PERF.md prices a
+``searchsorted`` step of 2^25 slots at seconds), and ``any_pair`` reads
+one bit a left row back off a prefix sum of the condition's hits.
 """
 from __future__ import annotations
 
@@ -204,3 +211,124 @@ def gather_side(columns: List[DeviceColumn], idx, slot_valid
         lengths = c.lengths[safe] if c.lengths is not None else None
         out.append(DeviceColumn(c.dtype, data, validity, lengths))
     return out
+
+
+class PairRows(NamedTuple):
+    """Every left row's key-matched pairs laid out in one array of
+    ``nl + c_out`` places: a row's marker, then one place for each of its
+    pairs, rows in order (a row without pairs has no place)."""
+    valid: object     # bool[nl + c_out]: the place holds a pair
+    right_pos: object  # int32[...]: its right row, a place in order_r
+    carried: list     # the left values asked for, at their pairs' places
+    first: object     # int32[nl]: a left row's marker, its pairs after it
+
+
+@device_phase("join.pairRows")
+def pair_rows(emit, lo, c_out: int, carried=()) -> PairRows:
+    """Slot t of a row's run, without a search.  Row i's ``emit[i]``
+    pairs are the right rows ``order_r[lo[i] + k]``, k < emit[i].  A
+    place in the layout is a row's marker (sort word 2 x its first slot)
+    or a slot (2t + 1), and one sort of both puts each row's slots right
+    after its marker: the marker of row i lands at ``first[i]`` = its
+    first slot + the rows with pairs before it.  Each 1-D array of
+    ``carried`` ([nl]) and the offset to the right row ride along in the
+    sort on the markers and are carried to the slots after them by one
+    restarting scan (``segment.scan_restarting`` keeping a segment's
+    first value): no gather by row, no scatter.  Unique words, so the
+    sort need not be stable; the rows without pairs sort last."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    nl = emit.shape[0]
+    some = emit > 0
+    offs = prefix_sum(emit) - emit
+    first = offs + prefix_sum(some.astype(jnp.int32)) - some
+    total = offs[-1] + emit[-1]
+    words = jnp.concatenate([
+        jnp.where(some, 2 * offs, 2 * c_out),
+        2 * jnp.arange(c_out, dtype=jnp.int32) + 1])
+    ride = [lo.astype(jnp.int32) - first - 1] + list(carried)
+    ride = [jnp.concatenate([x, jnp.zeros((c_out,), x.dtype)])
+            for x in ride]
+    words, *ride = lax.sort((words, *ride), num_keys=1, is_stable=False)
+    marker = (words & 1) == 0
+    by_dtype = {}
+    for i, x in enumerate(ride):
+        by_dtype.setdefault(x.dtype, []).append(i)
+    for group in by_dtype.values():
+        held = seg.scan_restarting(jnp.stack([ride[i] for i in group]),
+                                   marker, lambda a, b: a)
+        for row, i in enumerate(group):
+            ride[i] = held[row]
+    place = jnp.arange(nl + c_out, dtype=jnp.int32)
+    valid = ~marker & ((words >> 1) < total)
+    return PairRows(valid, place + ride[0], ride[1:], first)
+
+
+@device_phase("join.condition")
+def any_pair(hit, first, emit):
+    """bool[nl]: a left row has a pair where ``hit`` (bool over the
+    layout of ``pair_rows``) holds: its hits are the prefix sum read at
+    its marker and at its last slot, two gathers of ``nl`` rows."""
+    import jax.numpy as jnp
+
+    nl = first.shape[0]
+    hits = prefix_sum(hit.astype(jnp.int32))
+    ends = jnp.clip(jnp.concatenate([first, first + emit]), 0,
+                    hit.shape[0] - 1)
+    at = hits[ends]
+    return at[nl:] > at[:nl]
+
+
+def _words(x):
+    """``x`` ([n]) as rows of 32-bit words ([k, n] uint32) and the way
+    back; None where its bits cannot travel so (a float64, which the
+    chip holds as two f32 and will not bitcast; a 2-D string matrix)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    dt = x.dtype
+    if x.ndim != 1 or jnp.issubdtype(dt, jnp.floating) and dt.itemsize > 4:
+        return None
+    if dt == jnp.bool_:
+        return x.astype(jnp.uint32)[None], lambda w: w[0] != 0
+    if dt.itemsize == 8:
+        return (lax.bitcast_convert_type(x, jnp.uint32).T,
+                lambda w: lax.bitcast_convert_type(w.T, dt))
+    if dt.itemsize < 4:
+        return (lax.bitcast_convert_type(x.astype(jnp.int32), jnp.uint32)[
+            None], lambda w: lax.bitcast_convert_type(w[0], jnp.int32)
+            .astype(dt))
+    return (lax.bitcast_convert_type(x, jnp.uint32)[None],
+            lambda w: lax.bitcast_convert_type(w[0], dt))
+
+
+def take_rows(columns: List[DeviceColumn], idx) -> List[DeviceColumn]:
+    """The rows ``idx`` of each column, under the caller's scope (the
+    condition's pair-side reads stand in ``join.condition``, not in
+    ``reorder``).  Every array whose bits fit 32-bit words travels in
+    ONE stacked gather (a TPU gather is priced by its indices, not by
+    the width of its rows: PERF.md, PR 29); a float64 or a string's
+    bytes each in one of its own."""
+    import jax.numpy as jnp
+
+    parts = [a for c in columns for a in (c.data, c.validity, c.lengths)]
+    rows, backs, out, at = [], {}, {}, 0
+    for i, a in enumerate(parts):
+        if a is None:
+            continue
+        w = _words(a)
+        if w is None:
+            out[i] = a[jnp.clip(idx, 0, a.shape[0] - 1)]
+        else:
+            backs[i] = (at, w[0].shape[0], w[1])
+            rows.append(w[0])
+            at += w[0].shape[0]
+    if rows:
+        stack = jnp.concatenate(rows)
+        got = stack[:, jnp.clip(idx, 0, stack.shape[1] - 1)]
+        for i, (at, k, back) in backs.items():
+            out[i] = back(got[at:at + k])
+    return [DeviceColumn(c.dtype, out[3 * j], out[3 * j + 1],
+                         out.get(3 * j + 2))
+            for j, c in enumerate(columns)]

@@ -489,6 +489,13 @@ def segmented_scan(x, change, op):
     ``associative_scan`` took 38 and the scatter-add of one float64
     column 308.)  ``prefix_sum`` stays a ``cumsum``: it has no restarts
     to honour, and the counts need none (``reduce_sorted``)."""
+    return scan_restarting(x, change, op)
+
+
+def scan_restarting(x, change, op):
+    """``segmented_scan`` under no scope of its own: for a caller whose
+    phase the scan is part of (``join.py:pair_rows`` carries a left
+    row's values to its pairs with it)."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -514,7 +521,7 @@ def segmented_scan(x, change, op):
     (cut_last, last), (cut, inner) = lax.scan(
         step, (flags[0] & False, rows[0]), (flags, top, rows))
     if nb > 1:
-        carry = segmented_scan(last, cut_last[0], op)
+        carry = scan_restarting(last, cut_last[0], op)
         carry = jnp.concatenate([carry[:, :1], carry[:, :-1]], axis=1)
         inner = jnp.where(cut, inner, op(carry, inner))
     return inner.transpose(1, 2, 0).reshape(k, n + pad)[:, :n]

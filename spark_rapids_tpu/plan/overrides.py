@@ -256,6 +256,10 @@ class ExecMeta(BaseMeta):
     # ------------------------------------------------------------------
     def explain(self, all_mode: bool = True, indent: int = 0) -> str:
         name = type(self.plan).__name__
+        condition = getattr(self.plan, "condition", None)
+        if condition is not None and hasattr(self.plan, "how"):
+            # where a join's residual condition runs is part of the plan
+            name += f" [{self.plan.how}, {condition.sql()}]"
         if self.can_this_be_replaced:
             mark, note = "*", "will run on TPU"
         else:

@@ -866,13 +866,17 @@ class HashJoinExec(PhysicalPlan):
         rf = list(right.schema.fields)
         if how in ("semi", "anti"):
             self._schema = T.Schema(lf)
+            #: what a semi/anti join's condition reads: a left row beside
+            #: one right row of an equal key (Spark's LeftSemi/LeftAnti
+            #: residual condition sees both sides)
+            self.pair_schema = T.Schema(lf + rf)
         else:
             if how in ("left", "full"):
                 rf = [T.Field(f.name, f.dtype, True) for f in rf]
             if how in ("right", "full"):
                 lf = [T.Field(f.name, f.dtype, True) for f in lf]
-            self._schema = T.Schema(lf + rf)
-        self.condition = bind_references(condition, self._schema) \
+            self._schema = self.pair_schema = T.Schema(lf + rf)
+        self.condition = bind_references(condition, self.pair_schema) \
             if condition is not None else None
 
     @property
@@ -951,7 +955,7 @@ class HashJoinExec(PhysicalPlan):
             v = taken.is_valid() & (ridx >= 0)
             cols.append(HostColumn(c.dtype, taken.data,
                                    None if v.all() else v))
-        return HostBatch(self._schema, cols)
+        return HostBatch(self.pair_schema, cols)
 
     def execute(self, ctx):
         left = self.children[0].execute(ctx)

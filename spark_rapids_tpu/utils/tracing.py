@@ -30,7 +30,7 @@ DEVICE_PHASES = (
     "lexsort", "reorder", "segments", "gather.partitionOrder",
     "agg.prologue",
     "join.probe", "join.emitCounts", "join.expandSearch",
-    "join.expandGather",
+    "join.expandGather", "join.pairRows", "join.condition",
     "shuffle.hashPids", "shuffle.packedBuild", "shuffle.packedSlice",
     "shuffle.trim",
 )

@@ -159,6 +159,47 @@ def test_distributed_join_runs_cross_a_scan_block():
         _assert_rows_equal(got, one)
 
 
+@pytest.mark.parametrize("threshold", [0, None],
+                         ids=["shuffled", "broadcast"])
+@pytest.mark.parametrize("how", ["semi", "anti"])
+def test_distributed_conditional_semi_anti_join_keeps_its_condition(
+        how, threshold):
+    """A semi/anti join with a condition runs its pair kernel inside the
+    stage program (``join_static``): the pairs of one key (300 x 40 on
+    a shard, far past the first capacity) overflow, the runner retries
+    at their count, and the answer is the host engine's, never the
+    unconditioned one."""
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.parallel.runner import run_distributed
+    from spark_rapids_tpu.plan import functions as F
+
+    rng = np.random.RandomState(38)
+    lk = rng.randint(0, 30, 1200).astype(np.int64)
+    lk[:300] = 7
+    ls = rng.randint(0, 4, 1200).astype(np.int64)
+    rk = np.concatenate([np.full(40, 7),
+                         rng.choice([k for k in range(30) if k != 7], 200)])
+    rs = rng.randint(0, 4, len(rk)).astype(np.int64)
+    rs[:40] = 2                     # key 7: only s != 2 finds a pair
+
+    def q(sess, condition=True):
+        l = sess.create_dataframe({"k": lk, "s": ls})
+        r = sess.create_dataframe({"rk": rk.astype(np.int64), "rs": rs})
+        return l.join(r, on=(["k"], ["rk"]), how=how,
+                      condition=(F.col("s") != F.col("rs"))
+                      if condition else None)
+
+    conf = {} if threshold is None else \
+        {"spark.rapids.tpu.sql.broadcastSizeThreshold": threshold}
+    sess = Session(dict(conf))
+    got = run_distributed(sess, q(sess), mesh=_mesh(4)).to_rows()
+    exp = q(Session(tpu_enabled=False)).collect()
+    plain = q(Session(tpu_enabled=False), condition=False).collect()
+    assert sorted(map(tuple, exp)) != sorted(map(tuple, plain))
+    _assert_rows_equal(got, exp)
+    assert sess.last_metrics["distributed.stageRetries"] >= 1
+
+
 def test_distributed_global_sort_order_preserved():
     """Global sort above a join+agg must come back in sorted order even
     though the range exchange below it executes as a host leaf (the

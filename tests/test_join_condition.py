@@ -1,0 +1,361 @@
+"""A semi or anti join with a condition, on the device (ISSUE 38).
+
+Spark plans a correlated ``EXISTS`` / ``NOT EXISTS`` whose correlation
+is not only equalities as a LeftSemi / LeftAnti join with a residual
+condition (TPC-H q21).  The device lays the key-matched pairs out by one
+sort and scans (``ops/kernels/join.py:pair_rows``), evaluates the
+condition on every pair and keeps a left row where any pair is TRUE
+(NULL is no match).  Each case here is answered three ways: the device
+path on the CPU backend, the host engine (``plan/physical.py``), and a
+plain loop over the pairs.  Then: the split of the stream side where the
+pairs do not fit, Q21's shape through a strict session with its
+counters, and the lowered text of the unconditioned programs, which
+must be the parent's.
+"""
+import hashlib
+
+import numpy as np
+import pytest
+
+import spark_rapids_tpu as srt
+from spark_rapids_tpu import types as T
+from spark_rapids_tpu.exec.joins import TpuHashJoinExec
+from spark_rapids_tpu.plan import functions as F
+
+STRICT = {"spark.rapids.tpu.sql.test.enabled": True}
+LEFT = T.Schema([T.Field("k", T.INT64, True), T.Field("s", T.INT64, True)])
+RIGHT = T.Schema([T.Field("k2", T.INT64, True),
+                  T.Field("s2", T.INT64, True)])
+
+
+def _many(n_left, n_right, key=7):
+    """One key with ``n_left`` x ``n_right`` pairs beside a few others."""
+    return ({"k": [key] * n_left + [1, 2],
+             "s": list(range(n_left)) + [5, 6]},
+            {"k2": [key] * n_right + [1, 3],
+             "s2": [0] * n_right + [5, 6]})
+
+
+CASES = {
+    # null keys on either side never match; a NULL s makes every pair
+    # of its row NULL (no match), a NULL s2 the pairs it stands in
+    "nulls": ({"k": [1, None, 2, 2, 3, None, 4],
+               "s": [10, 11, None, 12, 13, 14, 15]},
+              {"k2": [1, 2, None, 2, 3, 4, None],
+               "s2": [11, None, 12, 12, 13, 15, 15]}),
+    # a left key no right row has: anti keeps it, semi drops it
+    "no_key_match": ({"k": [1, 2, 3, 9], "s": [1, 2, 3, 4]},
+                     {"k2": [1, 2, 3], "s2": [5, 5, 5]}),
+    # every pair of key 1 fails the condition, of key 2 one holds
+    "every_pair_fails": ({"k": [1, 1, 2, 2], "s": [4, 4, 4, 5]},
+                         {"k2": [1, 1, 1, 2, 2], "s2": [4, 4, 4, 4, 4]}),
+    "duplicates_both_sides": (
+        {"k": [1, 1, 1, 2, 2, 3, 3, 3, 3], "s": [1, 2, 3, 1, 1, 5, 6, 7, 5]},
+        {"k2": [3, 1, 3, 1, 2, 2, 3], "s2": [5, 1, 5, 2, 1, 1, 6]}),
+    "empty_left": ({"k": [], "s": []}, {"k2": [1, 2], "s2": [1, 2]}),
+    "empty_right": ({"k": [1, 2, None], "s": [1, 2, 3]},
+                    {"k2": [], "s2": []}),
+    # 17 x 8 = 136 pairs of key 7: past a 128-slot bucket, into 256
+    "crosses_a_bucket": _many(17, 8),
+}
+
+
+def _expected(left, right, how):
+    """The left rows a plain loop over every pair keeps."""
+    keep = []
+    for k, s in zip(left["k"], left["s"]):
+        hit = any(k is not None and k == k2 and s is not None
+                  and s2 is not None and s != s2
+                  for k2, s2 in zip(right["k2"], right["s2"]))
+        if hit == (how == "semi"):
+            keep.append((k, s))
+    return sorted(keep, key=repr)
+
+
+def _pairs(left, right):
+    return sum(k is not None and k == k2
+               for k in left["k"] for k2 in right["k2"])
+
+
+def _query(sess, left, right, how, n_partitions=1):
+    lf = sess.create_dataframe(left, schema=LEFT, n_partitions=n_partitions)
+    rf = sess.create_dataframe(right, schema=RIGHT, n_partitions=1)
+    return lf.join(rf, on=(["k"], ["k2"]), how=how,
+                   condition=F.col("s") != F.col("s2"))
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("how", ["semi", "anti"])
+def test_device_equals_host_and_the_pairs(how, case):
+    left, right = CASES[case]
+    want = _expected(left, right, how)
+    host = _query(srt.Session(tpu_enabled=False), left, right, how)
+    assert sorted(host.collect(), key=repr) == want
+    sess = srt.Session(STRICT)
+    df = _query(sess, left, right, how)
+    assert f"* HashJoinExec [{how}, (NOT (s == s2))]" in df.explain()
+    assert sorted(df.collect(), key=repr) == want
+    m = sess.last_metrics
+    assert m["join.conditionJoins"] == 1
+    assert m["join.conditionPairs"] == _pairs(left, right)
+    slots = m["join.conditionPairSlots"]
+    assert slots >= max(m["join.conditionPairs"], 128)
+    assert slots & (slots - 1) == 0
+    if case == "crosses_a_bucket":
+        assert (m["join.conditionPairs"], slots) == (136 + 1, 256)
+
+
+@pytest.mark.parametrize("how", ["semi", "anti"])
+@pytest.mark.parametrize("cause", ["too_many_pairs", "injected_oom"])
+def test_the_stream_side_splits_where_the_pairs_do_not_fit(
+        how, cause, monkeypatch):
+    """Past ``_PAIR_SLOTS_MOST`` slots (here 128), or at an injected
+    split-and-retry OOM at the join's checkpoint, the left batch is
+    halved by ``with_split_retry`` and each half laid out alone: every
+    pair is still evaluated and the answer does not change."""
+    from spark_rapids_tpu.memory import retry as R
+
+    left, right = _many(17, 8)
+    if cause == "too_many_pairs":
+        monkeypatch.setattr(TpuHashJoinExec, "_PAIR_SLOTS_MOST", 128)
+    else:
+        real, fired = R.maybe_inject_oom, []
+
+        def inject(site="", nbytes=0):
+            if site.endswith("HashJoinExec.join") and not fired:
+                fired.append(site)
+                raise R.TpuSplitAndRetryOOM("injected", injected=True)
+            return real(site, nbytes)
+
+        monkeypatch.setattr(R, "maybe_inject_oom", inject)
+    sess = srt.Session(STRICT)
+    got = _query(sess, left, right, how).collect()
+    assert sorted(got, key=repr) == _expected(left, right, how)
+    m = sess.last_metrics
+    assert m["retry.numSplitRetries"] >= 1
+    assert m["join.conditionJoins"] >= 2
+    assert m["join.conditionPairs"] == _pairs(left, right)
+    if cause == "too_many_pairs":
+        assert m["join.conditionPairSlots"] <= 128 * m["join.conditionJoins"]
+
+
+def test_a_string_condition_reads_both_sides_rows():
+    """A condition over a string column of each side: the left one is
+    read at its pairs by row, the right one in key order."""
+    data_l = {"k": [1, 1, 2, 3, None], "a": ["x", "yy", "z", None, "x"]}
+    data_r = {"k2": [1, 1, 2, 3], "b": ["x", "x", "zz", "q"]}
+
+    def q(sess, how):
+        lf = sess.create_dataframe(data_l, n_partitions=1)
+        rf = sess.create_dataframe(data_r, n_partitions=1)
+        return lf.join(rf, on=(["k"], ["k2"]), how=how,
+                       condition=(F.col("a") != F.col("b"))
+                       & (F.col("k") < F.lit(3)))
+
+    for how in ("semi", "anti"):
+        host = sorted(q(srt.Session(tpu_enabled=False), how).collect(),
+                      key=repr)
+        device = sorted(q(srt.Session(STRICT), how).collect(), key=repr)
+        assert device == host
+    assert host == sorted([(1, "x"), (3, None), (None, "x")], key=repr)
+
+
+@pytest.mark.parametrize("how", ["semi", "anti"])
+def test_a_condition_over_every_width_of_column(how):
+    """The right side's reads travel as 32-bit words in one gather,
+    each float64 in one of its own: a condition over an int8, an int16,
+    a date, a float32 and a float64 of each side, NULLs among them."""
+    widths = [("b", T.INT8), ("h", T.INT16), ("d", T.DATE32),
+              ("f", T.FLOAT32), ("x", T.FLOAT64)]
+    rng = np.random.default_rng(3)
+    n = 60
+
+    def side(suffix, keys):
+        data = {"k" + suffix: keys}
+        for name, _ in widths:
+            vals = rng.integers(-3, 4, n).tolist()
+            data[name + suffix] = [None if i % 9 == 4 else
+                                   (float(v) if name in "fx" else v)
+                                   for i, v in enumerate(vals)]
+        return data, T.Schema(
+            [T.Field("k" + suffix, T.INT64, True)]
+            + [T.Field(name + suffix, dt, True) for name, dt in widths])
+
+    left, ls = side("", rng.integers(0, 6, n).tolist())
+    right, rs = side("2", rng.integers(0, 6, n).tolist())
+    col = F.col
+    cond = ((col("b") + col("h") < col("b2") + col("h2"))
+            | (col("d") > col("d2")) & (col("f") != col("f2"))
+            | (col("x") * 2 == col("x2")))
+
+    def q(sess):
+        lf = sess.create_dataframe(left, schema=ls, n_partitions=1)
+        rf = sess.create_dataframe(right, schema=rs, n_partitions=1)
+        return lf.join(rf, on=(["k"], ["k2"]), how=how, condition=cond)
+
+    host = sorted(q(srt.Session(tpu_enabled=False)).collect(), key=repr)
+    device = sorted(q(srt.Session(STRICT)).collect(), key=repr)
+    assert device == host and 0 < len(host) < n
+
+
+# ==========================================================================
+# Q21's shape through a strict session
+# ==========================================================================
+def _q21_tables(rng, n_orders=300):
+    lines = rng.poisson(4, n_orders)
+    order = np.repeat(np.arange(n_orders) * 4 + 1, lines)
+    n = len(order)
+    supp = rng.integers(1, 21, n)
+    commit = rng.integers(0, 60, n)
+    receipt = commit + rng.integers(-30, 31, n)
+    return {
+        "supplier": {"s_suppkey": list(range(1, 21)),
+                     "s_name": [f"Supplier#{i:09d}" for i in range(1, 21)],
+                     "s_nationkey": [i % 3 for i in range(1, 21)]},
+        "lineitem": {"l_orderkey": order.tolist(),
+                     "l_suppkey": supp.tolist(),
+                     "l_commitdate": commit.tolist(),
+                     "l_receiptdate": receipt.tolist()},
+        "orders": {"o_orderkey": (np.arange(n_orders) * 4 + 1).tolist(),
+                   "o_orderstatus": rng.choice(["F", "O"], n_orders)
+                   .tolist()},
+        "nation": {"n_nationkey": [0, 1, 2],
+                   "n_name": ["ALGERIA", "SAUDI ARABIA", "PERU"]}}
+
+
+def _q21(sess, data):
+    t = {name: sess.create_dataframe(cols, n_partitions=1)
+         for name, cols in data.items()}
+    col, lit = F.col, F.lit
+    late = t["lineitem"].filter(col("l_receiptdate") > col("l_commitdate"))
+    l1 = t["supplier"].join(late.select("l_orderkey", "l_suppkey"),
+                            on=(["s_suppkey"], ["l_suppkey"]))
+    l2 = t["lineitem"].select(col("l_orderkey").alias("l2_orderkey"),
+                              col("l_suppkey").alias("l2_suppkey"))
+    l3 = late.select(col("l_orderkey").alias("l3_orderkey"),
+                     col("l_suppkey").alias("l3_suppkey"))
+    return (l1.join(l2, on=(["l_orderkey"], ["l2_orderkey"]), how="semi",
+                    condition=col("l_suppkey") != col("l2_suppkey"))
+            .join(l3, on=(["l_orderkey"], ["l3_orderkey"]), how="anti",
+                  condition=col("l_suppkey") != col("l3_suppkey"))
+            .join(t["orders"].filter(col("o_orderstatus") == lit("F")),
+                  on=(["l_orderkey"], ["o_orderkey"]))
+            .join(t["nation"].filter(col("n_name") == lit("SAUDI ARABIA")),
+                  on=(["s_nationkey"], ["n_nationkey"]))
+            .group_by("s_name").agg(F.count("*").alias("numwait"))
+            .sort(col("numwait").desc(), col("s_name").asc()).limit(100))
+
+
+def test_q21_shape_in_strict_mode_counts_its_pairs():
+    data = _q21_tables(np.random.default_rng(21))
+    li = data["lineitem"]
+    o, s = np.array(li["l_orderkey"]), np.array(li["l_suppkey"])
+    late = np.array(li["l_receiptdate"]) > np.array(li["l_commitdate"])
+    same = o[:, None] == o[None, :]
+    semi_pairs = same[late].sum()
+    exists = (same & (s[:, None] != s[None, :]))[late].any(axis=1)
+    kept = np.flatnonzero(late)[exists]
+    anti_pairs = (same & late[None, :])[kept].sum()
+    waits = kept[~(same & late[None, :] & (s[:, None] != s[None, :]))[
+        kept].any(axis=1)]
+
+    sess = srt.Session(STRICT)
+    df = _q21(sess, data)
+    text = df.explain()
+    for how, side in (("semi", "l2"), ("anti", "l3")):
+        assert (f"* HashJoinExec [{how}, (NOT (l_suppkey == "
+                f"{side}_suppkey))]") in text
+    assert "!" not in "".join(ln.split()[0] for ln in text.splitlines()
+                              if "LocalScanExec" not in ln)
+    got = df.collect()
+    host = _q21(srt.Session(tpu_enabled=False), data).collect()
+    assert got == host and len(got) > 1
+    m = sess.last_metrics
+    assert m["join.conditionJoins"] == 2
+    assert m["join.conditionPairs"] == semi_pairs + anti_pairs
+    # the answer from the lines the loop keeps
+    status = dict(zip(data["orders"]["o_orderkey"],
+                      data["orders"]["o_orderstatus"]))
+    name = dict(zip(data["supplier"]["s_suppkey"],
+                    data["supplier"]["s_name"]))
+    nation = dict(zip(data["supplier"]["s_suppkey"],
+                      data["supplier"]["s_nationkey"]))
+    count = {}
+    for i in waits:
+        if status[o[i]] == "F" and nation[s[i]] == 1:
+            count[name[s[i]]] = count.get(name[s[i]], 0) + 1
+    assert got == sorted(count.items(), key=lambda kv: (-kv[1], kv[0]))
+
+
+# ==========================================================================
+# the unconditioned programs are the parent's
+# ==========================================================================
+#: sha256 of the lowered text (no debug info) of the unconditioned semi
+#: join's program and the inner join's two, for the fixed input of
+#: ``_lowered_join_programs``, recorded on the parent commit (1c8d886,
+#: PR 37) under this JAX: their compile-cache keys are the parent's
+PARENT_TEXT = {
+    "jax": "0.9.0",
+    "join_semi":
+        "6f330582d86b6041edcf72f149169cb1aa846d1fa7a3edd2b7a371ae0259e1c1",
+    "join_count":
+        "9d93e29a6eb7bdc4962d777b9cdf1deebbe38c26eab1f7da1d546c815919b028",
+    "join_expand":
+        "ebef0c94a3f265bdf3e5d9e4173ea8daf5dafc7beeda9b849dc4404574d12dd1"}
+
+
+def _walk(node):
+    yield node
+    for c in node.children:
+        yield from _walk(c)
+
+
+def _lowered_join_programs():
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.data.column import DeviceBatch, DeviceColumn
+
+    sess = srt.Session(STRICT)
+    left = sess.create_dataframe({"k": [1, 2], "s": [3, 4]}, schema=LEFT)
+    right = sess.create_dataframe({"k2": [1, 2], "s2": [3, 4]},
+                                  schema=RIGHT)
+
+    def batch(schema, n, rows):
+        cols = [DeviceColumn(f.dtype,
+                             (jnp.arange(n, dtype=jnp.int64) * (i + 3)) % 97,
+                             jnp.arange(n) % 11 != 0)
+                for i, f in enumerate(schema)]
+        return DeviceBatch(schema, cols, rows)
+
+    lb, rb = batch(LEFT, 1024, 1000), batch(RIGHT, 512, 500)
+    out = {}
+    for how in ("semi", "inner"):
+        df = left.join(right, on=(["k"], ["k2"]), how=how)
+        op, = [n for n in _walk(sess.physical_plan(df.plan))
+               if isinstance(n, TpuHashJoinExec)]
+        if how == "semi":
+            out["join_semi"] = op._semi_kernel._jfn.lower(lb, rb).as_text()
+            continue
+        out["join_count"] = op._count_kernel._jfn.lower(lb, rb).as_text()
+        pr, emit, r_extra, _ = op._count_kernel(lb, rb)
+        out["join_expand"] = op._expand_kernel._jfn.lower(
+            2048, lb, rb, pr, emit, r_extra).as_text()
+    return out
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    return _lowered_join_programs()
+
+
+@pytest.mark.parametrize("program", ["join_semi", "join_count",
+                                     "join_expand"])
+def test_unconditioned_programs_lower_to_the_parents_text(lowered, program):
+    import jax
+
+    text = lowered[program]
+    assert f"jit_{program}" in text and "loc(" not in text
+    if jax.__version__ != PARENT_TEXT["jax"]:
+        pytest.skip("the parent's text was recorded under jax "
+                    + PARENT_TEXT["jax"])
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_TEXT[program]
