@@ -386,6 +386,11 @@ class TpuHashJoinExec(TpuExec):
             return self._semi_kernel(lb, rb)
         pr, emit, r_extra, total = self._count_kernel(lb, rb)
         c_out = bucket_rows(int(total))  # host sync: output sizing
+        # the kernel asks the rule of the same array: ``emit``'s length
+        by_sort = J.expand_by_sort(emit.shape[0], c_out)
+        name = "join.expandBySort" if by_sort else "join.expandBySearch"
+        if name in self.metrics:
+            self.metrics[name].add(1)
         return self._expand_kernel(c_out, lb, rb, pr, emit, r_extra)
 
     #: join types whose stream (left) side is row-local — every output
@@ -442,6 +447,11 @@ class TpuHashJoinExec(TpuExec):
             # the pair programs run (one a stream batch)
             for name in ("join.conditionPairs", "join.conditionPairSlots",
                          "join.conditionJoins"):
+                self.metrics[name] = ctx.metrics.metric(name)
+        elif self.how not in ("semi", "anti"):
+            # query-wide: the expand programs run, by how each mapped its
+            # slots to their left rows (``J.expand_by_sort``)
+            for name in ("join.expandBySort", "join.expandBySearch"):
                 self.metrics[name] = ctx.metrics.metric(name)
 
 

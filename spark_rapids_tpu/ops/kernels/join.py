@@ -16,9 +16,12 @@ replacement is reversed).  Three stages, all static shapes:
      front and from the back) — no search, no second lookup.  Match
      counts are exact before any expansion — the same "size before
      materialize" contract cudf's join APIs give the reference.
-  3. expand: with an output capacity chosen from the exact count, a
-     searchsorted over the emit-prefix-sum turns slot t into its
-     (left row, k-th match) pair; gathers materialize the output.
+  3. expand: with an output capacity chosen from the exact count, slot
+     t becomes its (left row, k-th match) pair: by ``pair_rows``' sort
+     and carry scan and a second sort that moves the slots to the front,
+     or, where few slots stand over a wide left side, by a searchsorted
+     over the emit prefix sum (``expand_by_sort`` decides from the
+     shapes); gathers materialize the output.
 
 The only host sync is reading the match count to pick the output's
 power-of-two bucket (the same sync point the reference has when cudf
@@ -163,10 +166,61 @@ def emit_counts(p: Probe, how: str, l_rm, r_rm):
     return emit, r_extra, total
 
 
+#: what one ``searchsorted`` step of one output slot costs against one
+#: element of the sort path (``_slots_by_sort``: two sorts of ``nl +
+#: c_out`` words, one carry scan).  A whole expand on a v5e: the search
+#: 18-46 ns a slot and step, the sort 7-13 ns an element (2^21 slots over
+#: 2^21 left rows: 1524 ms against 54; over 2^23: 1693 against 102; 2^15
+#: slots over 2^23 rows: 17 against 60; PERF.md, section 6)
+_SEARCH_STEP_PER_SORTED_ROW = 3
+
+
+def expand_by_sort(nl: int, c_out: int) -> bool:
+    """Whether ``expand_pairs`` maps its ``c_out`` slots to their left
+    rows by sorting (``_slots_by_sort``, about ``nl + c_out`` elements
+    sorted twice) rather than by searching (``searchsorted``: every slot
+    gathered once a step, ``log2(nl)`` steps).  A function of the static
+    shapes alone: a few thousand slots over a large left side search, an
+    expansion as wide as its side sorts."""
+    steps = max(nl, 1).bit_length()
+    return nl + c_out < _SEARCH_STEP_PER_SORTED_ROW * c_out * steps
+
+
+@device_phase("join.expandSearch")
+def _slots_by_sort(p: Probe, emit, c_out: int):
+    """Every slot's left row and the place of its right row in
+    ``order_r`` (negative: the row has no match), without a search, from
+    ``pair_rows``' layout: one sort puts each row's slots after its
+    marker and a carry scan gives them the row's index and the offset to
+    its right rows; a second sort by place moves the slots, in order, to
+    the front (a slot past the emitted ones holds any row: the caller
+    masks it).  A row without a match carries a ``lo`` so far below zero
+    that every place it gives stays negative."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    nl = emit.shape[0]
+    n = nl + c_out
+    lo = jnp.where(p.cnt > 0, p.lo, -n - 1)
+    lay = _pair_layout(emit, lo, c_out, [jnp.arange(nl, dtype=jnp.int32)])
+    place = jnp.arange(n, dtype=jnp.int32)
+    _, li, right_pos = lax.sort(
+        (jnp.where(lay.valid, place, n), lay.carried[0], lay.right_pos),
+        num_keys=1, is_stable=False)
+    return li[:c_out], right_pos[:c_out]
+
+
 @device_phase("join.expandGather")
 def expand_pairs(p: Probe, emit, r_extra, c_out: int):
     """Turn slot t in [0, c_out) into its (lidx, ridx) pair; -1 marks
-    the null-extended side.  Returns (lidx, ridx, slot_valid)."""
+    the null-extended side.  Returns (lidx, ridx, slot_valid).  The
+    slot's left row comes by sort or by search as ``expand_by_sort``
+    says of the shapes; both give the same arrays."""
+    return _expand_pairs(p, emit, r_extra, c_out,
+                         expand_by_sort(emit.shape[0], c_out))
+
+
+def _expand_pairs(p: Probe, emit, r_extra, c_out: int, by_sort: bool):
     import jax.numpy as jnp
 
     nl = emit.shape[0]
@@ -174,14 +228,20 @@ def expand_pairs(p: Probe, emit, r_extra, c_out: int):
     offs = prefix_sum(emit)                      # inclusive
     m_left = offs[-1]
     t = jnp.arange(c_out, dtype=jnp.int64)
-    with device_phase("join.expandSearch"):
-        li = jnp.searchsorted(offs, t, side="right").astype(jnp.int32)
-    li_safe = jnp.clip(li, 0, nl - 1)
-    prev = offs[li_safe] - emit[li_safe]         # exclusive prefix
-    k = (t - prev).astype(jnp.int32)
-    in_left = t < m_left
-    matched = p.cnt[li_safe] > 0
-    ri_pos = jnp.clip(p.lo[li_safe] + k, 0, nr - 1)
+    if by_sort:
+        li_safe, right_pos = _slots_by_sort(p, emit, c_out)
+        in_left = t < m_left
+        matched = right_pos >= 0
+        ri_pos = jnp.clip(right_pos, 0, nr - 1)
+    else:
+        with device_phase("join.expandSearch"):
+            li = jnp.searchsorted(offs, t, side="right").astype(jnp.int32)
+        li_safe = jnp.clip(li, 0, nl - 1)
+        prev = offs[li_safe] - emit[li_safe]     # exclusive prefix
+        k = (t - prev).astype(jnp.int32)
+        in_left = t < m_left
+        matched = p.cnt[li_safe] > 0
+        ri_pos = jnp.clip(p.lo[li_safe] + k, 0, nr - 1)
     ridx = jnp.where(matched, p.order_r[ri_pos], -1)
     lidx = jnp.where(in_left, li_safe, -1)
     ridx = jnp.where(in_left, ridx, -1)
@@ -236,6 +296,12 @@ def pair_rows(emit, lo, c_out: int, carried=()) -> PairRows:
     restarting scan (``segment.scan_restarting`` keeping a segment's
     first value): no gather by row, no scatter.  Unique words, so the
     sort need not be stable; the rows without pairs sort last."""
+    return _pair_layout(emit, lo, c_out, carried)
+
+
+def _pair_layout(emit, lo, c_out: int, carried) -> PairRows:
+    """``pair_rows`` under its caller's scope (``expand_pairs`` maps its
+    slots with it inside ``join.expandSearch``)."""
     import jax.numpy as jnp
     from jax import lax
 

@@ -365,3 +365,181 @@ def test_count_program_holds_no_search_and_no_row_wide_scatter():
                 and e.outvars[0].aval.ndim == 2]
     assert by_order == [(3, n)], by_order
     assert sum(e.primitive.name == "sort" for e in eqns) == 4
+
+
+# --------------------------------------------------------------------------
+# the expand at the kernel, by sort and by search, against a numpy oracle
+# --------------------------------------------------------------------------
+def _expand_oracle(p, emit, r_extra, c_out):
+    """(lidx, ridx, slot_valid) of ``expand_pairs`` from the probe's
+    arrays: each left row's ``emit`` slots in row order, its k-th the
+    right row ``order_r[lo + k]`` (-1 where it has no match), then the
+    unmatched right rows in row order, then -1s."""
+    order_r, lo, cnt = (np.asarray(x) for x in (p.order_r, p.lo, p.cnt))
+    emit, r_extra = np.asarray(emit), np.asarray(r_extra)
+    li = np.repeat(np.arange(len(emit)), emit)
+    k = np.arange(len(li)) - np.repeat(np.cumsum(emit) - emit, emit)
+    ri = np.where(cnt[li] > 0, order_r[np.clip(lo[li] + k, 0, None)
+                                       % len(order_r)], -1)
+    extra = np.flatnonzero(r_extra)
+    lidx = np.concatenate([li, np.full(len(extra), -1)])
+    ridx = np.concatenate([ri, extra])
+    n = len(lidx)
+    assert n <= c_out
+    pad = np.full(c_out - n, -1)
+    return (np.concatenate([lidx, pad]), np.concatenate([ridx, pad]),
+            np.arange(c_out) < n)
+
+
+# (left rows, right rows, key maker, share of null keys, padding rows,
+#  slots past the bucket of the output's rows: a factor)
+EXPAND_CASES = {
+    "nothing_emitted": (900, 600, lambda rng, n, side: (
+        rng.integers(0, 500, n) * 2 + side).astype(np.int64), 0.0, 0, 1),
+    "every_left_row_unmatched": (700, 500, lambda rng, n, side: (
+        rng.integers(0, 500, n) * 2 + side).astype(np.int64), 0.1, 20, 1),
+    "c_out_far_above_the_rows": (600, 400, _ints(0, 300), 0.0, 0, 8),
+    "padding_and_null_keys": (1000, 800, _ints(0, 300), 0.2, 150, 1),
+    "one_left_row_owns_every_slot": (1, 3000, _ints(7, 8), 0.0, 0, 1),
+    "runs_cross_a_scan_block": (2600, 1900, _long_run(700, 1800, 4500),
+                                0.05, 40, 1),
+    "few_slots_over_a_wide_side": (30_000, 300, lambda rng, n, side: (
+        rng.permutation(60_000)[:n]).astype(np.int64), 0.0, 0, 1),
+}
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "right", "full"])
+@pytest.mark.parametrize("case", list(EXPAND_CASES))
+def test_expand_by_sort_and_by_search_equal_the_oracle_to_the_bit(case,
+                                                                 how):
+    """Both ways of mapping a slot to its left row give the oracle's
+    three arrays, and ``expand_pairs`` gives what the rule picks."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.data.column import bucket_rows
+    from spark_rapids_tpu.ops.kernels import join as J
+
+    nl, nr, make, null_share, padding, wider = EXPAND_CASES[case]
+    rng = np.random.default_rng(len(case))
+    sides = []
+    for side, n in enumerate((nl, nr)):
+        n_pad = bucket_rows(n + padding)
+        keys = np.zeros(n_pad, np.int64)
+        keys[:n] = make(rng, n, side)
+        valid = np.arange(n_pad) < n
+        valid[:n] &= rng.random(n) >= null_share
+        rows = np.arange(n_pad) < n + padding
+        sides.append((_device_key(keys, valid), jnp.asarray(rows)))
+    (lk, l_rm), (rk, r_rm) = sides
+    p = J.probe([lk], [rk], l_rm, r_rm)
+    emit, r_extra, total = J.emit_counts(p, how, l_rm, r_rm)
+    c_out = bucket_rows(int(total)) * wider
+    want = _expand_oracle(p, emit, r_extra, c_out)
+    if case == "nothing_emitted" and how == "inner":
+        assert int(total) == 0
+    if case == "one_left_row_owns_every_slot" and how == "inner":
+        assert int(total) == nr and (np.asarray(emit) > 0).sum() == 1
+    got = {}
+    for by_sort in (True, False):
+        got[by_sort] = [np.asarray(x) for x in J._expand_pairs(
+            p, emit, r_extra, c_out, by_sort)]
+        for a, b in zip(got[by_sort], want):
+            np.testing.assert_array_equal(a, b)
+    rule = J.expand_by_sort(emit.shape[0], c_out)
+    for a, b in zip(J.expand_pairs(p, emit, r_extra, c_out), got[rule]):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    # a left or full join emits every left row: never few slots
+    assert rule == (case != "few_slots_over_a_wide_side"
+                    or how in ("left", "full"))
+
+
+#: (nl, c_out) -> the mapping PERF.md's microbenchmark table says is the
+#: faster on a v5e (section 6): q21's ``supplier`` join, q16's
+#: inner join, a mesh shard after its exchange, a small expansion over a
+#: large side
+MEASURED = {(1 << 21, 1 << 21): True, (1 << 23, 1 << 21): True,
+            (1 << 24, 1 << 21): True, (1 << 23, 1 << 15): False}
+
+
+@pytest.mark.parametrize("shape", list(MEASURED), ids=str)
+def test_the_shape_rule_picks_what_the_chip_measured(shape):
+    from spark_rapids_tpu.ops.kernels import join as J
+
+    assert J.expand_by_sort(*shape) == MEASURED[shape]
+
+
+@pytest.mark.parametrize("nl, c_out", [(1 << 21, 1 << 21),
+                                       (1 << 23, 1 << 21),
+                                       (1 << 23, 1 << 15)])
+def test_expand_program_searches_only_where_the_rule_says(nl, c_out):
+    """At a q21- or q16-like shape the expand holds no ``searchsorted``
+    and no loop that gathers (a search is a ``while`` that gathers every
+    slot a step): its rows come from two sorts.  Where few slots stand
+    over a wide side the search is still there."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.ops.kernels import join as J
+
+    nr = 1 << 17
+
+    def arr(n, dt):
+        return jax.ShapeDtypeStruct((n,), jnp.dtype(dt))
+
+    p = J.Probe(arr(nr, jnp.int32), arr(nl, jnp.int32),
+                arr(nl, jnp.int32), arr(nr, jnp.bool_))
+    jaxpr = jax.make_jaxpr(lambda p, e, r: J.expand_pairs(p, e, r, c_out))(
+        p, arr(nl, jnp.int32), arr(nr, jnp.bool_)).jaxpr
+    eqns = list(jaxpr_eqns(jaxpr))
+    named = [e.params.get("name") for e in eqns
+             if e.primitive.name in ("pjit", "jit")]
+    gathering = [e for e in eqns if e.primitive.name in ("scan", "while")
+                 and any(i.primitive.name == "gather" for i in jaxpr_eqns(
+                     (e.params.get("jaxpr") or e.params["body_jaxpr"]).jaxpr))]
+    sorts = [e.invars[0].aval.shape for e in eqns
+             if e.primitive.name == "sort"]
+    if J.expand_by_sort(nl, c_out):
+        assert "searchsorted" not in named and not gathering, named
+        assert sorts == [(nl + c_out,)] * 2, sorts
+    else:
+        assert "searchsorted" in named and gathering
+        assert not sorts, sorts
+
+
+@pytest.mark.parametrize("wide", [False, True])
+def test_session_counts_the_expands_by_how_they_map_their_slots(
+        wide, monkeypatch):
+    """``join.expandBySort`` / ``join.expandBySearch`` in
+    ``Session.last_metrics`` count the inner join's expand programs by
+    the mapping ``expand_by_sort`` picked: a join whose output is as
+    wide as its left side sorts, a few matches over a wide side search.
+    The counter and the kernel, while it traces, ask the rule of the
+    same shapes."""
+    from spark_rapids_tpu.data.column import bucket_rows
+    from spark_rapids_tpu.ops.kernels import join as J
+
+    asked = []
+    rule = J.expand_by_sort
+
+    def recorded(nl, c_out):
+        asked.append((int(nl), int(c_out)))
+        return rule(nl, c_out)
+
+    monkeypatch.setattr(J, "expand_by_sort", recorded)
+    n = 20_000 if wide else 300
+    # column names of this test alone: a program no other test compiled,
+    # so the expand traces here
+    key, build = f"rule_probe_{n}", f"rule_build_{n}"
+    sess = srt.Session({"spark.rapids.tpu.sql.test.enabled": True})
+    left = sess.create_dataframe({key: list(range(n)), "a": list(range(n))},
+                                 n_partitions=1)
+    right = sess.create_dataframe({build: [3, 5, 7], "b": [1, 2, 3]},
+                                  n_partitions=1)
+    got = left.join(right, on=([key], [build])).collect()
+    assert sorted(got) == [(3, 3, 3, 1), (5, 5, 5, 2), (7, 7, 7, 3)]
+    m = sess.last_metrics
+    shape = (bucket_rows(n), bucket_rows(3))
+    assert asked == [shape, shape], asked
+    assert rule(*shape) == (not wide)
+    assert (m["join.expandBySort"], m["join.expandBySearch"]) == (
+        (0, 1) if wide else (1, 0))

@@ -9,8 +9,8 @@ condition on every pair and keeps a left row where any pair is TRUE
 path on the CPU backend, the host engine (``plan/physical.py``), and a
 plain loop over the pairs.  Then: the split of the stream side where the
 pairs do not fit, Q21's shape through a strict session with its
-counters, and the lowered text of the unconditioned programs, which
-must be the parent's.
+counters, and the lowered text of the programs the expand's sort path
+leaves alone, which must be what it was before the expand could sort.
 """
 import hashlib
 
@@ -288,20 +288,26 @@ def test_q21_shape_in_strict_mode_counts_its_pairs():
 
 
 # ==========================================================================
-# the unconditioned programs are the parent's
+# the programs the expand's sort path leaves alone keep their text
 # ==========================================================================
 #: sha256 of the lowered text (no debug info) of the unconditioned semi
-#: join's program and the inner join's two, for the fixed input of
-#: ``_lowered_join_programs``, recorded on the parent commit (1c8d886,
-#: PR 37) under this JAX: their compile-cache keys are the parent's
+#: join's program, the inner join's count, the conditional semi join's
+#: pair program, and the inner join's expand where few slots stand over
+#: a wide left side (the search path), for the fixed input of
+#: ``_lowered_join_programs``, recorded on commit 4264f4c (before the
+#: expand could sort) under this JAX: their compile-cache keys are that
+#: commit's.  The expand at 2048 slots over 1024 rows sorts where it
+#: searched then: its text changed, and it holds no search
 PARENT_TEXT = {
     "jax": "0.9.0",
     "join_semi":
         "6f330582d86b6041edcf72f149169cb1aa846d1fa7a3edd2b7a371ae0259e1c1",
     "join_count":
         "9d93e29a6eb7bdc4962d777b9cdf1deebbe38c26eab1f7da1d546c815919b028",
-    "join_expand":
-        "ebef0c94a3f265bdf3e5d9e4173ea8daf5dafc7beeda9b849dc4404574d12dd1"}
+    "join_semiPairs":
+        "ea092322aa30c2e3ad2be275f69cd3662595a339a159edf5c5f1012163f33287",
+    "join_expand_search":
+        "5e439e6374892356405d72e4248b71ef3c25605a5985420e167d763b117317b0"}
 
 
 def _walk(node):
@@ -329,17 +335,29 @@ def _lowered_join_programs():
 
     lb, rb = batch(LEFT, 1024, 1000), batch(RIGHT, 512, 500)
     out = {}
-    for how in ("semi", "inner"):
-        df = left.join(right, on=(["k"], ["k2"]), how=how)
+    for how in ("semi", "semiPairs", "inner"):
+        df = left.join(right, on=(["k"], ["k2"]), how=how[:4],
+                       condition=F.col("s") != F.col("s2")
+                       if how == "semiPairs" else None)
         op, = [n for n in _walk(sess.physical_plan(df.plan))
                if isinstance(n, TpuHashJoinExec)]
         if how == "semi":
             out["join_semi"] = op._semi_kernel._jfn.lower(lb, rb).as_text()
             continue
+        if how == "semiPairs":
+            pr, emit, _, _ = op._count_kernel(lb, rb)
+            out["join_semiPairs"] = op._pairs_kernel._jfn.lower(
+                2048, lb, rb, pr, emit).as_text()
+            continue
         out["join_count"] = op._count_kernel._jfn.lower(lb, rb).as_text()
         pr, emit, r_extra, _ = op._count_kernel(lb, rb)
         out["join_expand"] = op._expand_kernel._jfn.lower(
             2048, lb, rb, pr, emit, r_extra).as_text()
+        # few slots over a wide left side: the search stays
+        wide = batch(LEFT, 1 << 16, 1000)
+        pr, emit, r_extra, _ = op._count_kernel(wide, rb)
+        out["join_expand_search"] = op._expand_kernel._jfn.lower(
+            128, wide, rb, pr, emit, r_extra).as_text()
     return out
 
 
@@ -349,12 +367,20 @@ def lowered():
 
 
 @pytest.mark.parametrize("program", ["join_semi", "join_count",
-                                     "join_expand"])
+                                     "join_expand", "join_semiPairs",
+                                     "join_expand_search"])
 def test_unconditioned_programs_lower_to_the_parents_text(lowered, program):
     import jax
 
     text = lowered[program]
-    assert f"jit_{program}" in text and "loc(" not in text
+    name = "jit_join_expand" if program.startswith("join_expand") \
+        else f"jit_{program}"
+    assert name in text and "loc(" not in text
+    if program == "join_expand":
+        # the slots find their rows by sort: the search is gone
+        assert "searchsorted" not in text and "sort" in text
+        return
+    assert ("searchsorted" in text) == (program == "join_expand_search")
     if jax.__version__ != PARENT_TEXT["jax"]:
         pytest.skip("the parent's text was recorded under jax "
                     + PARENT_TEXT["jax"])

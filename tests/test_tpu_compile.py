@@ -491,7 +491,9 @@ def test_join_probe_and_expand_compile_for_four_chips(topo, as_tpu):
     scans start their carries from the inputs and nothing in it
     scatters, so the chip's compiler takes it (a scatter whose indices
     and updates both come from an iota aborts its fusion pass); the
-    program holds the lexsort's two sorts and the probe's two."""
+    program holds the lexsort's two sorts and the probe's two, and where
+    the expand maps its slots by sort (as many slots as left rows) that
+    mapping's two."""
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -519,7 +521,8 @@ def test_join_probe_and_expand_compile_for_four_chips(topo, as_tpu):
                           out_specs=P("dp"))
     text = _compile(stage, key(), key(), stacked(np.bool_),
                     stacked(np.bool_)).as_text()
-    assert text.count(" sort(") == 4, text.count(" sort(")
+    assert J.expand_by_sort(ROWS, ROWS)
+    assert text.count(" sort(") == 6, text.count(" sort(")
 
 
 # --------------------------------------------------------------------------
