@@ -102,8 +102,9 @@ class TpuHashJoinExec(TpuExec):
                schema_signature(right.schema),
                schema_signature(plan.schema))
         twin = self.kernel_twin()
-        self._count_kernel = jit_kernel(twin._count,
-                                        key=sig + ("count",))
+        self._count_kernel = jit_kernel(
+            twin._count_outer if self._outer else twin._count,
+            key=sig + ("count",))
         self._expand_kernel = jit_kernel(twin._expand, static_argnums=(0,),
                                          key=sig + ("expand",))
         self._semi_kernel = jit_kernel(twin._semi_anti,
@@ -119,6 +120,12 @@ class TpuHashJoinExec(TpuExec):
         """A semi/anti join with a condition: it evaluates the condition
         on its key-matched pairs (``_semi_pairs``)."""
         return self.condition is not None and self.how in ("semi", "anti")
+
+    @property
+    def _outer(self) -> bool:
+        """A join that emits a left row without a match once, its right
+        side null."""
+        return self.how in ("left", "full")
 
     @property
     def schema(self):
@@ -281,6 +288,17 @@ class TpuHashJoinExec(TpuExec):
                                              lb.row_mask(), rb.row_mask())
         return pr, emit, r_extra, total
 
+    def _count_outer(self, lb: DeviceBatch, rb: DeviceBatch):
+        """``_count`` of a left or full join, its total beside the left
+        rows it emits with a null right side: one int64[2], so the one
+        readback that sizes the output reads both."""
+        import jax.numpy as jnp
+
+        pr, emit, r_extra, total = self._count(lb, rb)
+        with device_phase("join.emitCounts"):
+            unmatched = (lb.row_mask() & (pr.cnt == 0)).sum(dtype=jnp.int64)
+        return pr, emit, r_extra, jnp.stack([total, unmatched])
+
     def _expand(self, c_out: int, lb: DeviceBatch, rb: DeviceBatch,
                 pr: J.Probe, emit, r_extra) -> DeviceBatch:
         import jax.numpy as jnp
@@ -385,6 +403,12 @@ class TpuHashJoinExec(TpuExec):
         if self.how in ("semi", "anti"):
             return self._semi_kernel(lb, rb)
         pr, emit, r_extra, total = self._count_kernel(lb, rb)
+        if self._outer:
+            total, unmatched = np.asarray(total)  # host sync: both counts
+            for name, n in (("join.outerJoins", 1),
+                            ("join.unmatchedLeftRows", int(unmatched))):
+                if name in self.metrics:
+                    self.metrics[name].add(n)
         c_out = bucket_rows(int(total))  # host sync: output sizing
         # the kernel asks the rule of the same array: ``emit``'s length
         by_sort = J.expand_by_sort(emit.shape[0], c_out)
@@ -453,6 +477,11 @@ class TpuHashJoinExec(TpuExec):
             # slots to their left rows (``J.expand_by_sort``)
             for name in ("join.expandBySort", "join.expandBySearch"):
                 self.metrics[name] = ctx.metrics.metric(name)
+            if self._outer:
+                # query-wide: the left/full join programs run, and the
+                # left rows they emitted with a null right side
+                for name in ("join.outerJoins", "join.unmatchedLeftRows"):
+                    self.metrics[name] = ctx.metrics.metric(name)
 
 
 class TpuShuffledHashJoinExec(TpuHashJoinExec):

@@ -20,6 +20,7 @@ import numpy as np
 
 from .. import types as T
 from ..data.column import DeviceColumn, HostColumn
+from ..utils.tracing import device_phase
 from .expression import (
     Expression,
     Literal,
@@ -471,18 +472,23 @@ class Like(Expression):
         return HostColumn(T.BOOL, out, c.validity)
 
     def eval_tpu(self, batch):
-        import jax.numpy as jnp
-
         c = as_device_column(self.children[0].eval_tpu(batch),
                              batch.padded_rows)
-        bm, ln = c.data, c.lengths
+        with device_phase("strings.match"):
+            ok = self._match_device(c.data, c.lengths)
+        return DeviceColumn(T.BOOL, ok, c.validity)
+
+    def _match_device(self, bm, ln):
+        """bool[n]: the row's logical bytes match the pattern (null and
+        padding rows are the caller's, through the validity)."""
+        import jax.numpy as jnp
+
         segs = self._segs
         n = bm.shape[0]
         if len(segs) == 1:
             # no wildcard at all: exact (length + prefix) equality
             needle = segs[0]
-            ok = sk.startswith(bm, ln, needle) & (ln == len(needle))
-            return DeviceColumn(T.BOOL, ok, c.validity)
+            return sk.startswith(bm, ln, needle) & (ln == len(needle))
         first, last, mids = segs[0], segs[-1], segs[1:-1]
         ok = (sk.startswith(bm, ln, first) if first
               else jnp.ones((n,), dtype=jnp.bool_))
@@ -494,11 +500,9 @@ class Like(Expression):
             ok = ok & (pos1 > 0)
             cursor = jnp.where(pos1 > 0, pos1 - 1 + len(seg), cursor)
         if last:
-            ok = ok & sk.endswith(bm, ln, last) & \
+            return ok & sk.endswith(bm, ln, last) & \
                 (ln - len(last) >= cursor)
-        else:
-            ok = ok & (ln >= cursor)
-        return DeviceColumn(T.BOOL, ok, c.validity)
+        return ok & (ln >= cursor)
 
     @property
     def tpu_supported(self):

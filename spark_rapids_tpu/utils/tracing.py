@@ -32,7 +32,7 @@ DEVICE_PHASES = (
     "join.probe", "join.emitCounts", "join.expandSearch",
     "join.expandGather", "join.pairRows", "join.condition",
     "shuffle.hashPids", "shuffle.packedBuild", "shuffle.packedSlice",
-    "shuffle.trim",
+    "shuffle.trim", "strings.match",
 )
 
 #: ``segment.reduce_sorted``'s reads of one width stand under

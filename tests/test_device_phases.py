@@ -49,7 +49,7 @@ KERNELS = {
     "shuffle_packedSlice": {"shuffle.packedSlice", "reorder"},
     "shuffle_trim": {"shuffle.trim"},
     "fused__compute": {"TpuFilter", "TpuProject", "TpuFusedSegment",
-                       "gather.partitionOrder", "reorder"},
+                       "gather.partitionOrder", "reorder", "strings.match"},
     "mesh_stage": {"TpuShuffleWrite", "shuffle.hashPids",
                    "shuffle.packedBuild", "TpuShuffledHashJoinExec",
                    "join.probe", "join.expandSearch", "TpuHashAggregate",
@@ -95,6 +95,13 @@ def dispatched():
         fused = fact.filter(fact["v"] > 0.1).select(
             (fact["v"] * 2).alias("x"), fact["k"]).filter(f.col("x") < 1.5)
         assert fused.collect()
+        notes = sess.create_dataframe({
+            "k": list(range(40)),
+            "s": [f"{'special' if i % 3 else 'plain'} requests {i}"
+                  for i in range(40)]})
+        liked = notes.filter(~notes["s"].like("%special%requests%")).select(
+            (notes["k"] * 2).alias("x")).filter(f.col("x") < 60)
+        assert len(liked.collect()) == 10
         from spark_rapids_tpu.parallel.runner import run_distributed
 
         mesh = srt.Session({
