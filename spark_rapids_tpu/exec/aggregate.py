@@ -187,7 +187,8 @@ class TpuHashAggregateExec(TpuExec):
         pad_sorted = idx < rm.sum()
         if nkeys:
             order = seg.lexsort_device(key_cols, pad_valid=rm)
-            sorted_keys = [G.gather_column(c, order) for c in key_cols]
+            with device_phase("reorder"):
+                sorted_keys = G.take_rows(key_cols, order)
             change = seg.segment_change_device(sorted_keys,
                                                pad_valid=pad_sorted)
             n_real = (change & pad_sorted).sum().astype(jnp.int32)

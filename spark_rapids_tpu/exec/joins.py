@@ -28,7 +28,7 @@ from ..memory import retry as R
 from ..ops.cast import Cast
 from ..ops.expression import Expression, as_device_column
 from ..ops.kernels import join as J
-from ..ops.kernels.gather import compact
+from ..ops.kernels.gather import compact, take_rows
 from ..utils import metrics as M
 from ..utils.tracing import device_phase, trace_range
 from .base import DevicePartitionedData, RequireSingleBatch, TpuExec
@@ -304,8 +304,9 @@ class TpuHashJoinExec(TpuExec):
         import jax.numpy as jnp
 
         lidx, ridx, slot_valid = J.expand_pairs(pr, emit, r_extra, c_out)
-        cols = (J.gather_side(lb.columns, lidx, slot_valid)
-                + J.gather_side(rb.columns, ridx, slot_valid))
+        with device_phase("reorder"):     # idx -1: a null side
+            cols = (take_rows(lb.columns, lidx, lidx >= 0)
+                    + take_rows(rb.columns, ridx, ridx >= 0))
         num_rows = slot_valid.sum().astype(jnp.int32)
         out = DeviceBatch(self._schema, cols, num_rows)
         if self.condition is not None:
@@ -351,16 +352,16 @@ class TpuHashJoinExec(TpuExec):
                                    lay.carried[2 * j + 1], None)
         with device_phase("join.condition"):
             if wide:
-                taken = J.take_rows([lb.columns[i] for i in wide],
-                                    lay.carried[-1])
+                taken = take_rows([lb.columns[i] for i in wide],
+                                  lay.carried[-1])
                 for i, c in zip(wide, taken):
                     cols[i] = c
             right = [i for i in reads if i >= n_left]
             # the right columns in key order first (nr rows), then read
             # at the pairs
-            in_order = J.take_rows([rb.columns[i - n_left] for i in right],
-                                   pr.order_r)
-            for i, c in zip(right, J.take_rows(in_order, lay.right_pos)):
+            in_order = take_rows([rb.columns[i - n_left] for i in right],
+                                 pr.order_r)
+            for i, c in zip(right, take_rows(in_order, lay.right_pos)):
                 cols[i] = c
             for i, c in enumerate(cols):
                 if c is None:       # read by nothing: dead code

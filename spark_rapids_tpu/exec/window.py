@@ -34,7 +34,7 @@ from ..ops.kernels import segment as seg
 from ..ops.windowexprs import (DenseRank, Rank, RowNumber,
                                WindowExpression)
 from ..utils import metrics as M
-from ..utils.tracing import trace_range
+from ..utils.tracing import device_phase, trace_range
 from .base import DevicePartitionedData, RequireSingleBatch, TpuExec
 
 
@@ -131,7 +131,8 @@ class TpuWindowExec(TpuExec):
             order = jnp.arange(n, dtype=jnp.int32)
         rm_s = rm[order]
         if part_cols:
-            sorted_parts = [G.gather_column(c, order) for c in part_cols]
+            with device_phase("reorder"):
+                sorted_parts = G.take_rows(part_cols, order)
             seg_ids = seg.segment_ids_device(sorted_parts, pad_valid=rm_s)
         else:
             # padding rows still need their own segments
@@ -152,7 +153,8 @@ class TpuWindowExec(TpuExec):
             valid = rm_s
         elif isinstance(func, (Rank, DenseRank)):
             if order_cols:
-                sorted_all = [G.gather_column(c, order) for c in all_cols]
+                with device_phase("reorder"):
+                    sorted_all = G.take_rows(all_cols, order)
                 ok_ids = seg.segment_ids_device(sorted_all,
                                                 pad_valid=rm_s)
             else:  # no ordering: every row is its own tie group

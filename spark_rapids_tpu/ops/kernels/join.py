@@ -257,22 +257,6 @@ def _expand_pairs(p: Probe, emit, r_extra, c_out: int, by_sort: bool):
     return lidx, ridx, slot_valid
 
 
-@device_phase("reorder")
-def gather_side(columns: List[DeviceColumn], idx, slot_valid
-                ) -> List[DeviceColumn]:
-    """Gather one side's columns by row index; idx -1 → null."""
-    import jax.numpy as jnp
-
-    out = []
-    for c in columns:
-        safe = jnp.clip(idx, 0, c.data.shape[0] - 1)
-        data = c.data[safe]
-        validity = c.validity[safe] & (idx >= 0) & slot_valid
-        lengths = c.lengths[safe] if c.lengths is not None else None
-        out.append(DeviceColumn(c.dtype, data, validity, lengths))
-    return out
-
-
 class PairRows(NamedTuple):
     """Every left row's key-matched pairs laid out in one array of
     ``nl + c_out`` places: a row's marker, then one place for each of its
@@ -344,57 +328,3 @@ def any_pair(hit, first, emit):
                     hit.shape[0] - 1)
     at = hits[ends]
     return at[nl:] > at[:nl]
-
-
-def _words(x):
-    """``x`` ([n]) as rows of 32-bit words ([k, n] uint32) and the way
-    back; None where its bits cannot travel so (a float64, which the
-    chip holds as two f32 and will not bitcast; a 2-D string matrix)."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    dt = x.dtype
-    if x.ndim != 1 or jnp.issubdtype(dt, jnp.floating) and dt.itemsize > 4:
-        return None
-    if dt == jnp.bool_:
-        return x.astype(jnp.uint32)[None], lambda w: w[0] != 0
-    if dt.itemsize == 8:
-        return (lax.bitcast_convert_type(x, jnp.uint32).T,
-                lambda w: lax.bitcast_convert_type(w.T, dt))
-    if dt.itemsize < 4:
-        return (lax.bitcast_convert_type(x.astype(jnp.int32), jnp.uint32)[
-            None], lambda w: lax.bitcast_convert_type(w[0], jnp.int32)
-            .astype(dt))
-    return (lax.bitcast_convert_type(x, jnp.uint32)[None],
-            lambda w: lax.bitcast_convert_type(w[0], dt))
-
-
-def take_rows(columns: List[DeviceColumn], idx) -> List[DeviceColumn]:
-    """The rows ``idx`` of each column, under the caller's scope (the
-    condition's pair-side reads stand in ``join.condition``, not in
-    ``reorder``).  Every array whose bits fit 32-bit words travels in
-    ONE stacked gather (a TPU gather is priced by its indices, not by
-    the width of its rows: PERF.md, PR 29); a float64 or a string's
-    bytes each in one of its own."""
-    import jax.numpy as jnp
-
-    parts = [a for c in columns for a in (c.data, c.validity, c.lengths)]
-    rows, backs, out, at = [], {}, {}, 0
-    for i, a in enumerate(parts):
-        if a is None:
-            continue
-        w = _words(a)
-        if w is None:
-            out[i] = a[jnp.clip(idx, 0, a.shape[0] - 1)]
-        else:
-            backs[i] = (at, w[0].shape[0], w[1])
-            rows.append(w[0])
-            at += w[0].shape[0]
-    if rows:
-        stack = jnp.concatenate(rows)
-        got = stack[:, jnp.clip(idx, 0, stack.shape[1] - 1)]
-        for i, (at, k, back) in backs.items():
-            out[i] = back(got[at:at + k])
-    return [DeviceColumn(c.dtype, out[3 * j], out[3 * j + 1],
-                         out.get(3 * j + 2))
-            for j, c in enumerate(columns)]

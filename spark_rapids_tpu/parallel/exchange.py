@@ -25,7 +25,7 @@ from __future__ import annotations
 from typing import List, Tuple
 
 from ..data.column import DeviceBatch, DeviceColumn
-from ..ops.kernels.gather import partition_order
+from ..ops.kernels.gather import compact, take_rows
 from ..utils import hashing
 from ..utils.tracing import device_phase
 
@@ -75,19 +75,6 @@ def bucket_rows(pids, num_parts: int, capacity: int):
     return rows, valid
 
 
-@device_phase("shuffle.packedBuild")
-def _gather_tiles(batch: DeviceBatch, rows, valid) -> List[DeviceColumn]:
-    """Gather every column into [P, C, ...] tiles; validity AND'd with
-    the lane mask."""
-    tiles = []
-    for c in batch.columns:
-        data = c.data[rows]
-        validity = c.validity[rows] & valid
-        lengths = c.lengths[rows] if c.lengths is not None else None
-        tiles.append(DeviceColumn(c.dtype, data, validity, lengths))
-    return tiles
-
-
 def gather_replicate(batch: DeviceBatch, axis_name: str) -> DeviceBatch:
     """Replicate every shard's rows onto every device — the mesh form of
     the broadcast exchange (GpuBroadcastExchangeExec.scala:215: build
@@ -102,24 +89,8 @@ def gather_replicate(batch: DeviceBatch, axis_name: str) -> DeviceBatch:
         lengths = (jax.lax.all_gather(c.lengths, axis_name, tiled=True)
                    if c.lengths is not None else None)
         cols.append(DeviceColumn(c.dtype, data, validity, lengths))
-    return _compact(cols, present, batch.schema)
-
-
-def _compact(batch_cols: List[DeviceColumn], present, schema) -> DeviceBatch:
-    """Stable-move present rows to the front so the result is a normal
-    DeviceBatch (logical rows first, padding after)."""
-    import jax.numpy as jnp
-
-    order = partition_order(present)
-    num_rows = present.sum().astype(jnp.int32)
-    out = []
-    with device_phase("reorder"):
-        for c in batch_cols:
-            data = c.data[order]
-            validity = c.validity[order] & present[order]
-            lengths = c.lengths[order] if c.lengths is not None else None
-            out.append(DeviceColumn(c.dtype, data, validity, lengths))
-    return DeviceBatch(schema, out, num_rows)
+    return compact(DeviceBatch(batch.schema, cols, present.shape[0]),
+                   present)
 
 
 def collective_exchange(batch: DeviceBatch, pids, num_parts: int,
@@ -135,7 +106,8 @@ def collective_exchange(batch: DeviceBatch, pids, num_parts: int,
 
     cap = capacity or batch.padded_rows
     rows, valid = bucket_rows(pids, num_parts, cap)
-    tiles = _gather_tiles(batch, rows, valid)
+    with device_phase("shuffle.packedBuild"):
+        tiles = take_rows(batch.columns, rows, valid)
 
     recv_cols = []
     for c in tiles:
@@ -154,7 +126,8 @@ def collective_exchange(batch: DeviceBatch, pids, num_parts: int,
 
     lane_present = jax.lax.all_to_all(valid, axis_name, 0, 0, tiled=True)
     present = lane_present.reshape(num_parts * cap)
-    return _compact(recv_cols, present, batch.schema)
+    return compact(DeviceBatch(batch.schema, recv_cols, num_parts * cap),
+                   present)
 
 
 def squeeze_leading(b: DeviceBatch) -> DeviceBatch:

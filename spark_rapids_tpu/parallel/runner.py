@@ -49,6 +49,7 @@ from ..fault.errors import (TpuPayloadCorruption, TpuStageCrash,
 from ..fault.injector import maybe_inject_fault
 from ..fault.stats import GLOBAL as _fault_stats
 from ..memory.semaphore import DeviceSemaphoreTimeout
+from ..ops.kernels.gather import compact
 from ..telemetry import spans as tspans
 from ..telemetry.events import emit_event
 from ..utils import hashing
@@ -907,7 +908,7 @@ class DistributedRunner:
                 [b.columns[i].lengths for b in batches])
                 if batches[0].columns[i].lengths is not None else None)
             cols.append(DeviceColumn(dtype, data, validity, lengths))
-        return X._compact(cols, present, schema)
+        return compact(DeviceBatch(schema, cols, present.shape[0]), present)
 
     def _lower(self, node, env: Dict, aux: Dict, caps: Dict,
                used_caps: Dict) -> DeviceBatch:

@@ -38,7 +38,7 @@ from contextlib import contextmanager
 from typing import Dict
 
 from ..data.column import DeviceBatch, DeviceColumn
-from ..ops.kernels.gather import gather_batch, gather_column
+from ..ops.kernels.gather import gather_batch
 from ..utils.tracing import device_phase
 
 
@@ -137,10 +137,8 @@ def packed_slice(block: DeviceBatch, start, count) -> DeviceBatch:
     padded = block.padded_rows
     lane = jnp.arange(padded, dtype=jnp.int32)
     idx = jnp.clip(start + lane, 0, max(padded - 1, 0))
-    mask = lane < count
-    cols = [gather_column(c, idx, mask) for c in block.columns]
-    return DeviceBatch(block.schema, cols,
-                       jnp.asarray(count, dtype=jnp.int32))
+    return gather_batch(block, idx, jnp.asarray(count, dtype=jnp.int32),
+                        lane < count)
 
 
 @device_phase("shuffle.trim")

@@ -19,7 +19,9 @@ name without its fingerprint) and key — the op's **phase** (innermost
 **operator** (outermost ``Tpu...`` scope), JAX **primitive** (last
 component of ``tf_op``), **source** line or **tier** (``segment.reduce_sorted``'s
 ``readTier.<rows>`` scope: which read width a group-by's segment count
-chose on the device; ``--by tier --within segments``) — seconds, op count,
+chose on the device, ``--by tier --within segments``; ``gather.take_rows``'
+``readWords.<k>`` and ``readOwn``: a stacked read of ``k`` 32-bit words or
+a part read alone, ``--by tier --within reorder``) — seconds, op count,
 ``bytes_accessed``, GB/s and the share of the plane's peak bandwidth.
 Seconds are leaf seconds: an event that wraps others (a ``while`` around
 its body) adds only the time in which none of them ran, so the keys of
@@ -47,7 +49,7 @@ import struct
 import sys
 from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
-from ..utils.tracing import DEVICE_PHASES, READ_TIER
+from ..utils.tracing import DEVICE_PHASES, READ_TAGS
 
 #: the key of ops that stand under no scope of the asked kind
 UNSCOPED = "(unscoped)"
@@ -351,8 +353,10 @@ def operator_of(tf_op: str) -> str:
 
 def tier_of(tf_op: str) -> str:
     """``.../segments/cond/branch_0_fun/readTier.65536/gather:`` ->
-    ``readTier.65536``: the width of the read the op belongs to."""
-    return next((c for c in scopes(tf_op) if c.startswith(READ_TIER)),
+    ``readTier.65536``: the width of the read the op belongs to;
+    ``.../reorder/readWords.6/gather:`` -> ``readWords.6`` (one stacked
+    gather of six 32-bit words), ``readOwn`` (a part read alone)."""
+    return next((c for c in scopes(tf_op) if c.startswith(READ_TAGS)),
                 UNSCOPED if tf_op else NO_PATH)
 
 

@@ -39,6 +39,13 @@ DEVICE_PHASES = (
 #: ``readTier.<rows>``, inside ``segments``.  No phase: it takes no second
 #: out of ``segments``; ``telemetry/device_trace.py --by tier`` counts by it
 READ_TIER = "readTier."
+#: ``ops/kernels/gather.take_rows``' reads, under their caller's phase: one
+#: stacked gather of ``<k>`` 32-bit words, or a part read by a gather of
+#: its own (a float64, a string's bytes).  No phases either
+READ_WORDS = "readWords."
+READ_OWN = "readOwn"
+#: the scopes ``--by tier`` counts by
+READ_TAGS = (READ_TIER, READ_WORDS, READ_OWN)
 
 #: the span a request opens first (session.py, parallel/runner.py)
 QUERY_SPAN = "Query"

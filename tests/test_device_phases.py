@@ -151,7 +151,7 @@ def test_kernel_names_its_phases_in_the_lowered_text(dispatched, program):
     for scope in named:
         if "." in scope or scope.startswith("Tpu"):
             assert scope in tracing.DEVICE_PHASES or \
-                scope.startswith(tracing.READ_TIER) or \
+                scope.startswith(tracing.READ_TAGS) or \
                 device_trace.operator_of(f"jit(x)/{scope}/op:") == scope
 
 
@@ -314,6 +314,49 @@ def test_read_tiers_within_segments_by_seconds_and_events():
     text = "\n".join(device_trace.render(trace, by="tier",
                                          within="segments"))
     assert "by tier within segments" in text and "readTier.65536" in text
+
+
+def test_stacked_and_own_reads_within_reorder_by_seconds_and_events():
+    """``--by tier --within reorder``: ``take_rows``' stacked gathers
+    (``readWords.<k>``) and the parts read alone (``readOwn``: a float64,
+    a string's bytes), each under its caller's phase; the stack's fill
+    and the rest of the phase stand outside both."""
+    def op(start, end, path):
+        return device_trace.Op(start, end, "jit_fused", path, "", 8, "")
+
+    base = "jit(fused)/TpuFilter/reorder"
+    trace = device_trace.DeviceTrace({0: device_trace.Device(100.0, [
+        op(0, 10, f"{base}/concatenate:"),
+        op(10, 70, f"{base}/readWords.6/gather:"),
+        op(70, 90, f"{base}/readOwn/gather:"),
+        op(90, 100, f"{base}/readOwn/gather:"),
+        op(100, 130, "jit(fused)/TpuFilter/join.condition/readWords.3/"
+                     "gather:"),
+        op(130, 140, "jit(fused)/TpuFilter/gather.partitionOrder/scatter:"),
+    ], [(0, 140, "jit_fused")])}, [(0, 140)])
+    ps = 1e-12
+    assert device_trace.tier_of(f"{base}/readWords.6/gather:") == \
+        "readWords.6"
+    assert device_trace.tier_of(f"{base}/readOwn/gather:") == "readOwn"
+    assert device_trace.tier_of(f"{base}/concatenate:") == \
+        device_trace.UNSCOPED
+    # the tags are no phases: the read stays its caller's
+    assert device_trace.phase_of(f"{base}/readWords.6/gather:") == "reorder"
+    rows = device_trace.reduce(trace, 0, by="tier", within="reorder",
+                               window=trace.window)["jit_fused"]
+    assert set(rows) == {"readWords.6", "readOwn", device_trace.UNSCOPED}
+    assert rows["readWords.6"].seconds == pytest.approx(60 * ps)
+    assert rows["readWords.6"].ops == 1
+    assert rows["readOwn"].seconds == pytest.approx(30 * ps)
+    assert rows["readOwn"].ops == 2
+    assert rows[device_trace.UNSCOPED].seconds == pytest.approx(10 * ps)
+    by_phase = device_trace.reduce(trace, 0, window=trace.window)["jit_fused"]
+    assert by_phase["reorder"].seconds == pytest.approx(
+        sum(r.seconds for r in rows.values()))
+    assert by_phase["join.condition"].seconds == pytest.approx(30 * ps)
+    text = "\n".join(device_trace.render(trace, by="tier", within="reorder"))
+    assert "by tier within reorder" in text and "readWords.6" in text \
+        and "readOwn" in text
 
 
 def test_recorded_phases_innermost_scope_while_and_the_unscoped_rest():

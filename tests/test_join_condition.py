@@ -294,20 +294,22 @@ def test_q21_shape_in_strict_mode_counts_its_pairs():
 #: join's program, the inner join's count, the conditional semi join's
 #: pair program, and the inner join's expand where few slots stand over
 #: a wide left side (the search path), for the fixed input of
-#: ``_lowered_join_programs``, recorded on commit 4264f4c (before the
-#: expand could sort) under this JAX: their compile-cache keys are that
-#: commit's.  The expand at 2048 slots over 1024 rows sorts where it
-#: searched then: its text changed, and it holds no search
+#: ``_lowered_join_programs``, under this JAX.  The count's is commit
+#: 4264f4c's (before the expand could sort): its compile-cache key is
+#: that commit's.  The other three read their rows by one stacked gather
+#: (``gather.take_rows``) since, and are recorded from there.  The expand
+#: at 2048 slots over 1024 rows sorts where it searched then: its text
+#: changed, and it holds no search
 PARENT_TEXT = {
     "jax": "0.9.0",
     "join_semi":
-        "6f330582d86b6041edcf72f149169cb1aa846d1fa7a3edd2b7a371ae0259e1c1",
+        "c08538ff76f7e639a1624e4fdadaa8ed39f94f4213669270c5388908301d0412",
     "join_count":
         "9d93e29a6eb7bdc4962d777b9cdf1deebbe38c26eab1f7da1d546c815919b028",
     "join_semiPairs":
-        "ea092322aa30c2e3ad2be275f69cd3662595a339a159edf5c5f1012163f33287",
+        "6da666a0714f41fbd833913d3bef9dfeccf9aac34c76039d07c8953e1b7dcb5a",
     "join_expand_search":
-        "5e439e6374892356405d72e4248b71ef3c25605a5985420e167d763b117317b0"}
+        "ae53760b12cd592830859cc776231af5be2da174e8f39db864bc0a161dcec122"}
 
 
 def _walk(node):
