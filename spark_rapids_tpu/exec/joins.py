@@ -77,6 +77,51 @@ def _ordinals(expr: Expression) -> List[int]:
     return sorted(out)
 
 
+#: a comparison seen from its other operand
+_FLIPPED = {"<": ">", "<=": ">=", ">": "<", ">=": "<=", "!=": "!="}
+
+
+def _bounds_of(condition: Expression, n_left: int):
+    """``(op, x, r)`` where a semi/anti join's condition is ONE
+    comparison ``x op r`` (``<``, ``<=``, ``>``, ``>=``, ``NOT (a =
+    b)``; either operand order) of an expression ``x`` of the left
+    columns alone with an expression ``r`` of the right columns (``r``
+    rebound to the right side's own rows), both integers, dates or
+    timestamps: types whose device order is Spark's.  Whether some right
+    row of a key makes it TRUE then depends on the key's least and
+    greatest ``r`` alone (``J.some_holds``).  None for any other condition:
+    it keeps its pairs (a float compares NaN by IEEE rules, which no
+    order gives)."""
+    from ..ops.expression import BoundReference
+    from ..ops.predicates import EqualTo, Not, _Comparison
+
+    if isinstance(condition, Not) and type(condition.child) is EqualTo:
+        op, (x, r) = "!=", condition.child.children
+    elif isinstance(condition, _Comparison) and condition.op != "==":
+        op, (x, r) = condition.op, condition.children
+    else:
+        return None
+    if not condition.deterministic or not all(
+            e.dtype.is_integral or e.dtype.is_datetime for e in (x, r)):
+        return None
+
+    def right_only(e):
+        reads = _ordinals(e)
+        return bool(reads) and reads[0] >= n_left
+
+    def left_only(e):
+        return all(i < n_left for i in _ordinals(e))
+
+    if right_only(x) and left_only(r):
+        op, x, r = _FLIPPED[op], r, x
+    elif not (left_only(x) and right_only(r)):
+        return None
+    return op, x, r.transform(
+        lambda e: BoundReference(e.ordinal - n_left, e.dtype, e.nullable,
+                                 e.attr_name)
+        if isinstance(e, BoundReference) else None)
+
+
 class TpuHashJoinExec(TpuExec):
     """Shared device join core (reference: GpuHashJoin trait)."""
 
@@ -90,6 +135,11 @@ class TpuHashJoinExec(TpuExec):
         self._schema = plan.schema
         #: the rows a semi/anti join's condition reads: left + right
         self._pair_schema = plan.pair_schema
+        #: a semi/anti join's condition decided by the right side's
+        #: bounds (``_semi_bounds``), or None
+        self._bounds = _bounds_of(self.condition, len(left.schema)) \
+            if self.condition is not None \
+            and self.how in ("semi", "anti") else None
         from .kernel_cache import (expr_signature, jit_kernel,
                                    schema_signature)
 
@@ -109,17 +159,22 @@ class TpuHashJoinExec(TpuExec):
                                          key=sig + ("expand",))
         self._semi_kernel = jit_kernel(twin._semi_anti,
                                        key=sig + ("semi",))
-        if self._pairs_needed:
-            # a program of its own: the unconditioned joins keep theirs
+        # programs of their own: the unconditioned joins keep theirs
+        if self._bounds is not None:
+            self._bounds_kernel = jit_kernel(twin._semi_bounds,
+                                             key=sig + ("semiBounds",))
+        elif self._pairs_needed:
             self._pairs_kernel = jit_kernel(
                 twin._semi_pairs, static_argnums=(0,),
                 key=sig + ("semiPairs",))
 
     @property
     def _pairs_needed(self) -> bool:
-        """A semi/anti join with a condition: it evaluates the condition
-        on its key-matched pairs (``_semi_pairs``)."""
-        return self.condition is not None and self.how in ("semi", "anti")
+        """A semi/anti join with a condition the bounds cannot decide:
+        it evaluates the condition on its key-matched pairs
+        (``_semi_pairs``)."""
+        return self.condition is not None \
+            and self.how in ("semi", "anti") and self._bounds is None
 
     @property
     def _outer(self) -> bool:
@@ -323,6 +378,25 @@ class TpuHashJoinExec(TpuExec):
         keep = has if self.how == "semi" else ~has
         return compact(lb, keep)
 
+    def _semi_bounds(self, lb: DeviceBatch, rb: DeviceBatch) -> DeviceBatch:
+        """A semi/anti join whose condition is one comparison ``x op r``
+        (``_bounds_of``): a left row is kept (semi) or dropped (anti)
+        where some right row of its key makes it TRUE, as in
+        ``_semi_pairs``, decided by the key's least and greatest
+        non-null ``r`` against the row's ``x`` (``J.some_holds``): one
+        sort and one scan, no pair laid out and no count read back."""
+        op, x, r = self._bounds
+        xc = as_device_column(x.eval_tpu(lb), lb.padded_rows)
+        rc = as_device_column(r.eval_tpu(rb), rb.padded_rows)
+        dt = np.promote_types(xc.data.dtype, rc.data.dtype)
+        dt = np.int64 if dt.itemsize > 4 else np.int32
+        has = J.some_holds(op, self._keys_of(lb, self.left_keys),
+                           self._keys_of(rb, self.right_keys),
+                           lb.row_mask(), rb.row_mask(),
+                           xc.data.astype(dt), xc.validity,
+                           rc.data.astype(dt), rc.validity)
+        return compact(lb, has if self.how == "semi" else ~has)
+
     def _semi_pairs(self, c_out: int, lb: DeviceBatch, rb: DeviceBatch,
                     pr: J.Probe, emit) -> DeviceBatch:
         """A conditional semi/anti join: a left row is kept (semi) or
@@ -331,7 +405,9 @@ class TpuHashJoinExec(TpuExec):
         ``HashJoinExec._join_partition``.  The ``emit`` pairs are laid
         out in ``c_out`` slots by ``J.pair_rows``; the condition reads a
         batch of the pair schema whose left columns rode along to the
-        pairs and whose right columns are read at them."""
+        pairs and whose right columns are read at them.  Only for a
+        condition ``_bounds_of`` refuses: a string, a float, ``=`` or
+        ``<=>``, ``AND`` / ``OR``, an operand that reads both sides."""
         import jax.numpy as jnp
 
         n_left = len(lb.columns)
@@ -387,6 +463,10 @@ class TpuHashJoinExec(TpuExec):
     def _join(self, lb: DeviceBatch, rb: DeviceBatch) -> DeviceBatch:
         # OOM-injection checkpoint: the join's working set is the pair
         R.maybe_inject_oom(type(self).__name__ + ".join")
+        if self._bounds is not None:
+            if "join.conditionByBounds" in self.metrics:
+                self.metrics["join.conditionByBounds"].add(1)
+            return self._bounds_kernel(lb, rb)
         if self._pairs_needed:
             pr, emit, _, total = self._count_kernel(lb, rb)
             pairs = int(total)              # host sync: the layout's size
@@ -448,7 +528,8 @@ class TpuHashJoinExec(TpuExec):
             pr, emit, _, total = self._count(lb, rb)
             return self._semi_pairs(c_out, lb, rb, pr, emit), total
         if self.how in ("semi", "anti"):
-            out = self._semi_anti(lb, rb)
+            out = self._semi_bounds(lb, rb) if self._bounds is not None \
+                else self._semi_anti(lb, rb)
             return out, jnp.asarray(0, dtype=jnp.int64)
         pr, emit, r_extra, total = self._count(lb, rb)
         return self._expand(c_out, lb, rb, pr, emit, r_extra), total
@@ -473,6 +554,11 @@ class TpuHashJoinExec(TpuExec):
             for name in ("join.conditionPairs", "join.conditionPairSlots",
                          "join.conditionJoins"):
                 self.metrics[name] = ctx.metrics.metric(name)
+        elif self._bounds is not None:
+            # query-wide: the conditional semi/anti join programs that
+            # decided by the right side's bounds (one a stream batch)
+            name = "join.conditionByBounds"
+            self.metrics[name] = ctx.metrics.metric(name)
         elif self.how not in ("semi", "anti"):
             # query-wide: the expand programs run, by how each mapped its
             # slots to their left rows (``J.expand_by_sort``)

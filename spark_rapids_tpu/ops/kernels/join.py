@@ -29,8 +29,14 @@ returns the join output size).
 
 A semi or anti join with a condition (Spark's LeftSemi/LeftAnti with a
 residual, what ``EXISTS`` / ``NOT EXISTS`` with a non-equi correlation
-plan to) needs its pairs but never their order: ``pair_rows`` lays them
-out by one sort and scans, with no search (section 5 of PERF.md prices a
+plan to) asks only whether SOME pair of a left row makes it TRUE.  Where
+the condition is one comparison of a left value with a right one
+(``<``, ``<=``, ``>``, ``>=``, ``<>``) over integers, dates or
+timestamps, that depends on the key's least and greatest non-null right
+value alone: ``some_holds`` reads both off the probe's sort by one scan
+and compares, with no pair laid out.  Any other condition
+needs its pairs but never their order: ``pair_rows`` lays them out by
+one sort and scans, with no search (section 5 of PERF.md prices a
 ``searchsorted`` step of 2^25 slots at seconds), and ``any_pair`` reads
 one bit a left row back off a prefix sum of the condition's hits.
 """
@@ -74,6 +80,51 @@ class Probe(NamedTuple):
     has_r: object    # bool[Nr] right row has a left match
 
 
+class _Merged(NamedTuple):
+    """Both sides' rows in the order of one sort of their keys."""
+    order: object    # int32[n]: the row at each place, first side first
+    pos: object      # int32[n]: the places
+    ok_s: object     # bool[n]: the place holds a row that can join
+    change: object   # bool[n]: a key's segment starts at the place
+    seg_end: object  # bool[n]: ... or ends there
+    ride: object     # uint32[k, n]: the rows asked to ride, in key order
+
+
+def _merge(l_keys: List[DeviceColumn], r_keys: List[DeviceColumn],
+           l_ok, r_ok, ride=()) -> _Merged:
+    """The one sort ``probe`` and ``some_holds`` read their answers off
+    (``probe`` says how); within a key the first side's rows stand before
+    the second's.  Each uint32[nl + nr] row of ``ride`` goes into key
+    order in the stacked gather of the key words."""
+    import jax.numpy as jnp
+
+    nl, nr = l_ok.shape[0], r_ok.shape[0]
+    n = nl + nr
+    combined = [_concat_key_cols(a, b) for a, b in zip(l_keys, r_keys)]
+    ok = jnp.concatenate([l_ok, r_ok])
+    # null keys never join: fold key validity into row eligibility
+    for c in combined:
+        ok = ok & c.validity
+    words = seg.key_passes_device(combined, pad_valid=ok)
+    order = seg.sort_permutation(words, n)
+
+    # equal keys have equal words (-0.0 and 0.0, NaN and NaN share
+    # theirs); a string's length rides along, as its bytes are padded
+    rows = words + [c.lengths.astype(jnp.uint32) for c in combined
+                    if c.lengths is not None]
+    with device_phase("reorder"):
+        stacked = jnp.stack(rows + list(ride))[:, order]
+    keys_s = stacked[:len(rows)] if ride else stacked
+    pos = jnp.arange(n, dtype=jnp.int32)
+    ok_s = pos < ok.sum(dtype=jnp.int32)
+    change = ~ok_s | jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_),
+         (keys_s[:, 1:] != keys_s[:, :-1]).any(axis=0)])
+    seg_end = jnp.concatenate([change[1:], jnp.ones((1,), jnp.bool_)])
+    return _Merged(order, pos, ok_s, change, seg_end,
+                   stacked[len(rows):] if ride else None)
+
+
 @device_phase("join.probe")
 def probe(l_keys: List[DeviceColumn], r_keys: List[DeviceColumn],
           l_ok, r_ok) -> Probe:
@@ -104,44 +155,118 @@ def probe(l_keys: List[DeviceColumn], r_keys: List[DeviceColumn],
 
     nl, nr = l_ok.shape[0], r_ok.shape[0]
     n = nl + nr
-    combined = [_concat_key_cols(a, b) for a, b in zip(l_keys, r_keys)]
-    ok = jnp.concatenate([l_ok, r_ok])
-    # null keys never join: fold key validity into row eligibility
-    for c in combined:
-        ok = ok & c.validity
-    words = seg.key_passes_device(combined, pad_valid=ok)
-    order = seg.sort_permutation(words, n)
-
-    # equal keys have equal words (-0.0 and 0.0, NaN and NaN share
-    # theirs); a string's length rides along, as its bytes are padded
-    rows = words + [c.lengths.astype(jnp.uint32) for c in combined
-                    if c.lengths is not None]
-    with device_phase("reorder"):
-        keys_s = jnp.stack(rows)[:, order]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    ok_s = pos < ok.sum(dtype=jnp.int32)
-    change = ~ok_s | jnp.concatenate(
-        [jnp.ones((1,), jnp.bool_),
-         (keys_s[:, 1:] != keys_s[:, :-1]).any(axis=0)])
-    seg_end = jnp.concatenate([change[1:], jnp.ones((1,), jnp.bool_)])
+    m = _merge(l_keys, r_keys, l_ok, r_ok)
+    order, pos = m.order, m.pos
 
     is_right = order >= nl
     rights_before = prefix_sum(is_right.astype(jnp.int32))
     lefts_before = seg.segmented_scan(
-        (~is_right).astype(jnp.int32)[None], change, jnp.add)[0]
+        (~is_right).astype(jnp.int32)[None], m.change, jnp.add)[0]
     rights_after = jnp.flip(seg.segmented_scan(
-        jnp.flip(is_right).astype(jnp.int32)[None], jnp.flip(seg_end),
+        jnp.flip(is_right).astype(jnp.int32)[None], jnp.flip(m.seg_end),
         jnp.add)[0])
 
     # back to row order: a sort by a permutation is its inverse's gather
     mine = jnp.where(is_right, lefts_before,
-                     jnp.where(ok_s, rights_before, 0))
+                     jnp.where(m.ok_s, rights_before, 0))
     _, mine, rights_after = lax.sort((order, mine, rights_after),
                                      num_keys=1, is_stable=False)
     _, right_first = lax.sort((jnp.where(is_right, pos, n + pos), order),
                               num_keys=1, is_stable=False)
     return Probe(right_first[:nr] - nl, mine[:nl], rights_after[:nl],
                  mine[nl:] > 0)
+
+
+def _value_words(v):
+    """uint32 words, most significant first, whose unsigned
+    lexicographic order is the order of the int32 or int64 ``v``."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    sign = jnp.uint32(1 << 31)
+    if v.dtype == jnp.int64:
+        return [lax.bitcast_convert_type((v >> 32).astype(jnp.int32),
+                                         jnp.uint32) ^ sign,
+                lax.bitcast_convert_type(v.astype(jnp.int32), jnp.uint32)]
+    return [lax.bitcast_convert_type(v, jnp.uint32) ^ sign]
+
+
+def _less(a, b, or_equal: bool):
+    """``a < b`` (``a <= b``) over lists of words, most significant
+    first, broadcast over whatever leading axes they share."""
+    out = (a[-1] <= b[-1]) if or_equal else (a[-1] < b[-1])
+    for x, y in zip(reversed(a[:-1]), reversed(b[:-1])):
+        out = (x < y) | ((x == y) & out)
+    return out
+
+
+def _max_of_pairs(a, b):
+    """The row-wise max of ``[hi; lo]`` word pairs stacked on axis -2
+    (each ``[..., 2g, m]``: the g high words, then the g low words): the
+    combining op of the scan in ``some_holds`` for 64-bit values."""
+    import jax.numpy as jnp
+
+    a, b = jnp.broadcast_arrays(a, b)
+    g = a.shape[-2] // 2
+    take = ~_less([a[..., :g, :], a[..., g:, :]],
+                  [b[..., :g, :], b[..., g:, :]], or_equal=False)
+    return jnp.where(jnp.concatenate([take, take], axis=-2), a, b)
+
+
+#: ``x op r`` holds for SOME right value ``r`` of a key, in terms of the
+#: key's least and greatest (word lists; ``low <= high`` known)
+_SOME = {
+    "<": lambda x, low, high: _less(x, high, False),
+    "<=": lambda x, low, high: _less(x, high, True),
+    ">": lambda x, low, high: _less(low, x, False),
+    ">=": lambda x, low, high: _less(low, x, True),
+    "!=": lambda x, low, high: ~_less(x, low, True) | _less(x, high, False),
+}
+
+
+@device_phase("join.probe")
+def some_holds(op: str, l_keys: List[DeviceColumn],
+               r_keys: List[DeviceColumn], l_ok, r_ok, x, x_ok, value,
+               value_ok):
+    """bool[nl]: ``x op value`` (``op`` a key of ``_SOME``; ``x`` [nl]
+    and ``value`` [nr], both int32 or both int64, valid where ``x_ok``,
+    ``value_ok``) is TRUE for some right row of the left row's key (a
+    NULL on either side, or no key match, is no match), without laying
+    out a pair: it depends only on the least and the greatest non-null
+    ``value`` of the key.
+
+    Read off ``probe``'s sort with the right side first, so that in each
+    key's segment the right rows stand before the left ones, and with a
+    NULL ``x`` or ``value`` as a row that never joins (sorted last, a
+    segment of its own), as a NULL key is.  The values' words
+    (order-preserving uint32) and ``x``'s ride into key order in the
+    gather of the key words; one max-scan from the front over each
+    segment, of the right values and of their complements (``~w`` orders
+    them the other way, so its max is the complement of the min; the
+    left rows are 0, the identity), then stands at every left row of the
+    key, where ``x`` is compared in place; one sort by ``order`` takes
+    the answer to row order.  Exact: whole words compared, no rounding."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    nr = r_ok.shape[0]
+    ride = [jnp.concatenate([a, b])
+            for a, b in zip(_value_words(value), _value_words(x))]
+    m = _merge(r_keys, l_keys, r_ok & value_ok, l_ok & x_ok, ride)
+    with device_phase("join.condition"):
+        is_left = m.order >= nr
+        words = list(m.ride)
+        # each word of the values, then its complement: [w, ~w] or, a
+        # pair per group, [hi, ~hi, lo, ~lo]
+        top = seg.scan_restarting(
+            jnp.stack([jnp.where(is_left, jnp.uint32(0), v)
+                       for w in words for v in (w, ~w)]),
+            m.change, _max_of_pairs if len(words) == 2 else jnp.maximum)
+        high, low = list(top[0::2]), list(~top[1::2])
+        hit = (is_left & _less(low, high, True)
+               & _SOME[op](words, low, high))
+        _, hit = lax.sort((m.order, hit), num_keys=1, is_stable=False)
+    return hit[nr:]
 
 
 @device_phase("join.emitCounts")

@@ -44,6 +44,8 @@ KERNELS = {
     "join_semi": {"join.probe", "gather.partitionOrder", "reorder"},
     "join_semiPairs": {"join.pairRows", "join.condition",
                        "gather.partitionOrder", "reorder"},
+    "join_semiBounds": {"join.probe", "join.condition", "lexsort",
+                        "reorder", "gather.partitionOrder"},
     "shuffle__hash_pids": {"shuffle.hashPids"},
     "shuffle_packedBuild": {"shuffle.packedBuild", "reorder"},
     "shuffle_packedSlice": {"shuffle.packedSlice", "reorder"},
@@ -92,6 +94,10 @@ def dispatched():
         assert fact.join(dim.select(f.col("k").alias("k2"), "w"),
                          on=(["k"], ["k2"]), how="left_anti",
                          condition=f.col("v") * 40 > f.col("w")).collect()
+        assert fact.join(dim.select(f.col("k").alias("k2"),
+                                    f.col("k").alias("j")),
+                         on=(["k"], ["k2"]), how="left_semi",
+                         condition=f.col("g") < f.col("j")).collect()
         fused = fact.filter(fact["v"] > 0.1).select(
             (fact["v"] * 2).alias("x"), fact["k"]).filter(f.col("x") < 1.5)
         assert fused.collect()

@@ -164,11 +164,12 @@ def test_distributed_join_runs_cross_a_scan_block():
 @pytest.mark.parametrize("how", ["semi", "anti"])
 def test_distributed_conditional_semi_anti_join_keeps_its_condition(
         how, threshold):
-    """A semi/anti join with a condition runs its pair kernel inside the
-    stage program (``join_static``): the pairs of one key (300 x 40 on
-    a shard, far past the first capacity) overflow, the runner retries
-    at their count, and the answer is the host engine's, never the
-    unconditioned one."""
+    """A semi/anti join with a condition the bounds refuse (an operand
+    that reads both sides) runs its pair kernel inside the stage program
+    (``join_static``): the pairs of one key (300 x 40 on a shard, far
+    past the first capacity) overflow, the runner retries at their
+    count, and the answer is the host engine's, never the unconditioned
+    one."""
     from spark_rapids_tpu import Session
     from spark_rapids_tpu.parallel.runner import run_distributed
     from spark_rapids_tpu.plan import functions as F
@@ -186,7 +187,7 @@ def test_distributed_conditional_semi_anti_join_keeps_its_condition(
         l = sess.create_dataframe({"k": lk, "s": ls})
         r = sess.create_dataframe({"rk": rk.astype(np.int64), "rs": rs})
         return l.join(r, on=(["k"], ["rk"]), how=how,
-                      condition=(F.col("s") != F.col("rs"))
+                      condition=(F.col("s") - F.col("rs") != F.lit(0))
                       if condition else None)
 
     conf = {} if threshold is None else \
@@ -198,6 +199,58 @@ def test_distributed_conditional_semi_anti_join_keeps_its_condition(
     assert sorted(map(tuple, exp)) != sorted(map(tuple, plain))
     _assert_rows_equal(got, exp)
     assert sess.last_metrics["distributed.stageRetries"] >= 1
+
+
+@pytest.mark.parametrize("threshold", [0, None],
+                         ids=["shuffled", "broadcast"])
+@pytest.mark.parametrize("op", ["!=", "<", ">="])
+@pytest.mark.parametrize("how", ["semi", "anti"])
+def test_distributed_semi_anti_join_decides_by_the_bounds(how, op,
+                                                          threshold):
+    """A semi/anti join whose condition is one comparison of a left and
+    a right column is decided in the stage program by each key's least
+    and greatest right value (``join_static``): no pair, so no demand
+    and no retry, and the answer is the one chip's and the host's."""
+    import operator
+
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.parallel.runner import run_distributed
+    from spark_rapids_tpu.plan import functions as F
+
+    rng = np.random.RandomState(42)
+    lk = rng.randint(0, 30, 1200).astype(np.int64)
+    lk[:300] = 7
+    ls = [None if i % 13 == 5 else int(v)
+          for i, v in enumerate(rng.randint(0, 6, 1200))]
+    rk = np.concatenate([np.full(40, 7),
+                         rng.choice([k for k in range(30) if k != 7], 200)])
+    rs = [None if i % 11 == 3 else int(v)
+          for i, v in enumerate(rng.randint(0, 6, len(rk)))]
+    rs[:40] = [2] * 40              # key 7: one right value, 2
+    cmp = {"!=": operator.ne, "<": operator.lt, ">=": operator.ge}[op]
+
+    def q(sess, condition=True):
+        l = sess.create_dataframe({"k": lk, "s": ls})
+        r = sess.create_dataframe({"rk": rk.astype(np.int64), "rs": rs})
+        return l.join(r, on=(["k"], ["rk"]), how=how,
+                      condition=cmp(F.col("s"), F.col("rs"))
+                      if condition else None)
+
+    conf = {} if threshold is None else \
+        {"spark.rapids.tpu.sql.broadcastSizeThreshold": threshold}
+    def rows(got):      # NULLs among them: no order of their own
+        return sorted(map(tuple, got), key=repr)
+
+    sess = Session(dict(conf))
+    got = run_distributed(sess, q(sess), mesh=_mesh(4)).to_rows()
+    exp = rows(q(Session(tpu_enabled=False)).collect())
+    one = Session(dict(conf))
+    assert rows(q(one).collect()) == exp
+    assert one.last_metrics["join.conditionByBounds"] >= 1
+    assert rows(q(Session(tpu_enabled=False), condition=False)
+                .collect()) != exp
+    assert rows(got) == exp
+    assert sess.last_metrics.get("distributed.stageRetries", 0) == 0
 
 
 def test_distributed_global_sort_order_preserved():

@@ -2,23 +2,29 @@
 
 Spark plans a correlated ``EXISTS`` / ``NOT EXISTS`` whose correlation
 is not only equalities as a LeftSemi / LeftAnti join with a residual
-condition (TPC-H q21).  The device lays the key-matched pairs out by one
-sort and scans (``ops/kernels/join.py:pair_rows``), evaluates the
-condition on every pair and keeps a left row where any pair is TRUE
-(NULL is no match).  Each case here is answered three ways: the device
-path on the CPU backend, the host engine (``plan/physical.py``), and a
-plain loop over the pairs.  Then: the split of the stream side where the
-pairs do not fit, Q21's shape through a strict session with its
-counters, and the lowered text of the programs the expand's sort path
-leaves alone, which must be what it was before the expand could sort.
+condition (TPC-H q21).  Where the condition is one comparison of a left
+value with a right one (``<``, ``<=``, ``>``, ``>=``, ``<>``) over
+integers, dates or timestamps, the device decides it from each key's
+least and greatest right value (``ops/kernels/join.py:some_holds``) and lays
+out no pair.  Any other condition keeps its pairs: the device lays them
+out by one sort and scans (``pair_rows``), evaluates the condition on
+every pair and keeps a left row where any pair is TRUE (NULL is no
+match).  Each case here is answered three ways: the device path on the
+CPU backend, the host engine (``plan/physical.py``), and a plain loop
+over the pairs.  Then: the split of the stream side where the pairs do
+not fit, Q21's shape through a strict session with its counters, and
+the lowered text of the programs the bounds and the expand's sort path
+leave alone, which must be what it was before either.
 """
 import hashlib
+import operator
 
 import numpy as np
 import pytest
 
 import spark_rapids_tpu as srt
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.exec import joins
 from spark_rapids_tpu.exec.joins import TpuHashJoinExec
 from spark_rapids_tpu.plan import functions as F
 
@@ -59,13 +65,43 @@ CASES = {
     "crosses_a_bucket": _many(17, 8),
 }
 
+#: what the bounds decide besides: a key whose right values are all
+#: NULL (no match, whatever the operator), a NULL left value beside
+#: right values, keys of one right row, the int64 extremes (the least
+#: one's words are the scan's identity, 0) and a key over three blocks
+#: of the scan
+BOUNDS_CASES = dict(CASES, **{
+    "right_values_all_null": ({"k": [1, 1, 2], "s": [0, 5, None]},
+                              {"k2": [1, 1, 2, 2], "s2": [None, None, 3, 4]}),
+    "null_left_value": ({"k": [1, 1, 2, 2], "s": [None, 1, None, 9]},
+                        {"k2": [1, 1, 2], "s2": [1, 2, 9]}),
+    "one_right_row_a_key": ({"k": [1, 2, 3, 3, 4], "s": [1, 1, 2, 4, 0]},
+                            {"k2": [1, 2, 3, 4], "s2": [1, 2, 3, None]}),
+    # one key's right rows over three blocks of the scan (1024 rows),
+    # its values spread over the int64 range
+    "crosses_scan_blocks": (
+        {"k": [7, 7, 7, 7, 8], "s": [-2**62, 0, 2**62, None, 1]},
+        {"k2": [7] * 2500 + [8],
+         "s2": [(i * 7919 % 2500 - 1250) * 2**50 for i in range(2500)]
+         + [1]}),
+    "extremes": ({"k": [1, 1, 2, 2, 3],
+                  "s": [-2**63, 2**63 - 1, -2**63, 0, 2**63 - 1]},
+                 {"k2": [1, 2, 2, 3, 3],
+                  "s2": [-2**63, 2**63 - 1, -2**63, 2**63 - 1, None]}),
+})
 
-def _expected(left, right, how):
+#: the operators the bounds take: each builds the condition of two
+#: columns and compares a left value with a right one in the loop
+OPERATORS = {"<": operator.lt, "<=": operator.le, ">": operator.gt,
+             ">=": operator.ge, "!=": operator.ne}
+
+
+def _expected(left, right, how, holds=operator.ne):
     """The left rows a plain loop over every pair keeps."""
     keep = []
     for k, s in zip(left["k"], left["s"]):
         hit = any(k is not None and k == k2 and s is not None
-                  and s2 is not None and s != s2
+                  and s2 is not None and holds(s, s2)
                   for k2, s2 in zip(right["k2"], right["s2"]))
         if hit == (how == "semi"):
             keep.append((k, s))
@@ -77,25 +113,46 @@ def _pairs(left, right):
                for k in left["k"] for k2 in right["k2"])
 
 
-def _query(sess, left, right, how, n_partitions=1):
+#: conditions the bounds refuse, each TRUE where ``s != s2`` is (the
+#: keys of a pair are equal): they keep their pairs
+PAIR_CONDITIONS = {
+    "operand_reads_both_sides": lambda c: (c("s") - c("s2")) != F.lit(0),
+    "and": lambda c: (c("s") != c("s2")) & (c("k") == c("k2")),
+    "or": lambda c: (c("s") != c("s2")) | (c("k") != c("k2")),
+}
+
+
+def _query(sess, left, right, how, n_partitions=1,
+           condition=PAIR_CONDITIONS["operand_reads_both_sides"]):
     lf = sess.create_dataframe(left, schema=LEFT, n_partitions=n_partitions)
     rf = sess.create_dataframe(right, schema=RIGHT, n_partitions=1)
     return lf.join(rf, on=(["k"], ["k2"]), how=how,
-                   condition=F.col("s") != F.col("s2"))
+                   condition=condition(F.col))
 
 
+def _device_join(sess, df):
+    op, = [n for n in _walk(sess.physical_plan(df.plan))
+           if isinstance(n, TpuHashJoinExec)]
+    return op
+
+
+@pytest.mark.parametrize("form", sorted(PAIR_CONDITIONS))
 @pytest.mark.parametrize("case", sorted(CASES))
 @pytest.mark.parametrize("how", ["semi", "anti"])
-def test_device_equals_host_and_the_pairs(how, case):
+def test_device_equals_host_and_the_pairs(how, case, form):
     left, right = CASES[case]
     want = _expected(left, right, how)
-    host = _query(srt.Session(tpu_enabled=False), left, right, how)
+    cond = PAIR_CONDITIONS[form]
+    host = _query(srt.Session(tpu_enabled=False), left, right, how,
+                  condition=cond)
     assert sorted(host.collect(), key=repr) == want
     sess = srt.Session(STRICT)
-    df = _query(sess, left, right, how)
-    assert f"* HashJoinExec [{how}, (NOT (s == s2))]" in df.explain()
+    df = _query(sess, left, right, how, condition=cond)
+    assert f"* HashJoinExec [{how}, (" in df.explain()
+    assert _device_join(sess, df)._bounds is None
     assert sorted(df.collect(), key=repr) == want
     m = sess.last_metrics
+    assert "join.conditionByBounds" not in m
     assert m["join.conditionJoins"] == 1
     assert m["join.conditionPairs"] == _pairs(left, right)
     slots = m["join.conditionPairSlots"]
@@ -103,6 +160,101 @@ def test_device_equals_host_and_the_pairs(how, case):
     assert slots & (slots - 1) == 0
     if case == "crosses_a_bucket":
         assert (m["join.conditionPairs"], slots) == (136 + 1, 256)
+
+
+@pytest.mark.parametrize("case", sorted(BOUNDS_CASES))
+@pytest.mark.parametrize("left_first", [True, False],
+                         ids=["left_op_right", "right_op_left"])
+@pytest.mark.parametrize("op", sorted(OPERATORS))
+@pytest.mark.parametrize("how", ["semi", "anti"])
+def test_bounds_decide_as_the_pairs_do(how, op, left_first, case):
+    """One comparison of a left and a right column, in either order,
+    decided by each key's least and greatest right value: the device
+    keeps the rows the host engine and the loop over the pairs keep, and
+    lays out no pair."""
+    left, right = BOUNDS_CASES[case]
+    holds = OPERATORS[op]
+    if left_first:
+        def cond(c):
+            return OPERATORS[op](c("s"), c("s2"))
+    else:       # ``s2 <op'> s`` with op' the flipped operator
+        def cond(c):
+            return OPERATORS[joins._FLIPPED[op]](c("s2"), c("s"))
+    want = _expected(left, right, how, holds)
+    host = _query(srt.Session(tpu_enabled=False), left, right, how,
+                  condition=cond)
+    assert sorted(host.collect(), key=repr) == want
+    sess = srt.Session(STRICT)
+    df = _query(sess, left, right, how, condition=cond)
+    assert f"* HashJoinExec [{how}, (" in df.explain()
+    bounds = _device_join(sess, df)._bounds
+    assert bounds is not None and bounds[0] == op
+    assert sorted(df.collect(), key=repr) == want
+    m = sess.last_metrics
+    assert m["join.conditionByBounds"] == 1
+    for name in ("join.conditionJoins", "join.conditionPairs",
+                 "join.conditionPairSlots"):
+        assert m.get(name, 0) == 0
+
+
+@pytest.mark.parametrize("types", [
+    ("int8", T.INT8, T.INT8), ("int16", T.INT16, T.INT16),
+    ("int32", T.INT32, T.INT32), ("int32_int64", T.INT32, T.INT64),
+    ("date", T.DATE32, T.DATE32), ("timestamp", T.TIMESTAMP, T.TIMESTAMP)],
+    ids=lambda t: t[0])
+@pytest.mark.parametrize("how", ["semi", "anti"])
+def test_bounds_over_every_integer_width(how, types):
+    """The bounds of an int8, an int16, an int32 (a word each), a date,
+    an int64 or a timestamp (two words), and an int32 beside an int64,
+    compared with a left value of each, NULLs among them."""
+    _, lt, rt = types
+    rng = np.random.default_rng(42)
+    n = 80
+    ls = T.Schema([T.Field("k", T.INT64, True), T.Field("a", lt, True)])
+    rs = T.Schema([T.Field("k2", T.INT64, True), T.Field("b", rt, True)])
+    big = 100 if lt in (T.INT8, T.INT16) else 2**31 - 1
+
+    def values(seed):
+        v = np.random.default_rng(seed).integers(-big, big, n).tolist()
+        return [None if i % 7 == 3 else x for i, x in enumerate(v)]
+
+    left = {"k": rng.integers(0, 12, n).tolist(), "a": values(1)}
+    right = {"k2": rng.integers(0, 12, n).tolist(), "b": values(2)}
+    def q(sess, cond):
+        lf = sess.create_dataframe(left, schema=ls, n_partitions=1)
+        rf = sess.create_dataframe(right, schema=rs, n_partitions=1)
+        return lf.join(rf, on=(["k"], ["k2"]), how=how, condition=cond)
+
+    for cond in (F.col("a") < F.col("b"), F.col("a") != F.col("b"),
+                 F.col("b") <= F.col("a")):
+        host = sorted(q(srt.Session(tpu_enabled=False), cond).collect(),
+                      key=repr)
+        sess = srt.Session(STRICT)
+        device = sorted(q(sess, cond).collect(), key=repr)
+        assert device == host and 0 < len(host) < n
+        assert sess.last_metrics["join.conditionByBounds"] == 1
+
+
+def test_the_bounds_refuse_what_they_cannot_decide():
+    """A float, a string, ``=``, ``<=>``, ``AND``, an operand of both
+    sides, a comparison of two right columns: no bounds, pairs."""
+    sess = srt.Session(STRICT)
+    lf = sess.create_dataframe({"k": [1], "s": [1], "x": [1.5], "t": ["a"]},
+                               n_partitions=1)
+    rf = sess.create_dataframe({"k2": [1], "s2": [1], "y": [2.5],
+                                "u": ["b"]}, n_partitions=1)
+    c = F.col
+    refused = [c("x") < c("y"), c("t") != c("u"), c("s") == c("s2"),
+               c("s").eq_null_safe(c("s2")),
+               (c("s") < c("s2")) & (c("s") > F.lit(0)),
+               c("s") + c("s2") > F.lit(1), c("s2") != c("k2")]
+    taken = [c("s") < c("s2"), c("s2") >= c("s"), c("s") + F.lit(1) !=
+             c("s2") * c("k2"), F.lit(3) > c("s2")]
+    for cond, want in [(x, None) for x in refused] + [(x, 1) for x in taken]:
+        df = lf.join(rf, on=(["k"], ["k2"]), how="semi", condition=cond)
+        op = _device_join(sess, df)
+        assert (op._bounds is None) == (want is None), cond
+        assert op._pairs_needed == (want is None)
 
 
 @pytest.mark.parametrize("how", ["semi", "anti"])
@@ -247,15 +399,16 @@ def _q21(sess, data):
 
 
 def test_q21_shape_in_strict_mode_counts_its_pairs():
+    """Both of Q21's conditions (``l_suppkey <> l2_suppkey``, ``<>
+    l3_suppkey``) are decided by the bounds: one program a join and
+    stream batch, no pair laid out."""
     data = _q21_tables(np.random.default_rng(21))
     li = data["lineitem"]
     o, s = np.array(li["l_orderkey"]), np.array(li["l_suppkey"])
     late = np.array(li["l_receiptdate"]) > np.array(li["l_commitdate"])
     same = o[:, None] == o[None, :]
-    semi_pairs = same[late].sum()
     exists = (same & (s[:, None] != s[None, :]))[late].any(axis=1)
     kept = np.flatnonzero(late)[exists]
-    anti_pairs = (same & late[None, :])[kept].sum()
     waits = kept[~(same & late[None, :] & (s[:, None] != s[None, :]))[
         kept].any(axis=1)]
 
@@ -271,8 +424,9 @@ def test_q21_shape_in_strict_mode_counts_its_pairs():
     host = _q21(srt.Session(tpu_enabled=False), data).collect()
     assert got == host and len(got) > 1
     m = sess.last_metrics
-    assert m["join.conditionJoins"] == 2
-    assert m["join.conditionPairs"] == semi_pairs + anti_pairs
+    assert m["join.conditionByBounds"] == 2
+    assert m.get("join.conditionJoins", 0) == 0
+    assert m.get("join.conditionPairs", 0) == 0
     # the answer from the lines the loop keeps
     status = dict(zip(data["orders"]["o_orderkey"],
                       data["orders"]["o_orderstatus"]))
@@ -292,9 +446,10 @@ def test_q21_shape_in_strict_mode_counts_its_pairs():
 # ==========================================================================
 #: sha256 of the lowered text (no debug info) of the unconditioned semi
 #: join's program, the inner join's count, the conditional semi join's
-#: pair program, and the inner join's expand where few slots stand over
-#: a wide left side (the search path), for the fixed input of
-#: ``_lowered_join_programs``, under this JAX.  The count's is commit
+#: pair program (of ``s <> s2``, which the bounds decide since: it is
+#: planned here with the bounds refused), and the inner join's expand
+#: where few slots stand over a wide left side (the search path), for
+#: the fixed input of ``_lowered_join_programs``, under this JAX.  The count's is commit
 #: 4264f4c's (before the expand could sort): its compile-cache key is
 #: that commit's.  The other three read their rows by one stacked gather
 #: (``gather.take_rows``) since, and are recorded from there.  The expand
@@ -318,7 +473,7 @@ def _walk(node):
         yield from _walk(c)
 
 
-def _lowered_join_programs():
+def _lowered_join_programs(monkeypatch):
     import jax.numpy as jnp
 
     from spark_rapids_tpu.data.column import DeviceBatch, DeviceColumn
@@ -341,11 +496,15 @@ def _lowered_join_programs():
         df = left.join(right, on=(["k"], ["k2"]), how=how[:4],
                        condition=F.col("s") != F.col("s2")
                        if how == "semiPairs" else None)
-        op, = [n for n in _walk(sess.physical_plan(df.plan))
-               if isinstance(n, TpuHashJoinExec)]
+        with monkeypatch.context() as patch:
+            patch.setattr(joins, "_bounds_of", lambda *a: None)
+            op = _device_join(sess, df)
         if how == "semi":
             out["join_semi"] = op._semi_kernel._jfn.lower(lb, rb).as_text()
             continue
+        if how == "semiPairs":
+            out["join_semiBounds"] = _device_join(
+                sess, df)._bounds_kernel._jfn.lower(lb, rb).as_text()
         if how == "semiPairs":
             pr, emit, _, _ = op._count_kernel(lb, rb)
             out["join_semiPairs"] = op._pairs_kernel._jfn.lower(
@@ -365,7 +524,8 @@ def _lowered_join_programs():
 
 @pytest.fixture(scope="module")
 def lowered():
-    return _lowered_join_programs()
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        return _lowered_join_programs(monkeypatch)
 
 
 @pytest.mark.parametrize("program", ["join_semi", "join_count",
@@ -387,3 +547,21 @@ def test_unconditioned_programs_lower_to_the_parents_text(lowered, program):
         pytest.skip("the parent's text was recorded under jax "
                     + PARENT_TEXT["jax"])
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_TEXT[program]
+
+
+def test_the_bounds_program_lays_out_no_pair(lowered):
+    """``s <> s2`` over 1024 left and 512 right rows: no array of the
+    bounds' program is longer than the rows of both sides (padded to a
+    whole block of the scan), where the pair program's layout spans its
+    2048 slots and a marker a row."""
+    import re
+
+    from spark_rapids_tpu.ops.kernels.gather import _SCAN_BLOCK
+
+    def longest(text):
+        return max(int(d) for d in re.findall(r"tensor<(\d+)", text))
+
+    text = lowered["join_semiBounds"]
+    assert "jit_join_semiBounds" in text and "searchsorted" not in text
+    assert longest(text) == -(-(1024 + 512) // _SCAN_BLOCK) * _SCAN_BLOCK
+    assert longest(lowered["join_semiPairs"]) == 1024 + 2048

@@ -525,6 +525,39 @@ def test_join_probe_and_expand_compile_for_four_chips(topo, as_tpu):
     assert text.count(" sort(") == 6, text.count(" sort(")
 
 
+@pytest.mark.parametrize("value", [T.INT32, T.INT64], ids=["int32", "int64"])
+def test_semi_join_bounds_compile_for_v5e(one_chip, as_tpu, value):
+    """A semi join whose condition is one comparison (``l <> r``):
+    the bounds program, its sort, its one stacked read of the key and
+    value words, the max-scan of int32 or int64 values and the sort
+    back. It holds the lexsort's sorts and the sort back, and no pair
+    layout: its temporaries stay a small multiple of its input."""
+    import spark_rapids_tpu as srt
+    from spark_rapids_tpu.exec.joins import TpuHashJoinExec
+    from spark_rapids_tpu.plan import functions as F
+
+    sess = srt.Session({"spark.rapids.tpu.sql.test.enabled": True})
+    ls = T.Schema([T.Field("k", T.INT64), T.Field("a", value)])
+    rs = T.Schema([T.Field("k2", T.INT64), T.Field("b", value)])
+    lf = sess.create_dataframe({"k": [1], "a": [2]}, schema=ls)
+    rf = sess.create_dataframe({"k2": [1], "b": [3]}, schema=rs)
+    df = lf.join(rf, on=(["k"], ["k2"]), how="semi",
+                 condition=F.col("a") != F.col("b"))
+    op, = [n for n in _walk(sess.physical_plan(df.plan))
+           if isinstance(n, TpuHashJoinExec)]
+    assert op._bounds is not None
+
+    def batch(schema):
+        return DeviceBatch(schema, [_column(one_chip, f.dtype)
+                                    for f in schema],
+                           _shape(one_chip, (), np.int32))
+
+    compiled = _compile(op._bounds_kernel.fn, batch(ls), batch(rs))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 64 * mem.argument_size_in_bytes, mem
+    assert compiled.as_text().count(" sort(") >= 2
+
+
 # --------------------------------------------------------------------------
 # what the chip's float64 branch computes (runs on the CPU backend)
 # --------------------------------------------------------------------------
