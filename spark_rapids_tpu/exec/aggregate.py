@@ -34,12 +34,13 @@ from ..ops.kernels import segment as seg
 from ..utils import metrics as M
 from ..utils.tracing import device_phase, trace_range
 from .base import DevicePartitionedData, TpuExec
-from .fused import _member_fingerprint, run_members
+from .fused import members_signature, run_members
 
 
 class TpuHashAggregateExec(TpuExec):
-    """Sort-based group-by on device; wraps the host plan node to reuse its
-    bound keys/specs/schema (modes are identical).
+    """Sort-based group-by on device; reads its bound keys/specs/schema
+    off ``plan``, the host plan node or an aggregate rebuilt (modes are
+    identical), and holds none of it.
 
     Out-of-core: a partition bigger than the batch-size goal arrives as
     several batches; each is aggregated to its buffer form and merged into
@@ -52,7 +53,6 @@ class TpuHashAggregateExec(TpuExec):
 
     def __init__(self, child, plan, absorbed=()):
         super().__init__([child])
-        self.plan = plan  # physical.HashAggregateExec (exprs already bound)
         self.mode = plan.mode
         self.keys = plan.keys
         self.specs = plan.specs
@@ -67,31 +67,27 @@ class TpuHashAggregateExec(TpuExec):
         from .kernel_cache import (expr_signature, jit_kernel,
                                    schema_signature)
 
-        #: what the prologue adds to the kernel keys (and to the mesh's
-        #: stage signature): () where nothing was absorbed
-        self.absorbed_signature = tuple(
-            _member_fingerprint(m) for m in self.absorbed)
-        sig = ("agg", self.mode, schema_signature(child.schema),
-               expr_signature(self.keys),
-               tuple(sp.func.sql() for sp in self.specs),
-               schema_signature(plan.schema))
-        if self.absorbed:
-            sig += (self.absorbed_signature,)
+        # an absorbed chain is part of every body that reads raw rows
+        self.signature = (self.mode, schema_signature(child.schema),
+                          expr_signature(self.keys),
+                          tuple(sp.func.sql() for sp in self.specs),
+                          schema_signature(self._schema)) + (
+            (members_signature(self.absorbed),) if self.absorbed else ())
         twin = self.kernel_twin()
         self._kernel = jit_kernel(twin.compute_batch,
-                                  key=sig + ("batch",))
+                                  key=("agg", self.signature, "batch"))
         # chunked-path kernels (used only when a partition spans batches)
         self._update_kernel = jit_kernel(
             lambda b: twin._compute(b, "update", "buffers"),
-            key=sig + ("update",))
+            key=("agg", self.signature, "update"))
         self._merge_kernel = jit_kernel(
             lambda b: twin._compute(b, "merge", "buffers"),
-            key=sig + ("merge",))
+            key=("agg", self.signature, "merge"))
         # only reached from _agg_chunked when mode is final/complete
         # (partial returns the running buffers before finalize)
         self._merge_final_kernel = jit_kernel(
             lambda b: twin._compute(b, "merge", "final"),
-            key=sig + ("merge_final",))
+            key=("agg", self.signature, "merge_final"))
 
     def kernel_twin(self):
         # the absorbed members still link to the chain they stood in: a

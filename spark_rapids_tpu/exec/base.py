@@ -108,6 +108,12 @@ class TpuExec(PhysicalPlan):
     #: several operators' bodies (``device_phase``); None: the class's
     SPAN: Optional[str] = None
 
+    #: what every lowering of the operator reads of it (its own kernels
+    #: and the mesh's ``_lower_op``): kinds, bound expressions, schemas,
+    #: said once in ``__init__``.  Its kernels are keyed by it and the
+    #: mesh's stage programs are built from it; None: cannot be shared
+    signature: Optional[tuple] = None
+
     def __init__(self, children: Sequence[PhysicalPlan] = ()):  # noqa
         super().__init__(children)
         self.metrics = {}
@@ -149,13 +155,15 @@ class TpuExec(PhysicalPlan):
         would pin the whole plan subtree (and anything the subtree
         finalizes on collection, e.g. HostToDeviceExec's cached upload
         buffers) for the life of the process.  The twin keeps the
-        expression/schema state the compute body needs and swaps each
-        child for a schema-only stub.
+        expression/schema state the compute body needs, swaps each
+        child for a schema-only stub and leaves off ``plan``, the host
+        plan node (it holds the host subtree; no body reads it).
         """
         import copy
 
         twin = copy.copy(self)
         twin.children = [_SchemaStub(c.schema) for c in self.children]
+        vars(twin).pop("plan", None)
         return twin
 
     @property

@@ -31,10 +31,11 @@ class TpuProjectExec(TpuExec):
                 T.Field(output_name(raw, i), b.dtype, b.nullable)
                 for i, (raw, b) in enumerate(zip(exprs, self.exprs))])
         self._schema = schema
-        self._kernel = jit_kernel(
-            self.kernel_twin()._compute,
-            key=("project", schema_signature(child.schema),
-                 expr_signature(self.exprs), schema_signature(schema)))
+        self.signature = (schema_signature(child.schema),
+                          expr_signature(self.exprs),
+                          schema_signature(schema))
+        self._kernel = jit_kernel(self.kernel_twin()._compute,
+                                  key=("project", self.signature))
 
     @property
     def schema(self):
@@ -77,10 +78,10 @@ class TpuFilterExec(TpuExec):
     def __init__(self, child, condition: Expression):
         super().__init__([child])
         self.condition = bind_references(condition, child.schema)
-        self._kernel = jit_kernel(
-            self.kernel_twin()._compute,
-            key=("filter", schema_signature(child.schema),
-                 expr_signature([self.condition])))
+        self.signature = (schema_signature(child.schema),
+                          expr_signature([self.condition]))
+        self._kernel = jit_kernel(self.kernel_twin()._compute,
+                                  key=("filter", self.signature))
 
     @property
     def schema(self):
@@ -126,6 +127,8 @@ class TpuFilterExec(TpuExec):
 class TpuUnionExec(TpuExec):
     def __init__(self, children):
         super().__init__(children)
+        self.signature = tuple(schema_signature(c.schema)
+                               for c in children)
 
     @property
     def schema(self):
@@ -146,6 +149,7 @@ class TpuLocalLimitExec(TpuExec):
     def __init__(self, child, n: int):
         super().__init__([child])
         self.n = n
+        self.signature = (schema_signature(child.schema), int(n))
 
     @property
     def schema(self):
@@ -211,12 +215,14 @@ class TpuExpandExec(TpuExec):
         # a fused segment holding _kernel_fns pins this exec's subtree
         twin = self.kernel_twin()
         self._kernel_fns = [twin._mk_kernel(ps) for ps in self.projections]
-        self._kernels = [
-            jit_kernel(fn, key=("expand",
-                                schema_signature(child.schema),
-                                expr_signature(ps),
-                                schema_signature(self._schema)))
-            for fn, ps in zip(self._kernel_fns, self.projections)]
+        self.signature = (schema_signature(child.schema),
+                          tuple(expr_signature(ps)
+                                for ps in self.projections),
+                          schema_signature(self._schema))
+        # a kernel a projection list, shared by its own list alone
+        src, lists, out = self.signature
+        self._kernels = [jit_kernel(fn, key=("expand", src, ps, out))
+                         for fn, ps in zip(self._kernel_fns, lists)]
 
     @property
     def schema(self):

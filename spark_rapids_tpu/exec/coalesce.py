@@ -77,6 +77,8 @@ class TpuCoalesceBatchesExec(TpuExec):
     def __init__(self, child, goal: CoalesceGoal):
         super().__init__([child])
         self.goal = goal
+        #: a mesh stage lowers it as its child: nothing of it is read
+        self.signature = ()
 
     @property
     def schema(self):

@@ -178,7 +178,12 @@ class TpuShuffleExchangeExec(TpuExec):
         self.partitioning = plan.partitioning
         self.n_out = plan.n_out
         from .kernel_cache import (expr_signature, jit_kernel,
-                                   schema_signature)
+                                   schema_signature, sort_key_signature)
+
+        said = self.partitioning.signature()
+        #: a mesh stage reads the partitioning; an unknown one is private
+        self.signature = None if said is None else (
+            schema_signature(child.schema), said)
 
         # the exchange's own programs are keyed by what their bodies
         # read (the child's layout, the bound keys, the fan-out) and
@@ -211,9 +216,7 @@ class TpuShuffleExchangeExec(TpuExec):
                 self.schema)
         if isinstance(self.partitioning, RangePartitioning):
             bound_keys = self.partitioning._bound_keys
-            key_sig = tuple((k.expr.sql(), str(k.expr.dtype),
-                             bool(k.ascending), bool(k.nulls_first))
-                            for k in bound_keys)
+            key_sig = sort_key_signature(bound_keys)
             self._passes_kernel = jit_kernel(
                 functools.partial(range_key_passes, bound_keys=bound_keys),
                 key=("shuffle", layout, key_sig, "rangePasses"))

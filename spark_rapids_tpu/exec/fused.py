@@ -40,21 +40,13 @@ from ..utils.tracing import device_phase, trace_range
 from .base import DevicePartitionedData, TpuExec
 from .basic import TpuExpandExec, TpuFilterExec, TpuProjectExec
 from .generate import TpuGenerateExec
-from .kernel_cache import expr_signature, jit_kernel, schema_signature
+from .kernel_cache import jit_kernel, schema_signature
 
 
-def _member_fingerprint(m) -> tuple:
-    if isinstance(m, TpuProjectExec):
-        return ("p", expr_signature(m.exprs), schema_signature(m.schema))
-    if isinstance(m, TpuFilterExec):
-        return ("f", expr_signature([m.condition]))
-    if isinstance(m, TpuExpandExec):
-        return ("e", tuple(expr_signature(ps) for ps in m.projections),
-                schema_signature(m.schema))
-    if isinstance(m, TpuGenerateExec):
-        return ("g", expr_signature(m.elements), bool(m.position),
-                str(m._out_dtype), schema_signature(m.schema))
-    raise TypeError(f"{type(m).__name__} is not fusable")
+def members_signature(members) -> tuple:
+    """A chain of row-local members as their own signatures say them:
+    what a body composing them (``run_members``) reads."""
+    return tuple((type(m).__name__, m.signature) for m in members)
 
 
 def _apply_member(m, streams):
@@ -105,10 +97,10 @@ class TpuFusedSegmentExec(TpuExec):
         assert len(members) >= 2, "a segment fuses at least two execs"
         self.members = list(members)
         self._schema = self.members[-1].schema
+        self.signature = (schema_signature(child.schema),
+                          members_signature(self.members))
         self._kernel = jit_kernel(
-            self.kernel_twin()._compute,
-            key=("fused", schema_signature(child.schema),
-                 tuple(_member_fingerprint(m) for m in self.members)),
+            self.kernel_twin()._compute, key=("fused", self.signature),
             donate_argnums=(0,) if donate else ())
 
     def kernel_twin(self):

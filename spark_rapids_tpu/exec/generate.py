@@ -32,11 +32,12 @@ class TpuGenerateExec(TpuExec):
         self.position = plan.position
         self._schema = plan_schema = plan.schema
         self._out_dtype = plan_schema.fields[-1].dtype
-        self._kernel = jit_kernel(
-            self.kernel_twin()._compute,
-            key=("generate", schema_signature(child.schema),
-                 expr_signature(self.elements), bool(self.position),
-                 str(self._out_dtype), schema_signature(plan_schema)))
+        self.signature = (schema_signature(child.schema),
+                          expr_signature(self.elements),
+                          bool(self.position), str(self._out_dtype),
+                          schema_signature(plan_schema))
+        self._kernel = jit_kernel(self.kernel_twin()._compute,
+                                  key=("generate", self.signature))
 
     @property
     def schema(self):

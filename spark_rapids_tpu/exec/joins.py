@@ -129,6 +129,10 @@ class TpuHashJoinExec(TpuExec):
         super().__init__([left, right])
         self.plan = plan  # physical.HashJoinExec (exprs already bound)
         self.how = plan.how
+        #: the keys as the plan wrote them: what the exchanges below a
+        #: shuffled join hash (the mesh's colocation check and repair)
+        self.plan_left_keys = list(plan.left_keys)
+        self.plan_right_keys = list(plan.right_keys)
         self.left_keys, self.right_keys = _common_key_exprs(
             plan.left_keys, plan.right_keys)
         self.condition = plan.condition
@@ -137,44 +141,48 @@ class TpuHashJoinExec(TpuExec):
         self._pair_schema = plan.pair_schema
         #: a semi/anti join's condition decided by the right side's
         #: bounds (``_semi_bounds``), or None
-        self._bounds = _bounds_of(self.condition, len(left.schema)) \
-            if self.condition is not None \
-            and self.how in ("semi", "anti") else None
+        self._bounds = None
+        #: the program the join runs, chosen here alone: "expand" (count
+        #: the pairs, lay them out), "semi" (an unconditioned semi/anti
+        #: join), "bounds" (``_semi_bounds``), "pairs" (``_semi_pairs``:
+        #: a condition evaluated on the key-matched pairs)
+        self.path = "expand"
+        if self.how in ("semi", "anti"):
+            self.path = "semi"
+            if self.condition is not None:
+                self._bounds = _bounds_of(self.condition, len(left.schema))
+                self.path = "pairs" if self._bounds is None else "bounds"
         from .kernel_cache import (expr_signature, jit_kernel,
                                    schema_signature)
 
-        sig = ("join", type(self).__name__, self.how,
-               expr_signature(self.left_keys),
-               expr_signature(self.right_keys),
-               self.condition.sql() if self.condition is not None
-               else None,
-               schema_signature(left.schema),
-               schema_signature(right.schema),
-               schema_signature(plan.schema))
+        self.signature = (
+            type(self).__name__, self.how,
+            expr_signature(self.left_keys),
+            expr_signature(self.right_keys),
+            self.condition.sql() if self.condition is not None else None,
+            schema_signature(left.schema),
+            schema_signature(right.schema),
+            schema_signature(plan.schema),
+            tuple(k.sql() for k in self.plan_left_keys),
+            tuple(k.sql() for k in self.plan_right_keys))
         twin = self.kernel_twin()
         self._count_kernel = jit_kernel(
             twin._count_outer if self._outer else twin._count,
-            key=sig + ("count",))
-        self._expand_kernel = jit_kernel(twin._expand, static_argnums=(0,),
-                                         key=sig + ("expand",))
+            key=("join", self.signature, "count"))
+        self._expand_kernel = jit_kernel(
+            twin._expand, static_argnums=(0,),
+            key=("join", self.signature, "expand"))
         self._semi_kernel = jit_kernel(twin._semi_anti,
-                                       key=sig + ("semi",))
+                                       key=("join", self.signature, "semi"))
         # programs of their own: the unconditioned joins keep theirs
-        if self._bounds is not None:
-            self._bounds_kernel = jit_kernel(twin._semi_bounds,
-                                             key=sig + ("semiBounds",))
-        elif self._pairs_needed:
+        if self.path == "bounds":
+            self._bounds_kernel = jit_kernel(
+                twin._semi_bounds,
+                key=("join", self.signature, "semiBounds"))
+        elif self.path == "pairs":
             self._pairs_kernel = jit_kernel(
                 twin._semi_pairs, static_argnums=(0,),
-                key=sig + ("semiPairs",))
-
-    @property
-    def _pairs_needed(self) -> bool:
-        """A semi/anti join with a condition the bounds cannot decide:
-        it evaluates the condition on its key-matched pairs
-        (``_semi_pairs``)."""
-        return self.condition is not None \
-            and self.how in ("semi", "anti") and self._bounds is None
+                key=("join", self.signature, "semiPairs"))
 
     @property
     def _outer(self) -> bool:
@@ -322,7 +330,7 @@ class TpuHashJoinExec(TpuExec):
             else:
                 lbp = pad_device_batch(lb, cap_l, l_widths)
                 rbp = pad_device_batch(rb, cap_r, r_widths)
-                if self._pairs_needed:
+                if self.path == "pairs":
                     # too many pairs for one layout split the stream side
                     yield from self._join_stream_retry(lbp, rbp, rctx)
                     continue
@@ -463,11 +471,13 @@ class TpuHashJoinExec(TpuExec):
     def _join(self, lb: DeviceBatch, rb: DeviceBatch) -> DeviceBatch:
         # OOM-injection checkpoint: the join's working set is the pair
         R.maybe_inject_oom(type(self).__name__ + ".join")
-        if self._bounds is not None:
+        if self.path == "bounds":
             if "join.conditionByBounds" in self.metrics:
                 self.metrics["join.conditionByBounds"].add(1)
             return self._bounds_kernel(lb, rb)
-        if self._pairs_needed:
+        if self.path == "semi":
+            return self._semi_kernel(lb, rb)
+        if self.path == "pairs":
             pr, emit, _, total = self._count_kernel(lb, rb)
             pairs = int(total)              # host sync: the layout's size
             c_out = bucket_rows(pairs)
@@ -481,8 +491,6 @@ class TpuHashJoinExec(TpuExec):
                 if name in self.metrics:
                     self.metrics[name].add(n)
             return self._pairs_kernel(c_out, lb, rb, pr, emit)
-        if self.how in ("semi", "anti"):
-            return self._semi_kernel(lb, rb)
         pr, emit, r_extra, total = self._count_kernel(lb, rb)
         if self._outer:
             total, unmatched = np.asarray(total)  # host sync: both counts
@@ -522,15 +530,15 @@ class TpuHashJoinExec(TpuExec):
         a larger ``c_out``."""
         import jax.numpy as jnp
 
-        if self._pairs_needed:
+        if self.path == "bounds":
+            return self._semi_bounds(lb, rb), jnp.asarray(0, jnp.int64)
+        if self.path == "semi":
+            return self._semi_anti(lb, rb), jnp.asarray(0, jnp.int64)
+        if self.path == "pairs":
             # the pairs are laid out at ``c_out`` slots: their count is
             # the demand the runner retries a larger capacity on
             pr, emit, _, total = self._count(lb, rb)
             return self._semi_pairs(c_out, lb, rb, pr, emit), total
-        if self.how in ("semi", "anti"):
-            out = self._semi_bounds(lb, rb) if self._bounds is not None \
-                else self._semi_anti(lb, rb)
-            return out, jnp.asarray(0, dtype=jnp.int64)
         pr, emit, r_extra, total = self._count(lb, rb)
         return self._expand(c_out, lb, rb, pr, emit, r_extra), total
 
@@ -545,30 +553,27 @@ class TpuHashJoinExec(TpuExec):
         self.metrics[M.NUM_OUTPUT_BATCHES].add(1)
         return out
 
+    #: the query-wide counters each path feeds, summed over its joins:
+    #: the pairs a condition read, the slots they were laid out in and
+    #: the pair programs run; the bounds programs run; the expand
+    #: programs run, by how each mapped its slots to their left rows
+    #: (``J.expand_by_sort``).  Every program is one a stream batch
+    _PATH_METRICS = {
+        "pairs": ("join.conditionPairs", "join.conditionPairSlots",
+                  "join.conditionJoins"),
+        "bounds": ("join.conditionByBounds",),
+        "expand": ("join.expandBySort", "join.expandBySearch"),
+        "semi": ()}
+
     def _init_metrics(self, ctx):
         super()._init_metrics(ctx)
-        if self._pairs_needed:
-            # query-wide, summed over every conditional semi/anti join:
-            # the pairs evaluated, the slots they were laid out in, and
-            # the pair programs run (one a stream batch)
-            for name in ("join.conditionPairs", "join.conditionPairSlots",
-                         "join.conditionJoins"):
-                self.metrics[name] = ctx.metrics.metric(name)
-        elif self._bounds is not None:
-            # query-wide: the conditional semi/anti join programs that
-            # decided by the right side's bounds (one a stream batch)
-            name = "join.conditionByBounds"
+        names = self._PATH_METRICS[self.path]
+        if self._outer:
+            # the left/full join programs run, and the left rows they
+            # emitted with a null right side
+            names += ("join.outerJoins", "join.unmatchedLeftRows")
+        for name in names:
             self.metrics[name] = ctx.metrics.metric(name)
-        elif self.how not in ("semi", "anti"):
-            # query-wide: the expand programs run, by how each mapped its
-            # slots to their left rows (``J.expand_by_sort``)
-            for name in ("join.expandBySort", "join.expandBySearch"):
-                self.metrics[name] = ctx.metrics.metric(name)
-            if self._outer:
-                # query-wide: the left/full join programs run, and the
-                # left rows they emitted with a null right side
-                for name in ("join.outerJoins", "join.unmatchedLeftRows"):
-                    self.metrics[name] = ctx.metrics.metric(name)
 
 
 class TpuShuffledHashJoinExec(TpuHashJoinExec):

@@ -62,6 +62,15 @@ def expr_signature(exprs) -> Tuple:
     return tuple((e.sql(), str(e.dtype)) for e in exprs)
 
 
+def sort_key_signature(keys, bound: bool = True) -> Tuple:
+    """Hashable fingerprint of sort keys: each key's SQL, result dtype
+    and direction.  An unbound key (a range partitioning keeps its
+    plan's) has no dtype to say."""
+    return tuple((k.expr.sql(), str(k.expr.dtype) if bound else None,
+                  bool(k.ascending), bool(k.nulls_first))
+                 for k in keys or ())
+
+
 def _identifier(text: str) -> str:
     return re.sub(r"[^0-9A-Za-z]", "_", text)
 
@@ -261,16 +270,17 @@ class KernelCache:
         site — no sharing, but dispatches still count; such a site
         passes ``kind``, its operator's name for :func:`program_name`
         (a key would share the first caller's closure).  A non-None key
-        MUST capture everything the closure reads (operator kind,
-        bound-expression signatures, input/output schema signatures):
-        the first caller's closure serves every later caller.
+        MUST capture everything the closure reads (an operator's kind
+        and its ``TpuExec.signature``): the first caller's closure
+        serves every later caller.
 
         Lifetime discipline: a registered entry outlives the query, so
         an exec-bound body must be registered through
-        ``TpuExec.kernel_twin()`` — a kernel bound to the live exec
-        would pin its plan subtree (and whatever the subtree's GC
-        finalizers free, e.g. HostToDeviceExec's cached upload buffers)
-        for the life of the process."""
+        ``TpuExec.kernel_twin()``, which holds no child and no host plan
+        node — a kernel bound to the live exec would pin its plan
+        subtree (and whatever the subtree's GC finalizers free, e.g.
+        HostToDeviceExec's cached upload buffers) for the life of the
+        process."""
         use_key = None
         if key is not None:
             # donation_active() probes the jax backend — keep it out of

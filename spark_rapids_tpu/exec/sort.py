@@ -52,18 +52,16 @@ class TpuSortExec(TpuExec):
     def __init__(self, child, keys):
         super().__init__([child])
         self.keys = keys  # List[functions.SortKey], exprs already bound
-        from .kernel_cache import jit_kernel, schema_signature
+        from .kernel_cache import (jit_kernel, schema_signature,
+                                   sort_key_signature)
 
-        key_sig = tuple((k.expr.sql(), str(k.expr.dtype),
-                         bool(k.ascending), bool(k.nulls_first))
-                        for k in keys)
+        self.signature = (schema_signature(child.schema),
+                          sort_key_signature(keys))
         twin = self.kernel_twin()
-        self._kernel = jit_kernel(
-            twin._compute,
-            key=("sort", schema_signature(child.schema), key_sig))
-        self._order_kernel = jit_kernel(
-            twin._order,
-            key=("sort_order", schema_signature(child.schema), key_sig))
+        self._kernel = jit_kernel(twin._compute,
+                                  key=("sort", self.signature))
+        self._order_kernel = jit_kernel(twin._order,
+                                        key=("sort_order", self.signature))
 
     @property
     def schema(self):

@@ -35,15 +35,13 @@ import itertools
 import logging
 import random
 import time
-from types import SimpleNamespace
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..data.column import (DeviceBatch, DeviceColumn, HostBatch,
                            bucket_rows, device_to_host, host_to_device)
-from ..exec.kernel_cache import (_CachedKernel, expr_signature,
-                                 jit_kernel, schema_signature)
+from ..exec.kernel_cache import _CachedKernel, jit_kernel
 from ..fault.errors import (TpuPayloadCorruption, TpuStageCrash,
                             TpuStageTimeout)
 from ..fault.injector import maybe_inject_fault
@@ -110,6 +108,9 @@ class _ResumedPartitioning:
     single checks all reject this sentinel, and joins see an explicit
     'repair' verdict instead of 'unsupported'."""
 
+    def signature(self):
+        return (type(self).__name__,)
+
 
 class _BcastRef:
     """Placeholder for a precomputed (replicated) broadcast build side —
@@ -133,94 +134,14 @@ class _InputRef:
 
 
 class _Unsignable(Exception):
-    """The signature cannot say all the lowering reads of an operator:
-    its stage's program is compiled for the request and not shared."""
+    """An operator or a partitioning whose ``signature`` is None: its
+    stage's program is compiled for the request and not shared."""
 
 
-def _sort_signature(keys, bound: bool = True) -> Tuple:
-    """Sort keys as TpuSortExec's own kernel key says them; an unbound
-    key (a range partitioning keeps its plan's) has no dtype to say."""
-    return tuple((k.expr.sql(), str(k.expr.dtype) if bound else None,
-                  bool(k.ascending), bool(k.nulls_first))
-                 for k in keys or ())
-
-
-def _partitioning_signature(part):
-    """What the lowering reads of a partitioning: the kind, and the
-    keys as they print (they are bound against a schema the
-    operator's signature holds)."""
-    from ..shuffle.partitioning import (HashPartitioning,
-                                        RangePartitioning,
-                                        RoundRobinPartitioning,
-                                        SinglePartitioning)
-
-    if part is None:
-        return None
-    if isinstance(part, HashPartitioning):
-        return ("hash", tuple(k.sql() for k in part.keys))
-    if isinstance(part, RangePartitioning):
-        return ("range", _sort_signature(part._bound_keys),
-                _sort_signature(part.sort_keys, bound=False))
-    if isinstance(part, (SinglePartitioning, RoundRobinPartitioning,
-                         _ResumedPartitioning)):
-        return (type(part).__name__,)
-    raise _Unsignable(type(part).__name__)
-
-
-def _operator_signature(op) -> Tuple:
-    """What ``_lower`` and the operator's raw body read of ``op``: its
-    kind, bound expressions and schemas, said as the operator's own
-    kernel keys say them (exec/*.py).  An operator not listed here is
-    _Unsignable, never guessed at."""
-    from ..exec import basic as B
-    from ..exec.aggregate import TpuHashAggregateExec
-    from ..exec.coalesce import TpuCoalesceBatchesExec
-    from ..exec.exchange import TpuShuffleExchangeExec
-    from ..exec.fused import TpuFusedSegmentExec, _member_fingerprint
-    from ..exec.generate import TpuGenerateExec
-    from ..exec.joins import TpuHashJoinExec
-    from ..exec.sort import TpuSortExec
-
-    kind = type(op).__name__
-    if isinstance(op, TpuCoalesceBatchesExec):
-        return (kind,)                  # lowered as its child
-    head = (kind, tuple(schema_signature(c.schema) for c in op.children))
-    if isinstance(op, TpuShuffleExchangeExec):
-        return head + (_partitioning_signature(op.partitioning),)
-    if isinstance(op, TpuHashJoinExec):
-        return head + (
-            op.how, expr_signature(op.left_keys),
-            expr_signature(op.right_keys),
-            tuple(k.sql() for k in op.plan.left_keys),
-            tuple(k.sql() for k in op.plan.right_keys),
-            op.condition.sql() if op.condition is not None else None,
-            schema_signature(op.schema))
-    if isinstance(op, TpuFusedSegmentExec):
-        return head + tuple(_member_fingerprint(m) for m in op.members)
-    if isinstance(op, B.TpuProjectExec):
-        return head + (expr_signature(op.exprs),
-                       schema_signature(op.schema))
-    if isinstance(op, B.TpuFilterExec):
-        return head + (expr_signature([op.condition]),)
-    if isinstance(op, B.TpuExpandExec):
-        return head + (tuple(expr_signature(ps) for ps in op.projections),
-                       schema_signature(op.schema))
-    if isinstance(op, B.TpuUnionExec):
-        return head + (schema_signature(op.schema),)
-    if isinstance(op, B.TpuLocalLimitExec):
-        return head + (int(op.n),)
-    if isinstance(op, TpuSortExec):
-        return head + (_sort_signature(op.keys),)
-    if isinstance(op, TpuHashAggregateExec):
-        # an absorbed chain is part of the operator's raw body
-        return head + (op.mode, expr_signature(op.keys),
-                       tuple(sp.func.sql() for sp in op.specs),
-                       schema_signature(op.schema)) + (
-            (op.absorbed_signature,) if op.absorbed else ())
-    if isinstance(op, TpuGenerateExec):
-        return head + (expr_signature(op.elements), bool(op.position),
-                       str(op._out_dtype), schema_signature(op.schema))
-    raise _Unsignable(kind)
+def _said(of, signature):
+    if signature is None:
+        raise _Unsignable(type(of).__name__)
+    return signature
 
 
 class _StageProgram:
@@ -856,8 +777,8 @@ class DistributedRunner:
         'unsupported'."""
         lpart = self._source_partitioning(lkid)
         rpart = self._source_partitioning(rkid)
-        keys_ok = (self._hash_keys_match(lpart, op.plan.left_keys)
-                   and self._hash_keys_match(rpart, op.plan.right_keys))
+        keys_ok = (self._hash_keys_match(lpart, op.plan_left_keys)
+                   and self._hash_keys_match(rpart, op.plan_right_keys))
         single_ok = self._is_single(lpart) and self._is_single(rpart)
         if keys_ok or single_ok:
             return "ok"
@@ -988,12 +909,12 @@ class DistributedRunner:
                     # P-fold)
                     lb = self._capped_exchange(
                         lb, self._hash_pids_by_exprs(
-                            lb, op.plan.left_keys,
+                            lb, op.plan_left_keys,
                             op.children[0].schema),
                         f"jexl{op.place}", aux, caps, used_caps)
                     rb = self._capped_exchange(
                         rb, self._hash_pids_by_exprs(
-                            rb, op.plan.right_keys,
+                            rb, op.plan_right_keys,
                             op.children[1].schema),
                         f"jexr{op.place}", aux, caps, used_caps)
                 elif verdict == "unsupported":
@@ -1116,7 +1037,7 @@ class DistributedRunner:
         order.  A program outlives its request in the kernel cache; a
         live operator would pin its plan subtree, and through a leaf
         the uploads below it."""
-        from ..exec.joins import TpuBroadcastHashJoinExec, TpuHashJoinExec
+        from ..exec.joins import TpuBroadcastHashJoinExec
 
         if isinstance(node, (_LeafRef, _StageRef)):
             inputs.append(node)
@@ -1131,13 +1052,6 @@ class DistributedRunner:
         for name, held in list(vars(twin).items()):
             if isinstance(held, _CachedKernel):
                 delattr(twin, name)
-        if hasattr(twin, "plan"):
-            # the host plan node holds its subtree; the lowering reads
-            # a join's keys off it and nothing else
-            twin.plan = SimpleNamespace(
-                left_keys=op.plan.left_keys,
-                right_keys=op.plan.right_keys) \
-                if isinstance(op, TpuHashJoinExec) else None
         if isinstance(op, TpuBroadcastHashJoinExec):
             # the build side is gathered once a query, as a program of
             # its own (_prepare_broadcasts), and comes in replicated
@@ -1147,18 +1061,24 @@ class DistributedRunner:
         return (twin, *[self._detach(k, inputs, place) for k in kids])
 
     def _signature(self, node) -> Tuple:
+        """A detached stage tree as it says itself: each operator's kind
+        and own ``signature``, each input's partitioning's."""
         if isinstance(node, _InputRef):
-            return ("in", _partitioning_signature(node.partitioning))
+            part = node.partitioning
+            return ("in", None if part is None
+                    else _said(part, part.signature()))
         op, *kids = node
-        return (_operator_signature(op),
+        return ((type(op).__name__, _said(op, op.signature)),
                 *[self._signature(k) for k in kids])
 
     def _program_key(self, tree, post, what: str):
         """The kernel-cache key of a stage program — all its trace
         reads but the capacities (its static argument) and the inputs'
-        shapes (the jit's own): the operators, the mesh, the transport,
-        the row bucket, the ``post`` hook.  None where an operator
-        cannot be signed: that program is compiled for this request."""
+        shapes (the jit's own): the operators (each one's kind and
+        ``TpuExec.signature``), the inputs' partitionings, the mesh,
+        the transport, the row bucket, the ``post`` hook.  None where a
+        signature is None: that program is compiled for this
+        request."""
         try:
             sig = self._signature(tree)
         except _Unsignable as e:

@@ -109,7 +109,7 @@ class TpuFusionPass:
             members = list(reversed(self._chain(plan.children[0])))
             if self._absorbable(plan, members):
                 return TpuHashAggregateExec(
-                    self._rewrite(members[0].children[0]), plan.plan,
+                    self._rewrite(members[0].children[0]), plan,
                     absorbed=members)
         children = [self._rewrite(c) for c in plan.children]
         if children != list(plan.children):

@@ -40,6 +40,13 @@ class Partitioning:
     def describe(self) -> str:
         return f"{type(self).__name__}({self.num_partitions})"
 
+    def signature(self):
+        """What a mesh stage's lowering reads of the partitioning (its
+        kind, its keys as they print), for the stage program's key;
+        None: a partitioning that cannot say it, whose stage is
+        compiled for its request."""
+        return None
+
 
 class SinglePartitioning(Partitioning):
     def __init__(self):
@@ -47,6 +54,9 @@ class SinglePartitioning(Partitioning):
 
     def partition_ids(self, batch):
         return np.zeros(batch.num_rows, dtype=np.int32)
+
+    def signature(self):
+        return (type(self).__name__,)
 
 
 class RoundRobinPartitioning(Partitioning):
@@ -60,6 +70,9 @@ class RoundRobinPartitioning(Partitioning):
         self._next = (start + n) % self.num_partitions
         return ((start + np.arange(n)) % self.num_partitions).astype(
             np.int32)
+
+    def signature(self):
+        return (type(self).__name__,)
 
 
 class HashPartitioning(Partitioning):
@@ -85,6 +98,10 @@ class HashPartitioning(Partitioning):
     def describe(self):
         return (f"HashPartitioning([{', '.join(k.sql() for k in self.keys)}]"
                 f", {self.num_partitions})")
+
+    def signature(self):
+        # the keys bind against a schema the operator's signature holds
+        return ("hash", tuple(k.sql() for k in self.keys))
 
 
 class RangePartitioning(Partitioning):
@@ -209,3 +226,9 @@ class RangePartitioning(Partitioning):
 
     def describe(self):
         return f"RangePartitioning({self.num_partitions})"
+
+    def signature(self):
+        from ..exec.kernel_cache import sort_key_signature
+
+        return ("range", sort_key_signature(self._bound_keys),
+                sort_key_signature(self.sort_keys, bound=False))

@@ -681,8 +681,7 @@ def test_filter_under_group_by_runs_absorbed_on_the_mesh(keyed):
     the absorbed members: the mesh's answer is the one chip's, and two
     stages differing only in the absorbed predicate are two programs."""
     from spark_rapids_tpu import Session
-    from spark_rapids_tpu.parallel.runner import (_operator_signature,
-                                                  run_distributed)
+    from spark_rapids_tpu.parallel.runner import run_distributed
     from spark_rapids_tpu.plan import functions as F
 
     rng = np.random.RandomState(8)
@@ -715,7 +714,7 @@ def test_filter_under_group_by_runs_absorbed_on_the_mesh(keyed):
 
     def signed(bound):
         (agg,) = _absorbing(sess.physical_plan(q(sess, bound).plan))
-        return _operator_signature(agg)
+        return agg.signature, _one_operator_stage_key(agg)
 
     assert signed(10.0) == signed(10.0) != signed(11.0)
 
@@ -950,10 +949,7 @@ def test_a_cached_stage_program_keeps_no_request_alive():
     while todo:
         nodes.append(todo.pop())
         todo.extend(nodes[-1].children)
-    # every device operator, the leaves' uploads among them.  (A host
-    # scan below them is kept by the aggregate's own kernel twin, whose
-    # ``plan`` is the host node: exec/aggregate.py, with or without a
-    # mesh.)
+    # every device operator, the leaves' uploads among them
     nodes = [n for n in nodes if isinstance(n, TpuExec)]
     assert len(nodes) >= 5
     gone = [weakref.ref(o) for o in (runner, ctx, *nodes)]
@@ -1015,15 +1011,9 @@ def test_two_processes_agree_on_every_stage_program():
 
 
 # ---------------------------------------------------------------------
-# the stage signature against the operators' own kernel keys
+# the stage key is made of the operators' own signatures
 # ---------------------------------------------------------------------
-# parallel/runner.py:_operator_signature says again, by hand, what each
-# operator's own kernel key says in its __init__ (exec/*.py).  A stage
-# program is shared by that signature, so a field an operator's key
-# gains and the signature lacks would share a wrong program silently:
-# wrong rows, not a cache miss.  Until the operators hand their key out
-# themselves (ROADMAP Design 1), this is what fails first.
-def _own_kernel_keys(op):
+def _held_kernel_keys(op):
     """The keys ``op`` registered its own kernels under."""
     from spark_rapids_tpu.exec.kernel_cache import GLOBAL, _CachedKernel
 
@@ -1035,30 +1025,15 @@ def _own_kernel_keys(op):
                 if any(kern is h for h in held)]
 
 
-def _parts(sig):
-    yield sig
-    if isinstance(sig, tuple):
-        for s in sig:
-            yield from _parts(s)
+def _one_operator_stage_key(op):
+    """The stage-program key of a stage of ``op`` alone over inputs
+    that say no partitioning."""
+    from spark_rapids_tpu.parallel.runner import (DistributedRunner,
+                                                  _InputRef)
 
-
-def _unsaid(keys, signature):
-    """The fields of an operator's kernel keys that its stage signature
-    does not hold: all but the leading kind and a trailing phase
-    (``"count"``, ``"batch"``), which name the kernel and not what it
-    reads.  A tuple of fields may be said field by field (the fused
-    segment's members)."""
-    said = set(_parts(signature))
-    out = []
-    for key in keys:
-        fields = list(key[1:])
-        if fields and isinstance(fields[-1], str):
-            fields.pop()
-        out += [(key[0], f) for f in fields
-                if f not in said and not (
-                    isinstance(f, tuple) and f
-                    and all(p in said for p in f))]
-    return out
+    tree = (op.kernel_twin(), *[_InputRef(i) for i in
+                                range(len(op.children))])
+    return DistributedRunner(_mesh(2))._program_key(tree, None, "test")
 
 
 def _signed_plan(kind):
@@ -1087,33 +1062,122 @@ def _signed_plan(kind):
     return sess.physical_plan(q.plan)
 
 
-@pytest.mark.parametrize("kind", [
-    "TpuProjectExec", "TpuFilterExec", "TpuExpandExec", "TpuGenerateExec",
-    "TpuFusedSegmentExec", "TpuHashJoinExec", "TpuHashAggregateExec",
-    "TpuSortExec"])
-def test_stage_signature_says_all_the_operators_own_key_says(kind):
-    from spark_rapids_tpu.parallel.runner import _operator_signature
-
-    nodes, todo = [], [_signed_plan(kind)]
+def _ops_of(plan, kind):
+    nodes, todo = [], [plan]
     while todo:
         nodes.append(todo.pop())
         todo.extend(nodes[-1].children)
     ops = [n for n in nodes
            if kind in [c.__name__ for c in type(n).__mro__]]
     assert ops, sorted({type(n).__name__ for n in nodes})
-    for op in ops:
-        keys = _own_kernel_keys(op)
+    return ops
+
+
+@pytest.mark.parametrize("kind", [
+    "TpuProjectExec", "TpuFilterExec", "TpuExpandExec", "TpuGenerateExec",
+    "TpuFusedSegmentExec", "TpuHashJoinExec", "TpuHashAggregateExec",
+    "TpuSortExec"])
+def test_kernels_and_stage_key_read_the_operators_signature(kind):
+    """Every keyed kernel an operator holds is keyed by its
+    ``signature`` (an expand's, a kernel a projection list, by the
+    signature's fields), and a stage of the operator alone is keyed by
+    its kind and that same tuple."""
+    for op in _ops_of(_signed_plan(kind), kind):
+        keys = _held_kernel_keys(op)
         assert keys, f"{kind} registered no keyed kernel"
-        assert _unsaid(keys, _operator_signature(op)) == []
+        for key in keys:
+            if kind == "TpuExpandExec":
+                src, lists, out = op.signature
+                assert key[0] == "expand" and key[1] == src \
+                    and key[2] in lists and key[3] == out, key
+            else:
+                assert op.signature in key, key
+        stage = _one_operator_stage_key(op)
+        assert stage[1][0] == (type(op).__name__, op.signature)
 
 
-def test_a_field_the_signature_lacks_is_found():
-    from spark_rapids_tpu.parallel.runner import _operator_signature
+def test_a_field_of_the_signature_changes_the_stage_key():
+    """A literal or a schema the filter reads is in its signature and so
+    in its stage's key; an operator whose signature is None (a window)
+    has its stage compiled for the request."""
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.ops.windowexprs import over, row_number, window
+    from spark_rapids_tpu.parallel.runner import run_distributed
+    from spark_rapids_tpu.plan import functions as F
 
-    plan = _signed_plan("TpuFilterExec")
-    while type(plan).__name__ != "TpuFilterExec":
-        plan = plan.children[0]
-    (key,) = _own_kernel_keys(plan)
-    grown = key + (("ansi", True),)
-    assert _unsaid([grown], _operator_signature(plan)) == [
-        ("filter", ("ansi", True))]
+    def filter_key(v_type, bound):
+        sess = Session()
+        df = sess.create_dataframe(
+            {"k": list(range(8)), "v": [float(i) for i in range(8)]},
+            schema=T.Schema([T.Field("k", T.INT64),
+                             T.Field("v", v_type)]))
+        (op,) = _ops_of(sess.physical_plan(
+            df.filter(df["v"] > bound).plan), "TpuFilterExec")
+        return op.signature, _one_operator_stage_key(op)
+
+    sig, key = filter_key(T.FLOAT64, 3.0)
+    assert (sig, key) == filter_key(T.FLOAT64, 3.0)
+    for other in (filter_key(T.FLOAT64, 4.0), filter_key(T.FLOAT32, 3.0)):
+        assert other[0] != sig and other[1] != key
+
+    def ranked(sess):
+        df = sess.create_dataframe({"k": np.arange(40) % 4,
+                                    "t": np.arange(40)[::-1]})
+        return df.with_window("r", over(
+            row_number(), window().partition_by("k").order_by("t")))
+
+    sess = Session()
+    (win,) = _ops_of(sess.physical_plan(ranked(sess).plan),
+                     "TpuWindowExec")
+    assert win.signature is None and _one_operator_stage_key(win) is None
+    first = run_distributed(sess, ranked(sess), mesh=_mesh(2)).to_rows()
+    again = run_distributed(sess, ranked(sess), mesh=_mesh(2)).to_rows()
+    assert _stage_counters(sess)[0] >= 1        # compiled again
+    _assert_rows_equal(again, first)
+    _assert_rows_equal(first, ranked(Session(tpu_enabled=False)).collect())
+
+
+@pytest.mark.parametrize("path", ["expand", "semi", "bounds", "pairs"])
+def test_one_chip_and_mesh_take_the_same_join_path(path, monkeypatch):
+    """The join chooses its program once (``TpuHashJoinExec.path``):
+    one chip (``_join``) and a mesh stage (``join_static``) run the body
+    of that path and no other, to the same rows as the host engine."""
+    from spark_rapids_tpu import Session
+    from spark_rapids_tpu.exec.joins import TpuHashJoinExec
+    from spark_rapids_tpu.parallel.runner import run_distributed
+    from spark_rapids_tpu.plan import functions as F
+
+    bodies = {"expand": "_expand", "semi": "_semi_anti",
+              "bounds": "_semi_bounds", "pairs": "_semi_pairs"}
+    ran = []
+    for body in bodies.values():
+        def spy(self, *a, _body=body, _orig=getattr(TpuHashJoinExec, body)):
+            ran.append(_body)
+            return _orig(self, *a)
+
+        monkeypatch.setattr(TpuHashJoinExec, body, spy)
+    how = {"expand": "inner"}.get(path, "semi")
+    condition = {"bounds": F.col("s") < F.col("rs"),
+                 "pairs": F.col("s") + F.col("rs") > F.lit(4)}.get(path)
+    keys = np.random.RandomState(6).randint(0, 9, 200)
+
+    def q(sess):
+        l = sess.create_dataframe({"k": keys, "s": np.arange(200) % 7})
+        r = sess.create_dataframe({"rk": np.arange(12) % 6,
+                                   "rs": np.arange(12) % 5})
+        return l.join(r, on=(["k"], ["rk"]), how=how, condition=condition)
+
+    conf = {"spark.rapids.tpu.sql.broadcastSizeThreshold": 0}
+    one = Session(dict(conf))
+    df = q(one)
+    (op,) = _ops_of(one.physical_plan(df.plan), "TpuHashJoinExec")
+    assert op.path == path
+    rows = df.collect()
+    assert set(ran) == {bodies[path]}
+    ran.clear()
+    mesh = Session(dict(conf))
+    got = run_distributed(mesh, q(mesh), mesh=_mesh(4)).to_rows()
+    assert set(ran) == {bodies[path]}
+    _assert_rows_equal(got, rows)
+    _assert_rows_equal(rows, q(Session(tpu_enabled=False)).collect())

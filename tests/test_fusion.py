@@ -524,10 +524,11 @@ def test_absorbed_chain_survives_a_forced_split(keyed):
 
 
 def test_an_aggregate_that_absorbs_nothing_keeps_its_kernel_keys():
-    """The kernel-cache keys of a plain group-by are what they were
-    before aggregates could absorb (nothing recompiles in q3, q16,
-    q18), and two aggregates that differ only in the absorbed
-    predicate share no program."""
+    """The kernel-cache keys of a plain group-by say what they said
+    before aggregates could absorb, and nothing more (one program as
+    before in q3, q16, q18), and two aggregates that differ only in the
+    absorbed predicate share no program."""
+    from spark_rapids_tpu.exec.fused import members_signature
     from spark_rapids_tpu.exec.kernel_cache import (GLOBAL, _CachedKernel,
                                                     expr_signature,
                                                     schema_signature)
@@ -548,11 +549,12 @@ def test_an_aggregate_that_absorbs_nothing_keeps_its_kernel_keys():
     sess = srt.Session()
     df = sess.create_dataframe(_masked_data())
     plain = partial_of(sess, df.group_by("k").agg(F.sum("v").alias("s")))
-    assert not plain.absorbed and plain.absorbed_signature == ()
-    sig = ("agg", "partial", schema_signature(plain.children[0].schema),
+    assert not plain.absorbed
+    sig = ("partial", schema_signature(plain.children[0].schema),
            expr_signature(plain.keys), ("sum(v)",),
            schema_signature(plain.schema))
-    assert keys_of(plain) == {sig + (phase,) for phase in (
+    assert plain.signature == sig
+    assert keys_of(plain) == {("agg", sig, phase) for phase in (
         "batch", "update", "merge", "merge_final")}
 
     def filtered(bound):
@@ -564,10 +566,11 @@ def test_an_aggregate_that_absorbs_nothing_keeps_its_kernel_keys():
     assert keys_of(a) == keys_of(again)
     assert not keys_of(a) & keys_of(b)
     assert not keys_of(a) & keys_of(plain)
-    # the members' fingerprints and the chain's input schema are in it
+    # the members' signatures and the chain's input schema are in it
     for key in keys_of(a):
-        assert a.absorbed_signature in key
-        assert schema_signature(a.children[0].children[0].schema) in key
+        assert key[1] == a.signature
+        assert members_signature(a.absorbed) in a.signature
+        assert schema_signature(a.children[0].children[0].schema) in key[1]
 
 
 def test_an_absorbing_aggregate_survives_with_new_children():

@@ -149,7 +149,7 @@ def test_device_equals_host_and_the_pairs(how, case, form):
     sess = srt.Session(STRICT)
     df = _query(sess, left, right, how, condition=cond)
     assert f"* HashJoinExec [{how}, (" in df.explain()
-    assert _device_join(sess, df)._bounds is None
+    assert _device_join(sess, df).path == "pairs"
     assert sorted(df.collect(), key=repr) == want
     m = sess.last_metrics
     assert "join.conditionByBounds" not in m
@@ -187,8 +187,8 @@ def test_bounds_decide_as_the_pairs_do(how, op, left_first, case):
     sess = srt.Session(STRICT)
     df = _query(sess, left, right, how, condition=cond)
     assert f"* HashJoinExec [{how}, (" in df.explain()
-    bounds = _device_join(sess, df)._bounds
-    assert bounds is not None and bounds[0] == op
+    join = _device_join(sess, df)
+    assert join.path == "bounds" and join._bounds[0] == op
     assert sorted(df.collect(), key=repr) == want
     m = sess.last_metrics
     assert m["join.conditionByBounds"] == 1
@@ -254,7 +254,7 @@ def test_the_bounds_refuse_what_they_cannot_decide():
         df = lf.join(rf, on=(["k"], ["k2"]), how="semi", condition=cond)
         op = _device_join(sess, df)
         assert (op._bounds is None) == (want is None), cond
-        assert op._pairs_needed == (want is None)
+        assert op.path == ("pairs" if want is None else "bounds"), cond
 
 
 @pytest.mark.parametrize("how", ["semi", "anti"])

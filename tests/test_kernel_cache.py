@@ -5,6 +5,7 @@ between tests by the autouse ``_reset_kernel_cache`` fixture, so every
 test starts from zero counters and an empty registry.
 """
 import jax.numpy as jnp
+import pytest
 
 from spark_rapids_tpu.config import TpuConf
 from spark_rapids_tpu.exec.kernel_cache import (GLOBAL, KernelCache,
@@ -236,6 +237,54 @@ def test_registered_kernels_do_not_pin_plan_trees():
     alive = [r for r in refs if r() is not None]
     assert not alive, \
         "a registered kernel retains the plan tree past query end"
+
+
+@pytest.mark.parametrize("kind", ["join", "aggregate"])
+def test_a_cached_kernel_holds_no_host_plan_node(kind):
+    """A join's and an aggregate's kernel twins hold no host plan node:
+    once the query is dropped, its host HashJoinExec / HashAggregateExec
+    is collected while the kernel cache still holds their programs."""
+    import gc
+    import weakref
+
+    import spark_rapids_tpu as srt
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.joins import TpuHashJoinExec
+    from spark_rapids_tpu.plan import functions as F
+    from spark_rapids_tpu.plan import physical as P
+
+    cls, host, name = {
+        "join": (TpuHashJoinExec, P.HashJoinExec, "join_count"),
+        "aggregate": (TpuHashAggregateExec, P.HashAggregateExec,
+                      "agg_batch")}[kind]
+    # weakrefs via a constructor spy: plan capture would itself retain
+    # the tree on the session
+    refs = []
+    orig = cls.__init__
+
+    def spy(self, *args, **kw):
+        refs.extend(weakref.ref(a) for a in args if isinstance(a, host))
+        orig(self, *args, **kw)
+
+    cls.__init__ = spy
+    try:
+        sess = srt.Session(
+            {"spark.rapids.tpu.sql.broadcastSizeThreshold": 0})
+        df = sess.create_dataframe({"k": [1, 2, 2, 3], "v": [1, 2, 3, 4]})
+        other = sess.create_dataframe({"rk": [2, 3], "w": [5, 6]})
+        q = df.join(other, on=(["k"], ["rk"]), how="inner") \
+            if kind == "join" else \
+            df.group_by("k").agg(F.sum("v").alias("s"))
+        assert q.collect()
+    finally:
+        cls.__init__ = orig
+    assert refs, f"no {host.__name__} was converted"
+    del df, other, q, sess
+    gc.collect()
+    with GLOBAL._lock:
+        names = {k.name for k in GLOBAL._entries.values()}
+    assert name in names
+    assert [r for r in refs if r() is not None] == []
 
 
 def test_identical_execs_across_sessions_share_kernels():

@@ -545,7 +545,7 @@ def test_semi_join_bounds_compile_for_v5e(one_chip, as_tpu, value):
                  condition=F.col("a") != F.col("b"))
     op, = [n for n in _walk(sess.physical_plan(df.plan))
            if isinstance(n, TpuHashJoinExec)]
-    assert op._bounds is not None
+    assert op.path == "bounds"
 
     def batch(schema):
         return DeviceBatch(schema, [_column(one_chip, f.dtype)
